@@ -88,7 +88,7 @@ KNOBS: List[Knob] = [
        "pallas histogram kernel rows per grid step (ops/hist_pallas.py)"),
     _K("shifu.pallas.wmax", "int", "1024",
        "pallas histogram kernel max padded one-hot columns per VMEM "
-       "chunk (fused-scan chunks clamp to 1024)"),
+       "chunk (fused-scan chunks clamp to 512)"),
     # ---- observability / profiling (PR 2, PR 6) ----
     _K("shifu.profile", "str", "",
        "\"xla\" = deep-capture into the ledger dir; else explicit trace dir"),

@@ -257,7 +257,10 @@ class ColumnarData:
         import pandas as pd
 
         ser = self._series(name)
-        vals = pd.to_numeric(ser, errors="coerce").to_numpy(dtype=np.float64)
+        # copy=True: pandas 3 hands to_numpy() back read-only, and the
+        # non-finite pass below writes in place
+        vals = pd.to_numeric(ser, errors="coerce").to_numpy(
+            dtype=np.float64, copy=True)
         if len(self.missing_values):
             # strip before the missing-set check, exactly like missing_mask —
             # " NA " must count as missing in BOTH views ("" is excluded
@@ -425,7 +428,9 @@ def _flat_parse(data: "ColumnarData", names: Sequence[str]) -> np.ndarray:
         except (TypeError, ValueError):
             pass
     ser = pd.Series(flat)
-    vals = pd.to_numeric(ser, errors="coerce").to_numpy(np.float64)
+    # copy=True: pandas 3 hands to_numpy() back read-only, and both
+    # passes below write in place
+    vals = pd.to_numeric(ser, errors="coerce").to_numpy(np.float64, copy=True)
     if numeric_tokens:
         # the per-element strip+isin pass is a dominant host cost on an
         # online batch, and it can only CHANGE anything when a missing

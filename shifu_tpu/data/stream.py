@@ -23,6 +23,18 @@ from typing import Callable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
+try:
+    # imported HERE, on the thread that imports this module, not lazily in
+    # _string_dtype(): the chunk readers' bodies run on the prefetch worker
+    # (data/pipeline.py), and with the installed pyarrow a FIRST import from
+    # that non-main thread segfaults intermittently (seen as a crashed
+    # `compute_stats_streaming` whenever nothing had imported pandas yet)
+    import pyarrow  # noqa: F401
+
+    _HAVE_PYARROW = True
+except ImportError:
+    _HAVE_PYARROW = False
+
 from shifu_tpu.data.reader import (
     DEFAULT_MISSING,
     ColumnarData,
@@ -73,12 +85,7 @@ def _string_dtype():
     ~550-byte Python object per row), plain object strings otherwise. The
     LazyColumns facade (data/reader.py) keeps columns in this storage until
     a stage actually reads them, so the bounded-memory envelope holds."""
-    try:
-        import pyarrow  # noqa: F401
-
-        return "string[pyarrow]"
-    except ImportError:
-        return str
+    return "string[pyarrow]" if _HAVE_PYARROW else str
 
 
 def _iter_csv_chunks(

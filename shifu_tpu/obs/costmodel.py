@@ -11,6 +11,8 @@ fewer bytes can (the classic Williams/Waterman/Patterson roofline model).
 Peaks are public per-chip numbers (bf16 dense matmul TFLOP/s, HBM GB/s),
 matched by `device_kind` substring. The CPU entry is a NOMINAL figure so
 dev-harness rooflines classify sensibly; treat CPU MFU as relative only.
+It applies to platform == "cpu" alone: an accelerator missing from the
+table is an error, never graded against another device's peaks.
 
 Override knobs (for unlisted chips or corrected figures):
     -Dshifu.profile.peakTflops=<float>   peak dense TFLOP/s
@@ -81,21 +83,27 @@ def _overridden(peaks: ChipPeaks) -> ChipPeaks:
 
 
 def detect() -> ChipPeaks:
-    """Peaks for the current jax backend (override > table > nominal).
-    Never raises: an uninitializable jax yields the nominal CPU entry."""
-    kind = ""
-    try:
-        import jax
+    """Peaks for the current jax backend (override > table; the nominal
+    entry is for platform == "cpu" only). An accelerator that is not in
+    CHIP_TABLE is an error unless BOTH -Dshifu.profile.peak* overrides
+    are given — grading an unknown chip against CPU peaks would report
+    a wrong MFU under a plausible name."""
+    import jax
 
-        devices = jax.devices()
-        kind = getattr(devices[0], "device_kind", "") if devices else ""
-    except Exception:  # any jax import/init failure -> nominal CPU entry
-        kind = ""
-    entry = lookup(kind)
-    if entry is None:
+    dev = jax.devices()[0]
+    kind = getattr(dev, "device_kind", "")
+    if dev.platform == "cpu":
         name, tflops, gbs = CPU_NOMINAL
-        entry = ChipPeaks(name, kind, tflops, gbs, "nominal")
-    return _overridden(entry)
+        return _overridden(ChipPeaks(name, kind, tflops, gbs, "nominal"))
+    peaks = _overridden(
+        lookup(kind) or ChipPeaks(kind, kind, 0.0, 0.0, "unlisted"))
+    if peaks.peak_tflops <= 0.0 or peaks.peak_hbm_gbs <= 0.0:
+        raise ValueError(
+            f"device_kind {kind!r} ({dev.platform}) is not in "
+            "obs/costmodel.py CHIP_TABLE: add it with its published "
+            "peaks, or pass -Dshifu.profile.peakTflops and "
+            "-Dshifu.profile.peakGBs")
+    return peaks
 
 
 def roofline_verdict(flops: float, bytes_accessed: float,

@@ -39,18 +39,13 @@ DURATION_BUCKETS = tuple(0.001 * 2 ** k for k in range(17)) + (float("inf"),)
 
 
 def install() -> bool:
-    """Idempotently register the monitoring listeners. Returns True if the
-    probes are active (False when jax lacks the monitoring API)."""
+    """Idempotently register the monitoring listeners. Returns True once
+    the probes are active."""
     global _installed
     with _lock:
         if _installed:
             return True
-        try:
-            from jax import monitoring
-        except ImportError:  # pragma: no cover - jax always present
-            return False
-        if not hasattr(monitoring, "register_event_duration_secs_listener"):
-            return False  # pragma: no cover - ancient jax
+        from jax import monitoring
 
         def _on_duration(name: str, duration: float, **_kw) -> None:
             hit = _DURATION_EVENTS.get(name)

@@ -147,46 +147,48 @@ def _build_entry(name: str, fn: Callable, args: tuple,
     re-allowed inside (profiler-internal work, not the caller's hot
     path), so building an entry under an armed transfer guard is legal."""
     entry = _CostEntry(fn)
-    try:
-        import jax
+    import jax
 
-        lower = getattr(fn, "lower", None)
-        if lower is None:
-            return entry
-        t0 = time.perf_counter()
-        with jax.transfer_guard("allow"):
+    lower = getattr(fn, "lower", None)
+    if lower is None:
+        return entry
+    t0 = time.perf_counter()
+    with jax.transfer_guard("allow"):
+        try:
             lowered = lower(*args, **kwargs)
+        except Exception:  # un-lowerable seam -> plain-dispatch fallback
+            # (exotic pytree, shard_map edge, ...): dispatch counting only
+            return entry
+        try:
+            cost = _first_cost_dict(lowered.cost_analysis())
+        except Exception:  # cost analysis is best-effort per backend
+            cost = {}
+        # NOT guarded: a compile the backend refuses (Mosaic VMEM limit,
+        # HBM OOM) rises from the seam, once — re-dispatching through
+        # plain jit would only pay the same failing compile a second time
+        compiled = lowered.compile()
+        entry.compile_seconds = time.perf_counter() - t0
+        if not cost:
             try:
-                cost = _first_cost_dict(lowered.cost_analysis())
+                cost = _first_cost_dict(compiled.cost_analysis())
             except Exception:  # cost analysis is best-effort per backend
                 cost = {}
-            compiled = lowered.compile()
-            entry.compile_seconds = time.perf_counter() - t0
-            if not cost:
-                try:
-                    cost = _first_cost_dict(compiled.cost_analysis())
-                except Exception:  # cost analysis is best-effort per backend
-                    cost = {}
-            entry.flops = float(cost.get("flops", 0.0)) or None
-            entry.bytes_accessed = (
-                float(cost.get("bytes accessed", 0.0)) or None)
-            try:
-                mem = compiled.memory_analysis()
-                entry.peak_hbm = float(
-                    getattr(mem, "argument_size_in_bytes", 0)
-                    + getattr(mem, "output_size_in_bytes", 0)
-                    + getattr(mem, "temp_size_in_bytes", 0)
-                    - getattr(mem, "alias_size_in_bytes", 0))
-                entry.arg_bytes = float(
-                    getattr(mem, "argument_size_in_bytes", 0)) or None
-            except Exception:  # memory stats are best-effort per backend
-                entry.peak_hbm = None
-            entry.compiled = compiled
-            entry.source = "xla"
-    except Exception:  # un-lowerable seam -> plain-dispatch fallback
-        # (exotic pytree, shard_map edge, ...): dispatch counting only
-        entry.compiled = None
-        entry.source = "unavailable"
+        entry.flops = float(cost.get("flops", 0.0)) or None
+        entry.bytes_accessed = (
+            float(cost.get("bytes accessed", 0.0)) or None)
+        try:
+            mem = compiled.memory_analysis()
+            entry.peak_hbm = float(
+                getattr(mem, "argument_size_in_bytes", 0)
+                + getattr(mem, "output_size_in_bytes", 0)
+                + getattr(mem, "temp_size_in_bytes", 0)
+                - getattr(mem, "alias_size_in_bytes", 0))
+            entry.arg_bytes = float(
+                getattr(mem, "argument_size_in_bytes", 0)) or None
+        except Exception:  # memory stats are best-effort per backend
+            entry.peak_hbm = None
+        entry.compiled = compiled
+        entry.source = "xla"
     return entry
 
 

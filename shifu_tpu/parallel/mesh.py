@@ -215,7 +215,15 @@ def shard_rows(array, mesh):
     reg = registry()
     reg.counter("mesh.shard_rows", axes="x".join(axes)).inc()
     reg.counter("mesh.h2d_bytes").inc(float(getattr(array, "nbytes", 0)))
-    return jax.device_put(array, NamedSharding(mesh, spec))
+    out = jax.device_put(array, NamedSharding(mesh, spec))
+    # where the rows actually landed (shape metadata, no transfer): a
+    # placement that put everything on one device shows here
+    shards = out.addressable_shards
+    rows = [s.data.shape[0] for s in shards]
+    reg.gauge("mesh.rows_per_device.min").set(min(rows))
+    reg.gauge("mesh.rows_per_device.max").set(max(rows))
+    reg.gauge("mesh.row_devices").set(len({s.device.id for s in shards}))
+    return out
 
 
 def replicate(tree, mesh):
@@ -291,17 +299,10 @@ def fleet_reduce(mesh, parts: np.ndarray, max_cols: int = 0) -> np.ndarray:
 
 
 def shard_map_compat(fn, *, mesh, in_specs, out_specs, check: bool = False):
-    """shard_map across jax versions: newer jax exports `jax.shard_map`
-    (replication checking spelled `check_vma`), 0.4.x only has
-    `jax.experimental.shard_map.shard_map` (spelled `check_rep`). One
-    helper so every call site stays version-agnostic."""
-    try:
-        from jax import shard_map as _sm
+    """`jax.shard_map` with replication checking off by default (the
+    growers psum partials themselves). One helper so every call site
+    spells the check the same way."""
+    import jax
 
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

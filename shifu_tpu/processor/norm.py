@@ -37,12 +37,10 @@ log = get_logger(__name__)
 
 
 def default_shards() -> int:
-    try:
-        import jax
+    # a broken device set must fail here, not write one shard
+    import jax
 
-        return max(1, len(jax.devices()))
-    except Exception:  # pragma: no cover - jax always present in CI
-        return 1
+    return len(jax.devices())
 
 
 class NormProcessor(BasicProcessor):

@@ -599,12 +599,10 @@ class TrainProcessor(BasicProcessor):
         )
 
     def _mesh(self):
-        try:
-            from shifu_tpu.parallel.mesh import data_mesh
+        # a broken device set must fail here, not train on one device
+        from shifu_tpu.parallel.mesh import data_mesh
 
-            return data_mesh()
-        except Exception:  # pragma: no cover - no mesh: single device
-            return None
+        return data_mesh()
 
     # ---- trees / WDL: wired in by their engines ----
     def _train_tree_family(self, alg: Algorithm) -> None:

@@ -185,6 +185,12 @@ def train_tree_models(proc, alg) -> None:
             "oneVsAll": bool(mc.train.is_one_vs_all()),
             "dataSignature": data_sig,
         }
+        # the checkpoint file goes when training completes; the run
+        # manifest keeps which lowering grew the forest
+        from shifu_tpu.obs import profile as _profile
+
+        _profile.annotate("train.tree",
+                          pallasLowering=fingerprint["pallasLowering"])
         init_trees = None
         init_val_errors = None
         if os.path.isfile(ck_path):

@@ -1,0 +1,190 @@
+"""Ask the chip's compiler, without the chip: the main path's kernels at
+bench widths compile for a DESCRIBED TPU v5e (on-chip-measurement guide,
+section 2). Interpret mode (tests/test_hist_pallas.py) cannot see what
+Mosaic refuses — scoped-VMEM overflow, unaligned slices — and PR 22 found
+the fused kernel refused at every level past L = 2 that way.
+
+The topology is described INSIDE a module-scoped fixture, never at
+import / collection: only one process may load libtpu, and every xdist
+worker imports every test file. Keep these tests in this one file (a
+second file can land on another worker, whose fixture then skips).
+Nothing here runs on a device; a compile that passes is not a chip run.
+"""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from shifu_tpu.ops import hist_pallas as hp  # noqa: E402
+from shifu_tpu.train import tree_trainer as tt  # noqa: E402
+
+# the three bench layouts (bench.py GBT / _rf_slots / _gbt_wide_slots)
+LAYOUTS = {
+    "gbt": ([33] * 30, [False] * 30),
+    "rf": ([33] * 20 + [65] * 10, [False] * 20 + [True] * 10),
+    "gbt_wide": ([33] * 180 + [65] * 19 + [2001],
+                 [False] * 180 + [True] * 20),
+}
+N_ROWS = 65_536
+L_MAX = tt._FUSED_SCAN_L_CAP  # the largest level the static rule fuses
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no such topology here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip executable can be written to the persistent cache
+    # but not read back without a chip: keep the cache off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _row_args(one_chip, F):
+    s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    return (s((N_ROWS, F), jnp.int32), s((N_ROWS,), jnp.float32),
+            s((N_ROWS,), jnp.float32), s((N_ROWS,), jnp.int32),
+            s((N_ROWS,), jnp.bool_))
+
+
+def _compiled_kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("lowp", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("L", [1, 64])
+def test_hist_kernel_compiles(one_chip, L, lowp):
+    slots, is_cat = LAYOUTS["gbt"]
+    lay = tt.make_layout(slots, is_cat)
+    fn = hp.make_pallas_hist_fn(L, lay, low_precision=lowp)
+    compiled = jax.jit(fn).lower(*_row_args(one_chip, len(slots))).compile()
+    assert _compiled_kernels(compiled) == len(hp._chunks(lay))
+
+
+def _distinct_kernels(lay):
+    """{kernel signature: representative chunk index}. The per-column
+    metadata rides in as kernel INPUTS, so two chunks with the same
+    (padded width, feature count, code dtype) are the same Mosaic
+    program — gbt_wide's 54 chunks are 4 distinct kernels, and compiling
+    one of each asks the compiler everything the whole layout would."""
+    seen = {}
+    for ci, ch in enumerate(hp._chunks(lay, hp._SCAN_W_CAP)):
+        seen.setdefault((ch.w, ch.f_hi - ch.f_lo, ch.narrow), ci)
+    return seen
+
+
+# f32 planes are RF's, bf16 planes are GBT's (tree_trainer._low_precision)
+@pytest.mark.parametrize("layout,lowp", [
+    ("gbt", True), ("rf", False), ("gbt_wide", True)])
+@pytest.mark.parametrize("L", [1, L_MAX], ids=["Lmin", "Lmax"])
+def test_fused_kernels_compile_where_the_rule_admits(one_chip, layout,
+                                                     lowp, L):
+    slots, is_cat = LAYOUTS[layout]
+    lay = tt.make_layout(slots, is_cat)
+    kinds = _distinct_kernels(lay)
+    assert max(w for (w, _nf, _i8) in kinds) <= hp._SCAN_W_CAP
+    blk, C = hp.blk_setting(), 3
+    comp_dt = jnp.bfloat16 if lowp else jnp.float32
+    scan_key = ("variance" if lowp else "entropy", 1, 0.0, 0)
+    calls, args = [], []
+    for (w, nf, narrow), ci in kinds.items():
+        calls.append(hp._build_call(lay.key, hp._SCAN_W_CAP, ci, L, C, blk,
+                                    narrow, lowp, scan_key, False))
+        args.append((
+            _shape(one_chip, (N_ROWS, nf),
+                   jnp.int8 if narrow else jnp.int32),
+            _shape(one_chip, (N_ROWS, C), comp_dt),
+            _shape(one_chip, (N_ROWS, 1), jnp.int32),
+            _shape(one_chip, (1, w), jnp.float32)))
+
+    def every_kind(*chunk_args):
+        return [call(*a) for call, a in zip(calls, chunk_args)]
+
+    compiled = jax.jit(every_kind).lower(*args).compile()
+    assert _compiled_kernels(compiled) == len(kinds)
+
+
+@pytest.mark.parametrize("layout", ["gbt", "gbt_wide"])
+def test_fused_level_entry_compiles(one_chip, layout):
+    """The entry the grower calls, whole: every chunk's kernel plus the
+    XLA epilogue, and on gbt_wide the XLA fallback scan of the
+    2,001-slot column (too wide for one in-kernel chunk)."""
+    slots, is_cat = LAYOUTS[layout]
+    lay = tt.make_layout(slots, is_cat)
+    fn = hp.make_fused_level_fn(1, lay, "variance", 1, 0.0,
+                                low_precision=True)
+    codes, labels, weights, node, active = _row_args(one_chip, len(slots))
+    compiled = jax.jit(fn).lower(
+        codes, _shape(one_chip, codes.shape, jnp.int8), labels, weights,
+        node, active, _shape(one_chip, (lay.T,), jnp.bool_)).compile()
+    assert _compiled_kernels(compiled) == len(
+        hp._chunks(lay, hp._SCAN_W_CAP))
+    assert hp.wide_features(lay, hp._SCAN_W_CAP) == (
+        [199] if layout == "gbt_wide" else [])
+
+
+def test_nn_train_step_compiles_at_small_width(one_chip):
+    from shifu_tpu.models.nn import flatten_params, init_params
+    from shifu_tpu.train import nn_trainer as nt
+
+    rows, d = 1_000_000, 30  # bench.py SMALL: 30 -> [50] -> 1
+    cfg = nt.NNTrainConfig(hidden_nodes=[50], activations=["tanh"],
+                           mixed_precision=True)
+    flat0, shapes = flatten_params(
+        init_params([d, 50, 1], seed=0, init=cfg.weight_init))
+    program, init_state = nt._get_program(cfg, shapes, rows)
+    flat = jnp.asarray(flat0)
+    carry = (flat, init_state(flat0.size), jnp.int32(0), jnp.float32(0.1),
+             jnp.float32(np.inf), flat, jnp.int32(0),
+             jnp.zeros((), dtype=bool), jnp.float32(0.0), jnp.float32(0.0))
+    row = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    args = (carry, jnp.int32(5),
+            jax.ShapeDtypeStruct((rows, d), jnp.float32), row, row, row,
+            jax.random.PRNGKey(0), jnp.float32(1.0))
+    args = jax.tree_util.tree_map(
+        lambda a: _shape(one_chip, a.shape, a.dtype), args)
+    compiled = program.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+def test_levels_beyond_the_rule_go_to_the_hist_kernel(monkeypatch):
+    """The static rule, as the grower applies it: levels with
+    2**d <= _FUSED_SCAN_L_CAP build the fused kernel (chunked to
+    _SCAN_W_CAP), deeper ones the hist-mode kernel + XLA scan. Builders
+    only — nothing is traced or compiled here."""
+    fused_L, hist_L = [], []
+    monkeypatch.setattr(hp, "pallas_active", lambda: (True, False))
+    monkeypatch.setattr(
+        hp, "make_fused_level_fn",
+        lambda L, *a, **k: fused_L.append(L) or (lambda *x: None))
+    monkeypatch.setattr(
+        hp, "make_pallas_hist_fn",
+        lambda L, *a, **k: hist_L.append(L) or (lambda *x: None))
+    lay = tt.make_layout([33] * 7, [False] * 7)  # a layout no test shares
+    key_before = set(tt._PROGRAMS)
+    try:
+        tt._get_tree_program(8, lay, "variance", 1, 0.0, lowp=True)
+    finally:
+        for k in set(tt._PROGRAMS) - key_before:
+            del tt._PROGRAMS[k]  # built from stubs: never reuse
+    assert fused_L == [1, 2, 4, 8, 16, 32] and L_MAX == 32
+    assert hist_L == [64, 128]
+    assert hp._SCAN_W_CAP == 512
