@@ -11,6 +11,11 @@ reset (new step, bench scenario, test) transparently redirects recording:
     with span("stats.pass2", chunks=k):
         ...
 
+A span is a ring event on the host's `perf_counter` AND, for its duration,
+a `jax.profiler.TraceAnnotation("shifu.stats.pass2", chunks=k)`: whenever a
+profiler session is running it lands in that capture on the device trace's
+own clock (obs/tracing.py).
+
 Nested processor runs (combo invoking stats/norm/...) keep the outer step's
 registry: only depth-0 begin_run() resets, every depth writes its own
 manifest.

@@ -71,9 +71,12 @@ class Counter:
         self._lock = tracked_lock("obs.metrics.counter")
         self._value = 0.0
 
-    def inc(self, n: float = 1.0) -> None:
+    def inc(self, n: float = 1.0) -> float:
+        """Add `n`; returns the value after it, so that a caller who counts
+        calls has each call's sequence number from the same locked step."""
         with self._lock:
             self._value += n
+            return self._value
 
     @property
     def value(self) -> float:

@@ -36,6 +36,7 @@ import threading
 import time
 
 from shifu_tpu.analysis.racetrack import tracked_lock
+from shifu_tpu.obs import jaxprobe, tracing
 from collections import OrderedDict
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -478,22 +479,29 @@ def _dispatch_inner(name, fn, args, kwargs, sync,
         sig = None
     if sig is None:  # tracer context or unhashable signature
         return fn(*args, **kwargs)
+    # a process that goes straight to a trainer (no BasicProcessor.run)
+    # still records which program traced, lowered and compiled
+    jaxprobe.install()
     entry = _cost_entry(name, fn, sig, args, kwargs)
     scale = _current_scale()
     t0 = time.perf_counter()
-    if entry.compiled is not None:
-        try:
-            out = entry.compiled(*dyn_args, **dyn_kwargs)
-        except (TypeError, ValueError):
-            # AOT call convention mismatch: permanent per-entry fallback
-            entry.compiled = None
+    # annotation only, no ring event: this seam is per request in serve/
+    # and per chunk in stats/
+    with tracing.profiler_annotation(
+            tracing.PROFILER_PREFIX + "prog." + name):
+        if entry.compiled is not None:
+            try:
+                out = entry.compiled(*dyn_args, **dyn_kwargs)
+            except (TypeError, ValueError):
+                # AOT call convention mismatch: permanent per-entry fallback
+                entry.compiled = None
+                out = fn(*args, **kwargs)
+        else:
             out = fn(*args, **kwargs)
-    else:
-        out = fn(*args, **kwargs)
-    if sync:
-        import jax
+        if sync:
+            import jax
 
-        out = jax.block_until_ready(out)
+            out = jax.block_until_ready(out)
     _profiler.record_dispatch(name, entry, scale,
                               time.perf_counter() - t0, sync)
     return out

@@ -5,6 +5,19 @@ attributes; the collected events serialize to the Chrome-trace JSON format
 (`chrome://tracing` / Perfetto "traceEvents" with ph="X" complete events),
 one file per lifecycle step next to the run manifest (obs/ledger.py).
 
+Every span also opens `jax.profiler.TraceAnnotation("shifu." + name)` for
+its duration, with the span's scalar attributes as the event's stats. With a
+`jax.profiler` session running (`-Dshifu.profile=xla`, a benchmark's traced
+run) the span therefore lands on the host plane of the xplane file, on the
+device trace's own clock, beside the device's operations; with none an
+annotation is an atomic load. A process that never imported jax has no
+session to write into, so host-only steps import nothing for this.
+
+The ring's events stay on `time.perf_counter()`: `Tracer.t0` is the anchor
+their `ts` counts from, and `Tracer.between(lo, hi)` picks the events that
+end between two `perf_counter` stamps (a measured window, without its
+warm-up).
+
 Thread-safe: the streaming pipeline's prefetch worker opens spans on its own
 thread; events carry the recording thread id so overlap between the parse
 thread and the device thread is visible as parallel tracks.
@@ -20,17 +33,45 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 
 from shifu_tpu.analysis.racetrack import tracked_lock
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, Iterator, List, Optional
 
 from shifu_tpu.utils import environment
 
 DEFAULT_MAX_EVENTS = 65536
+
+
+PROFILER_PREFIX = "shifu."  # a span's name in the profiler's trace
+
+# os.getpid() is a system call, and a v5e host read 5.7 us for it against
+# 0.17 us in the sandbox (my chip run, PR 26): asked once a process, not
+# once an event (jax fires 11,000 trace events for one whole-tree program)
+_PID = os.getpid()
+
+
+def _pid_after_fork() -> None:
+    global _PID
+    _PID = os.getpid()
+
+
+os.register_at_fork(after_in_child=_pid_after_fork)
+
+
+def profiler_annotation(name: str, **stats: Any):
+    """`jax.profiler.TraceAnnotation(name, **stats)`, or a context that does
+    nothing in a process that has not imported jax (no profiler session can
+    be running there)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None while jax still imports
+    if profiler is None:
+        return nullcontext()
+    return profiler.TraceAnnotation(name, **stats)
 
 
 def max_events_setting() -> int:
@@ -46,8 +87,8 @@ class Tracer:
         self._events: deque = deque(maxlen=self.max_events)
         self._dropped = 0
         self._local = threading.local()
-        # one wall-clock anchor so perf_counter offsets render as absolute-ish
-        self._t0 = time.perf_counter()
+        # the perf_counter stamp every event's `ts` counts from
+        self.t0 = time.perf_counter()
 
     def _stack(self) -> List[str]:
         st = getattr(self._local, "stack", None)
@@ -65,40 +106,61 @@ class Tracer:
         """Record a nested span; yields the mutable attrs dict so callers can
         attach results discovered mid-span (row counts, output paths)."""
         stack = self._stack()
+        parent = "/".join(stack)
         stack.append(name)
         args = dict(attrs)
         t0 = time.perf_counter()
         try:
-            yield args
+            with profiler_annotation(
+                    PROFILER_PREFIX + name,
+                    **{k: v for k, v in args.items()
+                       if isinstance(v, (str, int, float))}):
+                yield args
         finally:
-            t1 = time.perf_counter()
             stack.pop()
-            event = {
-                "name": name,
-                "ph": "X",
-                "ts": (t0 - self._t0) * 1e6,  # Chrome trace wants microseconds
-                "dur": (t1 - t0) * 1e6,
-                "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "args": {k: _jsonable(v) for k, v in args.items()},
-            }
-            if stack:
-                event["args"]["parent"] = "/".join(stack)
-            overflow = False
-            with self._lock:
-                if len(self._events) == self._events.maxlen:
-                    self._dropped += 1  # deque evicts the oldest span
-                    overflow = True
-                self._events.append(event)
-            if overflow:
-                from shifu_tpu.obs import registry
+            self.record(name, t0, time.perf_counter(), parent, args)
 
-                registry().counter("trace.dropped").inc()
+    def record(self, name: str, start: float, end: float, parent: str = "",
+               args: Optional[Dict[str, Any]] = None) -> None:
+        """Append one finished span, `start` and `end` being `perf_counter`
+        stamps: what `span` does at its exit, and how an event that arrives
+        with its duration already taken (obs/jaxprobe.py) joins the ring."""
+        event = {
+            "name": name,
+            "ph": "X",
+            "ts": (start - self.t0) * 1e6,  # Chrome trace wants microseconds
+            "dur": (end - start) * 1e6,
+            "pid": _PID,
+            "tid": threading.get_ident(),
+            "args": {k: _jsonable(v) for k, v in (args or {}).items()},
+        }
+        if parent:
+            event["args"]["parent"] = parent
+        overflow = False
+        with self._lock:
+            if len(self._events) == self._events.maxlen:
+                self._dropped += 1  # deque evicts the oldest span
+                overflow = True
+            self._events.append(event)
+        if overflow:
+            from shifu_tpu.obs import registry
+
+            registry().counter("trace.dropped").inc()
 
     @property
     def events(self) -> List[dict]:
         with self._lock:
             return [dict(e) for e in self._events]
+
+    def between(self, lo: float, hi: float, prefix: str = "") -> List[dict]:
+        """The events that END inside [lo, hi], both `perf_counter` stamps
+        (the clock a caller times its own window on), whose name starts with
+        `prefix`; in the order they ended."""
+        lo_us, hi_us = (lo - self.t0) * 1e6, (hi - self.t0) * 1e6
+        with self._lock:
+            return [dict(e) for e in self._events
+                    if e["name"].startswith(prefix)
+                    and lo_us <= e["ts"] + e["dur"] <= hi_us]
 
     @property
     def dropped(self) -> int:

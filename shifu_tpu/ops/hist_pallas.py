@@ -256,6 +256,12 @@ def wide_features(lay, target: Optional[int] = None) -> List[int]:
             if _pad_lane(s) > target]
 
 
+def kernel_name(do_scan: bool) -> str:
+    """The name the kernel carries in the compiled program (`pallas_call`'s
+    `name` and `metadata["kernel"]`) and in the run manifest."""
+    return "tree_fused_level" if do_scan else "tree_hist"
+
+
 @functools.lru_cache(maxsize=None)
 def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
                 blk: int, code_i8: bool, lowp: bool, scan_key,
@@ -280,6 +286,7 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
     W = ch.w
     nf = ch.f_hi - ch.f_lo
     do_scan = scan_key is not None
+    name = kernel_name(do_scan)
     comp_dt = jnp.bfloat16 if lowp else jnp.float32
     m_dt = comp_dt
     if do_scan:
@@ -538,6 +545,12 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
             out_shape=out_shape,
             scratch_shapes=scratch,
             interpret=interpret,
+            name=name,
+            # `name` is the op's kernel_name attribute, which the HLO text
+            # of a profiler trace does not print; `metadata` is printed,
+            # as frontend_attributes={kernel_metadata={...}}, and is what
+            # tells this kernel and its level from any other in a trace
+            metadata={"kernel": name, "L": str(L)},
         )(*args)
         return outs
 
@@ -573,8 +586,8 @@ def _annotate(lay, chunks, L, do_scan, lowp, i8_chunks, interpret):
     from shifu_tpu.obs import profile as _profile
 
     _profile.annotate(
-        "ops.hist_pallas", blk=blk_setting(), wMax=wmax_setting(),
-        chunks=len(chunks), L=int(L), T=int(lay.T),
+        "ops.hist_pallas", kernel=kernel_name(do_scan), blk=blk_setting(),
+        wMax=wmax_setting(), chunks=len(chunks), L=int(L), T=int(lay.T),
         paddedT=int(sum(c.w for c in chunks)), fusedScan=bool(do_scan),
         bf16Planes=bool(lowp), int8Chunks=int(i8_chunks),
         mode=pallas_mode(), interpret=bool(interpret))

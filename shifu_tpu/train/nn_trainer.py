@@ -40,7 +40,7 @@ from shifu_tpu.models.nn import (
     init_params,
     unflatten_params,
 )
-from shifu_tpu.obs import profile
+from shifu_tpu.obs import profile, registry, span
 from shifu_tpu.resilience.checkpoint import atomic_save_npy
 from shifu_tpu.train.updaters import make_updater
 from shifu_tpu.utils.log import get_logger
@@ -282,30 +282,39 @@ def _loss_and_errors(cfg: NNTrainConfig, shapes):
 
     def total_loss(flat, x, t, sig, key):
         params = unflatten(flat)
-        p = fwd(params, x, key, train=True)
+        with jax.named_scope("nn.fwd"):
+            p = fwd(params, x, key, train=True)
         return jnp.sum(sig * record_loss(p, ideal_of(t))), p
 
     grad_fn = jax.grad(total_loss, has_aux=True)
 
+    # Named scopes are op metadata only (the `tf_op` a profiler trace shows
+    # for each device operation). Under `nn.bwd` jax names the forward half
+    # `jvp(nn.fwd)` and the backward half `transpose(jvp(nn.fwd))`.
     def step_metrics(flat, x, t, sig_train, sig_valid, key):
-        g_neg, p_train = grad_fn(flat, x, t, sig_train, key)
-        g = -g_neg  # descent direction, summed over records
-        if dropout > 0.0:
-            # dropout-free predictions for error reporting
-            p = fwd(unflatten(flat), x, key, train=False)
-        else:
-            p = p_train
-        # reported errors are squared-error means like Encog calculateError
-        # (multi-class: mean over the K output neurons as well); the SVM
-        # decision value maps through sigmoid first so its error lives on
-        # the same [0,1] scale (saved models score sigmoid(w.x+b) too)
-        if hinge:
-            p = activation_fn("sigmoid")(p)
-        sq = (ideal_of(t) - p) ** 2
-        if out_dim > 1:
-            sq = sq.mean(axis=-1)
-        train_err = jnp.sum(sig_train * sq) / jnp.maximum(jnp.sum(sig_train), 1.0)
-        valid_err = jnp.sum(sig_valid * sq) / jnp.maximum(jnp.sum(sig_valid), 1.0)
+        with jax.named_scope("nn.bwd"):
+            g_neg, p_train = grad_fn(flat, x, t, sig_train, key)
+            g = -g_neg  # descent direction, summed over records
+        with jax.named_scope("nn.valid"):
+            if dropout > 0.0:
+                # dropout-free predictions for error reporting
+                p = fwd(unflatten(flat), x, key, train=False)
+            else:
+                p = p_train
+            # reported errors are squared-error means like Encog
+            # calculateError (multi-class: mean over the K output neurons
+            # as well); the SVM decision value maps through sigmoid first
+            # so its error lives on the same [0,1] scale (saved models
+            # score sigmoid(w.x+b) too)
+            if hinge:
+                p = activation_fn("sigmoid")(p)
+            sq = (ideal_of(t) - p) ** 2
+            if out_dim > 1:
+                sq = sq.mean(axis=-1)
+            train_err = (jnp.sum(sig_train * sq)
+                         / jnp.maximum(jnp.sum(sig_train), 1.0))
+            valid_err = (jnp.sum(sig_valid * sq)
+                         / jnp.maximum(jnp.sum(sig_valid), 1.0))
         return g, train_err, valid_err
 
     return step_metrics
@@ -361,7 +370,8 @@ def _get_program(cfg: NNTrainConfig, shapes, rows: int):
             _, tr, va = step_metrics(flat, x, t, sig_train, sig_valid, key)
         else:
             g, tr, va = step_metrics(flat, x, t, sig_train, sig_valid, key)
-        new_flat, new_opt = apply_update(opt, flat, g, lr, it + 1, nts)
+        with jax.named_scope("nn.update"):
+            new_flat, new_opt = apply_update(opt, flat, g, lr, it + 1, nts)
         improved = va < best_val
         best_val2 = jnp.where(improved, va, best_val)
         # va was measured on the PRE-update weights; keep those as "best"
@@ -411,120 +421,126 @@ def train_nn(
     import jax
     import jax.numpy as jnp
 
-    n, d = features.shape
-    out_dim = cfg.n_classes if cfg.n_classes > 2 else 1
-    layer_sizes = [d] + list(cfg.hidden_nodes) + [out_dim]
-    params0 = init_params(layer_sizes, seed=cfg.seed, init=cfg.weight_init)
-    flat0, shapes = flatten_params(params0)
-    if init_flat is not None and init_flat.size == flat0.size:
-        flat0 = init_flat.astype(np.float32)  # continuous training resume
-    n_flat = flat0.size
+    # the body stays in this frame (see train_trees: a frame more under a
+    # program's first dispatch is paid for in its tracing)
+    call = int(registry().counter("train.calls", engine="nn").inc())
+    with span("train.nn.call", call=call, rows=int(features.shape[0]),
+              epochs=int(cfg.num_epochs)):
+        with span("train.nn.prologue", call=call):
+            n, d = features.shape
+            out_dim = cfg.n_classes if cfg.n_classes > 2 else 1
+            layer_sizes = [d] + list(cfg.hidden_nodes) + [out_dim]
+            params0 = init_params(layer_sizes, seed=cfg.seed, init=cfg.weight_init)
+            flat0, shapes = flatten_params(params0)
+            if init_flat is not None and init_flat.size == flat0.size:
+                flat0 = init_flat.astype(np.float32)  # continuous training resume
+            n_flat = flat0.size
 
-    # ---- shard rows over the mesh; pad to even splits with zero significance
-    # features may already live on device (bench / repeated runs): don't pull
-    # it back to host, HBM residency is the point
-    x = features if isinstance(features, jax.Array) else features.astype(np.float32)
-    t = tags if isinstance(tags, jax.Array) else tags.astype(np.float32)
-    if mesh is not None:
-        from shifu_tpu.parallel.mesh import pad_rows, shard_rows
+            # ---- shard rows over the mesh; pad to even splits with zero significance
+            # features may already live on device (bench / repeated runs): don't pull
+            # it back to host, HBM residency is the point
+            x = features if isinstance(features, jax.Array) else features.astype(np.float32)
+            t = tags if isinstance(tags, jax.Array) else tags.astype(np.float32)
+            if mesh is not None:
+                from shifu_tpu.parallel.mesh import pad_rows, shard_rows
 
-        sig, valid_mask = split_and_sample(n, cfg)
-        sig_train = (sig * np.asarray(weights)).astype(np.float32)
-        sig_valid = (valid_mask.astype(np.float32)
-                     * np.asarray(weights)).astype(np.float32)
-        n_train_size = float(max(sig.sum(), 1.0))
-        n_dev = mesh.devices.size
-        (x, t, sig_train, sig_valid), _ = pad_rows(
-            [x, t, sig_train, sig_valid], n_dev
+                sig, valid_mask = split_and_sample(n, cfg)
+                sig_train = (sig * np.asarray(weights)).astype(np.float32)
+                sig_valid = (valid_mask.astype(np.float32)
+                             * np.asarray(weights)).astype(np.float32)
+                n_train_size = float(max(sig.sum(), 1.0))
+                n_dev = mesh.devices.size
+                (x, t, sig_train, sig_valid), _ = pad_rows(
+                    [x, t, sig_train, sig_valid], n_dev
+                )
+                x = shard_rows(x, mesh)
+                t = shard_rows(t, mesh)
+                sig_train = shard_rows(sig_train, mesh)
+                sig_valid = shard_rows(sig_valid, mesh)
+            else:
+                # single device: the deterministic draw lives in a device cache and
+                # the weight product happens on device — repeat runs transfer zero
+                # sampling bytes. Host inputs are placed EXPLICITLY here (one
+                # device_put, not an implicit per-dispatch transfer) so the
+                # program dispatch below is a transfer-free sanitizer seam.
+                if not isinstance(x, jax.Array):
+                    x = jax.device_put(x)
+                if not isinstance(t, jax.Array):
+                    t = jax.device_put(t)
+                sig_d, valid_d, n_train_size = _device_split_and_sample(n, cfg)
+                w_d = (weights if isinstance(weights, jax.Array)
+                       else jax.device_put(np.asarray(weights, np.float32)))
+                sig_train = sig_d * w_d
+                sig_valid = valid_d * w_d
+
+            rows = x.shape[0]
+            max_iters = cfg.num_epochs
+            program, init_state = _get_program(cfg, shapes, rows)
+            opt0 = init_state(n_flat)
+
+            flat_j = jnp.asarray(flat0)
+            if mesh is not None:
+                from shifu_tpu.parallel.mesh import replicate
+
+                flat_j = replicate(flat_j, mesh)
+                opt0 = replicate(opt0, mesh)
+
+            carry0 = (
+                flat_j, opt0, jnp.int32(0), jnp.float32(cfg.learning_rate),
+                jnp.float32(np.inf), flat_j, jnp.int32(0),
+                jnp.zeros((), dtype=bool), jnp.float32(0.0), jnp.float32(0.0),
+            )
+            key0 = jax.random.PRNGKey(cfg.seed)
+            nts = jnp.float32(n_train_size)
+
+        def run_until(carry, limit):
+            # sanitizer seam: every operand is device-resident by here (the
+            # scalar conversion included), so the program dispatch itself
+            # must be transfer-free (-Dshifu.sanitize=transfer). Profiled
+            # sync (the caller pulls scalars right after anyway); the
+            # enclosing scaled() context credits one loop body per epoch.
+            with span("train.nn.program", call=call, limit=int(limit)):
+                limit_j = jnp.int32(limit)
+                with sanitize.transfer_free("nn.program"):
+                    return profile.dispatch(
+                        "nn.train_program", program, carry, limit_j, x, t,
+                        sig_train, sig_valid, key0, nts, sync=True)
+
+        if cfg.checkpoint_every and cfg.checkpoint_every > 0:
+            result = _run_with_checkpoints(run_until, carry0, cfg, max_iters)
+        else:
+            with profile.scaled(max_iters):
+                result = run_until(carry0, max_iters)
+
+        (flat_f, _, it_f, _, best_val, best_flat, _, _, tr_e, va_e) = result
+        # ONE host round-trip for all scalars (serial float()/int() casts each
+        # pay a full RTT on remote TPU links)
+        with span("train.nn.pull", call=call) as pulled:
+            scalars = jax.device_get((it_f, best_val, tr_e, va_e))
+            it_n, bv, tr_h, va_h = (a.item() for a in scalars)
+            pulled["bytes"] = sum(a.nbytes for a in scalars)
+            it_n = int(it_n)
+            final_valid = float(bv) if math.isfinite(bv) else float(va_h)
+            use_best = cfg.valid_set_rate > 0 and math.isfinite(bv)
+            if fetch_params:
+                chosen = (np.asarray(best_flat) if use_best
+                          else np.asarray(flat_f))
+                pulled["bytes"] += chosen.nbytes
+        params = unflatten_params(chosen, shapes) if fetch_params else None
+        reg = registry()
+        reg.gauge("train.train_error").set(float(tr_h))
+        reg.gauge("train.valid_error").set(final_valid)
+        reg.counter("train.iterations").inc(it_n)
+        log.info(
+            "train done: %d iterations, train_err %.6f valid_err %.6f",
+            it_n, tr_h, final_valid,
         )
-        x = shard_rows(x, mesh)
-        t = shard_rows(t, mesh)
-        sig_train = shard_rows(sig_train, mesh)
-        sig_valid = shard_rows(sig_valid, mesh)
-    else:
-        # single device: the deterministic draw lives in a device cache and
-        # the weight product happens on device — repeat runs transfer zero
-        # sampling bytes. Host inputs are placed EXPLICITLY here (one
-        # device_put, not an implicit per-dispatch transfer) so the
-        # program dispatch below is a transfer-free sanitizer seam.
-        if not isinstance(x, jax.Array):
-            x = jax.device_put(x)
-        if not isinstance(t, jax.Array):
-            t = jax.device_put(t)
-        sig_d, valid_d, n_train_size = _device_split_and_sample(n, cfg)
-        w_d = (weights if isinstance(weights, jax.Array)
-               else jax.device_put(np.asarray(weights, np.float32)))
-        sig_train = sig_d * w_d
-        sig_valid = valid_d * w_d
-
-    rows = x.shape[0]
-    max_iters = cfg.num_epochs
-    program, init_state = _get_program(cfg, shapes, rows)
-    opt0 = init_state(n_flat)
-
-    flat_j = jnp.asarray(flat0)
-    if mesh is not None:
-        from shifu_tpu.parallel.mesh import replicate
-
-        flat_j = replicate(flat_j, mesh)
-        opt0 = replicate(opt0, mesh)
-
-    carry0 = (
-        flat_j, opt0, jnp.int32(0), jnp.float32(cfg.learning_rate),
-        jnp.float32(np.inf), flat_j, jnp.int32(0),
-        jnp.zeros((), dtype=bool), jnp.float32(0.0), jnp.float32(0.0),
-    )
-    key0 = jax.random.PRNGKey(cfg.seed)
-    nts = jnp.float32(n_train_size)
-
-    def run_until(carry, limit):
-        # sanitizer seam: every operand is device-resident by here (the
-        # scalar conversion included), so the program dispatch itself
-        # must be transfer-free (-Dshifu.sanitize=transfer). Profiled
-        # sync (the caller pulls scalars right after anyway); the
-        # enclosing scaled() context credits one loop body per epoch.
-        limit_j = jnp.int32(limit)
-        with sanitize.transfer_free("nn.program"):
-            return profile.dispatch(
-                "nn.train_program", program, carry, limit_j, x, t,
-                sig_train, sig_valid, key0, nts, sync=True)
-
-    if cfg.checkpoint_every and cfg.checkpoint_every > 0:
-        result = _run_with_checkpoints(run_until, carry0, cfg, max_iters)
-    else:
-        with profile.scaled(max_iters):
-            result = run_until(carry0, max_iters)
-
-    (flat_f, _, it_f, _, best_val, best_flat, _, _, tr_e, va_e) = result
-    # ONE host round-trip for all scalars (serial float()/int() casts each
-    # pay a full RTT on remote TPU links)
-    it_n, bv, tr_h, va_h = map(
-        lambda a: a.item(), jax.device_get((it_f, best_val, tr_e, va_e)))
-    it_n = int(it_n)
-    final_valid = float(bv) if math.isfinite(bv) else float(va_h)
-    use_best = cfg.valid_set_rate > 0 and math.isfinite(bv)
-    if fetch_params:
-        chosen = (np.asarray(best_flat) if use_best
-                  else np.asarray(flat_f))
-        params = unflatten_params(chosen, shapes)
-    else:
-        params = None
-    from shifu_tpu.obs import registry
-
-    reg = registry()
-    reg.gauge("train.train_error").set(float(tr_h))
-    reg.gauge("train.valid_error").set(final_valid)
-    reg.counter("train.iterations").inc(it_n)
-    log.info(
-        "train done: %d iterations, train_err %.6f valid_err %.6f",
-        it_n, tr_h, final_valid,
-    )
-    return TrainResult(
-        params=params,
-        train_error=float(tr_h),
-        valid_error=final_valid,
-        iterations=it_n,
-    )
+        return TrainResult(
+            params=params,
+            train_error=float(tr_h),
+            valid_error=final_valid,
+            iterations=it_n,
+        )
 
 
 def train_nn_bagged(
@@ -758,7 +774,7 @@ def _run_with_checkpoints(run_until, carry, cfg, max_iters):
     while it < max_iters:
         limit = min(it + every, max_iters)
         with profile.scaled(limit - it):  # loop bodies this segment runs
-            carry = run_until(carry, jnp.int32(limit))
+            carry = run_until(carry, limit)
         it = int(carry[2])
         tr, va = float(carry[8]), float(carry[9])
         if cfg.progress_cb:

@@ -64,7 +64,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from shifu_tpu.obs import profile
+from shifu_tpu.obs import profile, registry, span
 
 from shifu_tpu.models.tree import DenseTree, TreeModelSpec
 from shifu_tpu.utils.log import get_logger
@@ -1303,6 +1303,16 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
             return fn(hist, feat_ok_t, is_cat_c, seg_c, pos_c, start_c,
                       size_c, off_c, clip_c, seg0)
 
+        # One named scope a level and one a phase inside it: op metadata
+        # only (the `tf_op` a profiler trace shows for each device
+        # operation), so device time can be told by level and phase
+        # whatever the fusions are numbered. `hist` is the kernel or
+        # `call_hist` with its pads and casts, `derive` the subtraction
+        # and interleave, `scan` the XLA scan where it runs, `route` the
+        # rows' move to their children.
+        def phase(L, what):
+            return jax.named_scope("tree.L%d/%s" % (L, what))
+
         for d in range(D):
             L = 2**d
             if prev is not None and fuse_at[d - 1]:
@@ -1311,66 +1321,86 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                 # pass), derive the sibling as parent − built and scan it
                 # with the XLA reference, then interleave per parent
                 p_hist, p_split, p_lcnt, p_ncnt = prev
-                left_small = p_lcnt <= p_ncnt - p_lcnt
-                nhalf, build_row = _sub_row_masks(node, active, left_small)
-                built, scan_b = fused_fns[d - 1](
-                    codes, codes8, labels, weights, nhalf, build_row,
-                    feat_ok_t)
-                b_acc = built.astype(p_hist.dtype)
-                derived = jnp.where(p_split[None, :, None],
-                                    p_hist - b_acc,
-                                    jnp.zeros_like(p_hist))
-                scan_d = xla_scan(d - 1, derived.astype(jnp.float32),
-                                  raw=True)
-                (bf, br, rank_flat, lv, is_split, _g, lm, nc, lc) = tuple(
-                    _interleave_children(left_small, xb, xd)
-                    for xb, xd in zip(scan_b, scan_d))
-                hist_acc = jnp.concatenate(
-                    [_interleave_children(left_small, b_acc[c], derived[c])
-                     [None] for c in range(b_acc.shape[0])], axis=0)
+                with phase(L, "hist"):
+                    left_small = p_lcnt <= p_ncnt - p_lcnt
+                    nhalf, build_row = _sub_row_masks(node, active,
+                                                      left_small)
+                    built, scan_b = fused_fns[d - 1](
+                        codes, codes8, labels, weights, nhalf, build_row,
+                        feat_ok_t)
+                with phase(L, "derive"):
+                    b_acc = built.astype(p_hist.dtype)
+                    derived = jnp.where(p_split[None, :, None],
+                                        p_hist - b_acc,
+                                        jnp.zeros_like(p_hist))
+                with phase(L, "scan"):
+                    scan_d = xla_scan(d - 1, derived.astype(jnp.float32),
+                                      raw=True)
+                with phase(L, "derive"):
+                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                     lc) = tuple(
+                        _interleave_children(left_small, xb, xd)
+                        for xb, xd in zip(scan_b, scan_d))
+                    hist_acc = jnp.concatenate(
+                        [_interleave_children(left_small, b_acc[c],
+                                              derived[c])
+                         [None] for c in range(b_acc.shape[0])], axis=0)
             elif prev is None and fuse_at[d]:
-                hist, scan_t = fused_fns[d](codes, codes8, labels, weights,
-                                            node, active, feat_ok_t)
-                (bf, br, rank_flat, lv, is_split, _g, lm, nc, lc) = scan_t
-                hist_acc = hist.astype(acc_dt) if acc64 else hist
+                with phase(L, "hist"):
+                    hist, scan_t = fused_fns[d](codes, codes8, labels,
+                                                weights, node, active,
+                                                feat_ok_t)
+                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                     lc) = scan_t
+                    hist_acc = hist.astype(acc_dt) if acc64 else hist
             elif prev is not None:  # sub_levels[d]: derive from the parent
                 p_hist, p_split, p_lcnt, p_ncnt = prev
-                left_small = p_lcnt <= p_ncnt - p_lcnt
-                nhalf, build_row = _sub_row_masks(node, active, left_small)
-                built = call_hist(d - 1, nhalf, build_row)
-                hist, hist_acc = derive(p_hist, built, p_split, left_small)
-                (bf, br, rank_flat, lv, is_split, _g, lm, nc,
-                 lc) = xla_scan(d, hist)
+                with phase(L, "hist"):
+                    left_small = p_lcnt <= p_ncnt - p_lcnt
+                    nhalf, build_row = _sub_row_masks(node, active,
+                                                      left_small)
+                    built = call_hist(d - 1, nhalf, build_row)
+                with phase(L, "derive"):
+                    hist, hist_acc = derive(p_hist, built, p_split,
+                                            left_small)
+                with phase(L, "scan"):
+                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                     lc) = xla_scan(d, hist)
             else:
-                hist = call_hist(d, node, active)
-                hist_acc = hist.astype(acc_dt) if acc64 else hist
-                (bf, br, rank_flat, lv, is_split, _g, lm, nc,
-                 lc) = xla_scan(d, hist)
+                with phase(L, "hist"):
+                    hist = call_hist(d, node, active)
+                    hist_acc = hist.astype(acc_dt) if acc64 else hist
+                with phase(L, "scan"):
+                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                     lc) = xla_scan(d, hist)
             prev = ((hist_acc, is_split, lc, nc)
                     if d + 1 < D and sub_levels[d + 1] else None)
             base = L - 1
-            nl = jnp.clip(node, 0, L - 1)
-            settled = active & ~is_split[nl]
-            resting = jnp.where(settled, base + nl, resting)
-            f = jnp.where(is_split, bf, 0)[nl]
-            code = jnp.take_along_axis(codes, f[:, None], axis=1)[:, 0]
-            cf = off_c[f] + jnp.clip(code, 0, clip_c[f])
-            goes_left = rank_flat[nl, cf] <= br[nl]
-            still = is_split[nl] & active
-            node = jnp.where(still, jnp.where(goes_left, 2 * nl, 2 * nl + 1),
-                             0)
-            active = still
+            with phase(L, "route"):
+                nl = jnp.clip(node, 0, L - 1)
+                settled = active & ~is_split[nl]
+                resting = jnp.where(settled, base + nl, resting)
+                f = jnp.where(is_split, bf, 0)[nl]
+                code = jnp.take_along_axis(codes, f[:, None], axis=1)[:, 0]
+                cf = off_c[f] + jnp.clip(code, 0, clip_c[f])
+                goes_left = rank_flat[nl, cf] <= br[nl]
+                still = is_split[nl] & active
+                node = jnp.where(still,
+                                 jnp.where(goes_left, 2 * nl, 2 * nl + 1),
+                                 0)
+                active = still
             feats_l.append(jnp.where(is_split, bf, -1))
             masks_l.append(lm)
             leaves_l.append(lv)
 
         # final level: node totals only (no per-slot histogram)
         L2 = 2**D
-        acc = leaf_acc(labels, weights, node, active)
-        if on_mesh:
-            acc = jax.lax.psum(acc, r_axes)
-        leaves_l.append(leaf_finalize(acc))
-        resting = jnp.where(active, (L2 - 1) + node, resting)
+        with jax.named_scope("tree.leaf"):
+            acc = leaf_acc(labels, weights, node, active)
+            if on_mesh:
+                acc = jax.lax.psum(acc, r_axes)
+            leaves_l.append(leaf_finalize(acc))
+            resting = jnp.where(active, (L2 - 1) + node, resting)
         feat_flat = jnp.concatenate(
             feats_l + [jnp.full(L2, -1, jnp.int32)])
         mask_flat = jnp.concatenate(
@@ -1903,26 +1933,31 @@ def _score_existing(trees: List[DenseTree], codes) -> "object":
 
 
 def _assemble_deferred(trees: List, deferred: List[tuple],
-                       cfg: TreeTrainConfig, extra=None):
+                       cfg: TreeTrainConfig, extra=None, *, call: int,
+                       k: int):
     """Materialize fused-path trees from their device results. The backlog
     is stacked on device first so the host pull is ONE device_get of
     three contiguous arrays (plus the caller's `extra` pytree, fetched in
     the same round-trip), not three per tree — each pull is a host sync
-    that stalls the async dispatch chain. Returns the fetched `extra`."""
+    that stalls the async dispatch chain. Returns the fetched `extra`.
+    `call` and `k` (the newest tree of the backlog) label the spans: the
+    pull is a `train.tree.wait` inside the `train.tree.assemble`."""
     import jax
     import jax.numpy as jnp
 
-    f_all = jnp.stack([f for _k, _w, f, _m, _lv in deferred])
-    m_all = jnp.stack([m for _k, _w, _f, m, _lv in deferred])
-    l_all = jnp.stack([lv for _k, _w, _f, _m, lv in deferred])
-    fh_all, mh_all, lh_all, extra_h = jax.device_get(
-        (f_all, m_all, l_all, extra))
-    for i, (k, weight_k, _f, _m, _lv) in enumerate(deferred):
-        tree = _assemble_dense_tree(fh_all[i], mh_all[i], lh_all[i],
-                                    cfg.max_depth)
-        tree.weight = weight_k
-        trees[k] = tree  # trees list is indexed by global tree id
-    deferred.clear()
+    with span("train.tree.assemble", call=call, k=k):
+        f_all = jnp.stack([f for _k, _w, f, _m, _lv in deferred])
+        m_all = jnp.stack([m for _k, _w, _f, m, _lv in deferred])
+        l_all = jnp.stack([lv for _k, _w, _f, _m, lv in deferred])
+        with span("train.tree.wait", call=call, k=k):
+            fh_all, mh_all, lh_all, extra_h = jax.device_get(
+                (f_all, m_all, l_all, extra))
+        for i, (ki, weight_k, _f, _m, _lv) in enumerate(deferred):
+            tree = _assemble_dense_tree(fh_all[i], mh_all[i], lh_all[i],
+                                        cfg.max_depth)
+            tree.weight = weight_k
+            trees[ki] = tree  # trees list is indexed by global tree id
+        deferred.clear()
     return extra_h
 
 
@@ -1958,376 +1993,391 @@ def train_trees(
     import jax
     import jax.numpy as jnp
 
-    n, F = codes.shape
-    n_orig = n  # rng draws always use the UNpadded count so the stream (and
-    # therefore every tree) is identical with and without a mesh
-    valid_mask = np.random.default_rng([cfg.seed, 999_983]).random(n) \
-        < cfg.valid_set_rate
-    if mesh is not None:
-        from shifu_tpu.parallel.mesh import pad_rows, shard_rows
+    # No inner function for the body: what the first tree traces is traced
+    # from this frame, and one more Python frame under it cost the whole-tree
+    # program 3 s of its 15 s of tracing on a v5e host (PERF.md, PR 26).
+    call = int(registry().counter("train.calls", engine="tree").inc())
+    with span("train.trees.call", call=call, rows=int(codes.shape[0]),
+              trees=int(cfg.tree_num), depth=int(cfg.max_depth)):
+        with span("train.trees.prologue", call=call):
+            n, F = codes.shape
+            n_orig = n  # rng draws always use the UNpadded count so the stream (and
+            # therefore every tree) is identical with and without a mesh
+            valid_mask = np.random.default_rng([cfg.seed, 999_983]).random(n) \
+                < cfg.valid_set_rate
+            if mesh is not None:
+                from shifu_tpu.parallel.mesh import pad_rows, shard_rows
 
-        row_put = lambda a: shard_rows(a, mesh)  # noqa: E731
-        codes_np = np.asarray(codes, np.int32)
-        y_np = np.asarray(tags, np.float32)
-        base_w_np = np.where(valid_mask, 0.0,
-                             np.asarray(weights)).astype(np.float32)
-        real_np = np.ones(n, dtype=bool)
-        n_dev = mesh.devices.size
-        (codes_np, y_np, base_w_np, valid_mask, real_np), _ = pad_rows(
-            [codes_np, y_np, base_w_np, valid_mask, real_np], n_dev
-        )
-        n = codes_np.shape[0]
-        codes_j = shard_rows(codes_np, mesh)
-        y_j = shard_rows(y_np, mesh)
-        vm_j = shard_rows(valid_mask, mesh)
-        base_w_j = shard_rows(base_w_np, mesh)
-        real_j = shard_rows(real_np, mesh)
-    else:
-        # device-resident inputs stay on device (the code matrix is the
-        # big one and may already live in HBM from a previous run)
-        row_put = jnp.asarray
-        codes_j = (codes.astype(jnp.int32) if isinstance(codes, jax.Array)
-                   else jnp.asarray(np.asarray(codes, np.int32)))
-        y_j = (tags.astype(jnp.float32) if isinstance(tags, jax.Array)
-               else jnp.asarray(np.asarray(tags, np.float32)))
-        w_j = (weights.astype(jnp.float32)
-               if isinstance(weights, jax.Array)
-               else jnp.asarray(np.asarray(weights, np.float32)))
-        vm_j = jnp.asarray(valid_mask)
-        base_w_j = jnp.where(vm_j, 0.0, w_j)
-        real_j = jnp.ones(n, dtype=bool)
-    slots_np = np.asarray(slots, dtype=np.int32)
-    is_cat_np = np.asarray(is_cat, dtype=bool)
-
-    k_sub = subset_count(cfg.feature_subset_strategy, F)
-    leaf_wise = cfg.max_leaves and cfg.max_leaves > 0
-    if leaf_wise and mesh is not None:
-        log.warning("leaf-wise growth runs single-device; ignoring mesh")
-        mesh = None
-    trees: List[DenseTree] = list(init_trees or [])
-    start_k = len(trees)
-    lr = cfg.learning_rate
-    is_gbt = cfg.algorithm == "GBT"
-    log_loss = cfg.loss == "log"
-
-    reg_err = _get_errors_program()
-    errors_of = lambda score: reg_err(score, y_j, vm_j, real_j)  # noqa: E731
-
-    is_cls = cfg.n_classes >= 3
-    if is_cls and is_gbt:
-        raise ValueError(
-            "NATIVE multi-class tree training is RF-only (the reference "
-            "supports GBT multi-class via ONEVSALL, "
-            "TrainModelProcessor.java:341-349)"
-        )
-    if is_cls:
-        c_err = _get_cls_errors_program()
-        cls_errors_of = lambda votes: c_err(  # noqa: E731
-            votes, y_j, vm_j, real_j)
-
-    # prediction state re-derived from loaded trees on resume (the workers'
-    # recoverGBTData analog): GBT keeps the raw sum F(x), RF the running
-    # mean over trees built so far — classification keeps per-class VOTES
-    votes = None
-    if is_cls:
-        if start_k:
-            from shifu_tpu.models.tree import traverse_trees
-
-            per_tree = np.asarray(
-                traverse_trees(trees, codes_j))  # [n, k] class
-            votes_np = np.zeros((n, cfg.n_classes), np.float32)
-            for col in range(per_tree.shape[1]):
-                cls_idx = np.clip(per_tree[:, col].astype(np.int64), 0,
-                                  cfg.n_classes - 1)
-                votes_np[np.arange(n), cls_idx] += 1.0
-            votes = row_put(votes_np)
-        else:
-            votes = row_put(np.zeros((n, cfg.n_classes), np.float32))
-        pred = row_put(jnp.zeros(n, dtype=jnp.float32))
-    elif start_k:
-        if is_gbt and cfg.dropout_rate > 0.0:
-            # DART resume: regenerate each tree's keyed per-row keep mask
-            # so the running prediction matches the uninterrupted run
-            from shifu_tpu.models.tree import traverse_trees
-
-            per_tree = np.asarray(
-                traverse_trees(trees, codes_j))  # [n, k]
-            s = np.zeros(n, np.float32)
-            for col in range(per_tree.shape[1]):
-                contrib = per_tree[:, col]  # weight folded by traverse
-                if col > 0:
-                    keep = (np.random.default_rng([cfg.seed, col, 777])
-                            .random(n_orig) >= cfg.dropout_rate)
-                    keep = np.pad(keep.astype(np.float32),
-                                  (0, n - n_orig), constant_values=1.0)
-                    contrib = contrib * keep
-                s += contrib
-        else:
-            s = np.asarray(_score_existing(trees, codes_j))
-        pred = row_put((s if is_gbt else s / start_k).astype(np.float32))
-    else:
-        pred = row_put(jnp.zeros(n, dtype=jnp.float32))
-    # replay the checkpointed error history through the early-stop state so
-    # a resumed run stops at the same tree the uninterrupted run would
-    valid_errors: List[float] = list(init_valid_errors or [])[:start_k]
-    bad_rounds = 0
-    decider = (DTEarlyStopDecider(cfg.max_depth)
-               if cfg.enable_early_stop else None)
-    for idx, v in enumerate(valid_errors):
-        if decider is not None:
-            decider.add(v)
-        if cfg.early_stop_rounds and idx >= 1:
-            if v > min(valid_errors[:idx + 1]):
-                bad_rounds += 1
+                row_put = lambda a: shard_rows(a, mesh)  # noqa: E731
+                codes_np = np.asarray(codes, np.int32)
+                y_np = np.asarray(tags, np.float32)
+                base_w_np = np.where(valid_mask, 0.0,
+                                     np.asarray(weights)).astype(np.float32)
+                real_np = np.ones(n, dtype=bool)
+                n_dev = mesh.devices.size
+                (codes_np, y_np, base_w_np, valid_mask, real_np), _ = pad_rows(
+                    [codes_np, y_np, base_w_np, valid_mask, real_np], n_dev
+                )
+                n = codes_np.shape[0]
+                codes_j = shard_rows(codes_np, mesh)
+                y_j = shard_rows(y_np, mesh)
+                vm_j = shard_rows(valid_mask, mesh)
+                base_w_j = shard_rows(base_w_np, mesh)
+                real_j = shard_rows(real_np, mesh)
             else:
-                bad_rounds = 0
-    terr = verr = 0.0
+                # device-resident inputs stay on device (the code matrix is the
+                # big one and may already live in HBM from a previous run)
+                row_put = jnp.asarray
+                codes_j = (codes.astype(jnp.int32) if isinstance(codes, jax.Array)
+                           else jnp.asarray(np.asarray(codes, np.int32)))
+                y_j = (tags.astype(jnp.float32) if isinstance(tags, jax.Array)
+                       else jnp.asarray(np.asarray(tags, np.float32)))
+                w_j = (weights.astype(jnp.float32)
+                       if isinstance(weights, jax.Array)
+                       else jnp.asarray(np.asarray(weights, np.float32)))
+                vm_j = jnp.asarray(valid_mask)
+                base_w_j = jnp.where(vm_j, 0.0, w_j)
+                real_j = jnp.ones(n, dtype=bool)
+            slots_np = np.asarray(slots, dtype=np.int32)
+            is_cat_np = np.asarray(is_cat, dtype=bool)
 
-    # per-tree host sync only when someone consumes per-tree results;
-    # otherwise the whole forest builds as ONE async dispatch chain
-    # (progress/checkpoint/early-stop all off => no host round-trips
-    # between trees)
-    need_sync = bool(progress_cb or checkpoint_cb or cfg.early_stop_rounds
-                     or decider is not None)
-    lay = make_layout([int(s) for s in slots_np], [bool(c) for c in is_cat_np])
-    batch_cap = _node_batch_size(lay.T, cfg.max_stats_memory_mb,
-                                 cfg.n_classes)
-    fused = (not leaf_wise) and 2**cfg.max_depth <= batch_cap
-    M_forest = None
-    codes8_forest = None
-    pallas_fused = False
-    if fused:
-        replicate_fn = None
-        if mesh is not None:
-            from shifu_tpu.parallel.mesh import replicate
+            k_sub = subset_count(cfg.feature_subset_strategy, F)
+            leaf_wise = cfg.max_leaves and cfg.max_leaves > 0
+            if leaf_wise and mesh is not None:
+                log.warning("leaf-wise growth runs single-device; ignoring mesh")
+                mesh = None
+            trees: List[DenseTree] = list(init_trees or [])
+            start_k = len(trees)
+            lr = cfg.learning_rate
+            is_gbt = cfg.algorithm == "GBT"
+            log_loss = cfg.loss == "log"
 
-            replicate_fn = lambda a: replicate(a, mesh)  # noqa: E731
-        _p_on, _p_int, pallas_fused = _pallas_state(mesh)
-        # hoist the code one-hot across the WHOLE forest when it fits:
-        # node-independent, so one bf16 [n, T] build replaces a rebuild +
-        # HBM materialization per level of every tree. The Pallas fused
-        # kernel supersedes it — M is exactly the [n, T] HBM plane the
-        # kernel exists to not materialize.
-        C_hist = cfg.n_classes if cfg.n_classes >= 3 else 3
-        n_pad_m = -(-n // _M_BLK) * _M_BLK
-        use_m = (mesh is None and not pallas_fused
-                 and n_pad_m * lay.T * 2 <= _m_budget_bytes()
-                 # deepest hist level is 2^(D-1) nodes; cap the A width
-                 and C_hist * 2 ** max(cfg.max_depth - 1, 0) <= _M_CL_CAP
-                 # resume-stable: depends on cfg only, never on start_k,
-                 # so a checkpoint-resumed run picks the SAME lowering as
-                 # the uninterrupted one (bit-equal resume contract)
-                 and cfg.tree_num * cfg.max_depth >= 2)
-        sub_levels, acc64 = _sub_plan(cfg, batch_cap)
-        sub_counts = _plan_counts(sub_levels[:cfg.max_depth],
-                                  cfg.hist_subtraction)
-        tree_prog = _get_tree_program(
-            cfg.max_depth, lay, cfg.impurity,
-            cfg.min_instances_per_node, cfg.min_info_gain,
-            n_classes=cfg.n_classes, mesh=mesh, with_m=use_m,
-            sub_levels=sub_levels, acc64=acc64,
-            lowp=_low_precision(cfg),
-        )
-        if use_m:
-            M_forest = _get_m_builder(lay)(codes_j)
-        if pallas_fused:
-            # int8 code planes hoisted once per forest (codes are
-            # tree/level-independent): 4x less kernel code-read bandwidth
-            codes8_forest = _get_codes8_program(lay)(codes_j)
-    deferred: List[tuple] = []  # (k, weight, feats_d, masks_d, leaves_d)
-    err_pairs: List[tuple] = []  # device (train, valid) when deferred
+            reg_err = _get_errors_program()
+            errors_of = lambda score: reg_err(score, y_j, vm_j, real_j)  # noqa: E731
 
-    # the ALL-features mask never changes: transfer it once instead of per
-    # tree (a host->device put per tree buys nothing)
-    fot_all_features = None
-    if fused and k_sub >= F:
-        fot_all_features = jnp.asarray(np.ones(lay.T, dtype=bool))
-        if replicate_fn is not None:
-            fot_all_features = replicate_fn(fot_all_features)
+            is_cls = cfg.n_classes >= 3
+            if is_cls and is_gbt:
+                raise ValueError(
+                    "NATIVE multi-class tree training is RF-only (the reference "
+                    "supports GBT multi-class via ONEVSALL, "
+                    "TrainModelProcessor.java:341-349)"
+                )
+            if is_cls:
+                c_err = _get_cls_errors_program()
+                cls_errors_of = lambda votes: c_err(  # noqa: E731
+                    votes, y_j, vm_j, real_j)
 
-    # ---- per-tree RNG draws, PREPASSED: each tree's stream is keyed by
-    # (seed, tree index) — resume at tree k replays identically — so the
-    # draws are known up front. RF bag counts ship as ONE [K, n] uint16
-    # transfer instead of a [n] f32 per tree (remote TPU links price every
-    # host->device byte); values are exact (Poisson counts nowhere near
-    # 65535). feat_ok stays host-side (tiny, drives layout masks). ----
-    draw_ks = list(range(start_k, cfg.tree_num))
-    feat_oks: Dict[int, np.ndarray] = {}
-    bags_j = None
-    if cfg.algorithm == "RF" and draw_ks:
-        bag_rows = []
-        for k in draw_ks:
-            rng_k = np.random.default_rng([cfg.seed, k])
-            if cfg.bagging_with_replacement:
-                bag = rng_k.poisson(cfg.bagging_sample_rate, size=n_orig)
-            else:
-                bag = rng_k.random(n_orig) < cfg.bagging_sample_rate
-            bag_rows.append(np.pad(bag.astype(np.uint16), (0, n - n_orig)))
-            feat_ok = np.zeros(F, dtype=bool)
-            if k_sub >= F:
-                feat_ok[:] = True
-            else:
-                feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
-            feat_oks[k] = feat_ok
-        if mesh is None:
-            bags_j = jnp.asarray(np.stack(bag_rows))  # [K, n] u16, one put
-        else:
-            bags_j = [row_put(b.astype(np.float32)) for b in bag_rows]
-    else:
-        for k in draw_ks:
-            rng_k = np.random.default_rng([cfg.seed, k])
-            feat_ok = np.zeros(F, dtype=bool)
-            if k_sub >= F:
-                feat_ok[:] = True
-            else:
-                feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
-            feat_oks[k] = feat_ok
+            # prediction state re-derived from loaded trees on resume (the workers'
+            # recoverGBTData analog): GBT keeps the raw sum F(x), RF the running
+            # mean over trees built so far — classification keeps per-class VOTES
+            votes = None
+            if is_cls:
+                if start_k:
+                    from shifu_tpu.models.tree import traverse_trees
 
-    # NOTE (round 5, measured): building all K RF trees as ONE program
-    # with fat [blk, K*C*L] x [blk, T] contractions was tried and is
-    # SLOWER than the sequential hoisted-M path (8.2x vs 13.4x one numpy
-    # worker on the rf bench) — the K-times-larger A/one-hot
-    # materialization traffic outweighs the better MXU shape. See git
-    # history for the implementation.
-    for k in range(start_k, cfg.tree_num):
-        feat_ok = feat_oks[k]
-        if cfg.algorithm == "RF":
-            if mesh is None:
-                w_k = base_w_j * bags_j[k - start_k].astype(jnp.float32)
-            else:
-                w_k = base_w_j * bags_j[k - start_k]
-            labels_k = y_j
-        else:  # GBT: fit the negative loss gradient
-            w_k = base_w_j
-            if log_loss:
-                labels_k = y_j - 1.0 / (1.0 + jnp.exp(-pred))
-            else:
-                labels_k = y_j - pred
+                    per_tree = np.asarray(
+                        traverse_trees(trees, codes_j))  # [n, k] class
+                    votes_np = np.zeros((n, cfg.n_classes), np.float32)
+                    for col in range(per_tree.shape[1]):
+                        cls_idx = np.clip(per_tree[:, col].astype(np.int64), 0,
+                                          cfg.n_classes - 1)
+                        votes_np[np.arange(n), cls_idx] += 1.0
+                    votes = row_put(votes_np)
+                else:
+                    votes = row_put(np.zeros((n, cfg.n_classes), np.float32))
+                pred = row_put(jnp.zeros(n, dtype=jnp.float32))
+            elif start_k:
+                if is_gbt and cfg.dropout_rate > 0.0:
+                    # DART resume: regenerate each tree's keyed per-row keep mask
+                    # so the running prediction matches the uninterrupted run
+                    from shifu_tpu.models.tree import traverse_trees
 
-        tree = None
-        if leaf_wise:
-            tree, resting = build_tree_leafwise(
-                codes_j, labels_k, w_k, slots_np, is_cat_np, cfg, feat_ok
-            )
-            tree_pred = jnp.asarray(tree.leaf_value)[resting]
-        elif fused:
-            if fot_all_features is not None:
-                fot = fot_all_features
+                    per_tree = np.asarray(
+                        traverse_trees(trees, codes_j))  # [n, k]
+                    s = np.zeros(n, np.float32)
+                    for col in range(per_tree.shape[1]):
+                        contrib = per_tree[:, col]  # weight folded by traverse
+                        if col > 0:
+                            keep = (np.random.default_rng([cfg.seed, col, 777])
+                                    .random(n_orig) >= cfg.dropout_rate)
+                            keep = np.pad(keep.astype(np.float32),
+                                          (0, n - n_orig), constant_values=1.0)
+                            contrib = contrib * keep
+                        s += contrib
+                else:
+                    s = np.asarray(_score_existing(trees, codes_j))
+                pred = row_put((s if is_gbt else s / start_k).astype(np.float32))
             else:
-                fot = jnp.asarray(np.asarray(feat_ok, bool)[lay.seg_of_t])
+                pred = row_put(jnp.zeros(n, dtype=jnp.float32))
+            # replay the checkpointed error history through the early-stop state so
+            # a resumed run stops at the same tree the uninterrupted run would
+            valid_errors: List[float] = list(init_valid_errors or [])[:start_k]
+            bad_rounds = 0
+            decider = (DTEarlyStopDecider(cfg.max_depth)
+                       if cfg.enable_early_stop else None)
+            for idx, v in enumerate(valid_errors):
+                if decider is not None:
+                    decider.add(v)
+                if cfg.early_stop_rounds and idx >= 1:
+                    if v > min(valid_errors[:idx + 1]):
+                        bad_rounds += 1
+                    else:
+                        bad_rounds = 0
+            terr = verr = 0.0
+
+            # per-tree host sync only when someone consumes per-tree results;
+            # otherwise the whole forest builds as ONE async dispatch chain
+            # (progress/checkpoint/early-stop all off => no host round-trips
+            # between trees)
+            need_sync = bool(progress_cb or checkpoint_cb or cfg.early_stop_rounds
+                             or decider is not None)
+            lay = make_layout([int(s) for s in slots_np], [bool(c) for c in is_cat_np])
+            batch_cap = _node_batch_size(lay.T, cfg.max_stats_memory_mb,
+                                         cfg.n_classes)
+            fused = (not leaf_wise) and 2**cfg.max_depth <= batch_cap
+            M_forest = None
+            codes8_forest = None
+            pallas_fused = False
+            if fused:
+                replicate_fn = None
+                if mesh is not None:
+                    from shifu_tpu.parallel.mesh import replicate
+
+                    replicate_fn = lambda a: replicate(a, mesh)  # noqa: E731
+                _p_on, _p_int, pallas_fused = _pallas_state(mesh)
+                # hoist the code one-hot across the WHOLE forest when it fits:
+                # node-independent, so one bf16 [n, T] build replaces a rebuild +
+                # HBM materialization per level of every tree. The Pallas fused
+                # kernel supersedes it — M is exactly the [n, T] HBM plane the
+                # kernel exists to not materialize.
+                C_hist = cfg.n_classes if cfg.n_classes >= 3 else 3
+                n_pad_m = -(-n // _M_BLK) * _M_BLK
+                use_m = (mesh is None and not pallas_fused
+                         and n_pad_m * lay.T * 2 <= _m_budget_bytes()
+                         # deepest hist level is 2^(D-1) nodes; cap the A width
+                         and C_hist * 2 ** max(cfg.max_depth - 1, 0) <= _M_CL_CAP
+                         # resume-stable: depends on cfg only, never on start_k,
+                         # so a checkpoint-resumed run picks the SAME lowering as
+                         # the uninterrupted one (bit-equal resume contract)
+                         and cfg.tree_num * cfg.max_depth >= 2)
+                sub_levels, acc64 = _sub_plan(cfg, batch_cap)
+                sub_counts = _plan_counts(sub_levels[:cfg.max_depth],
+                                          cfg.hist_subtraction)
+                tree_prog = _get_tree_program(
+                    cfg.max_depth, lay, cfg.impurity,
+                    cfg.min_instances_per_node, cfg.min_info_gain,
+                    n_classes=cfg.n_classes, mesh=mesh, with_m=use_m,
+                    sub_levels=sub_levels, acc64=acc64,
+                    lowp=_low_precision(cfg),
+                )
+                if use_m:
+                    M_forest = _get_m_builder(lay)(codes_j)
+                if pallas_fused:
+                    # int8 code planes hoisted once per forest (codes are
+                    # tree/level-independent): 4x less kernel code-read bandwidth
+                    codes8_forest = _get_codes8_program(lay)(codes_j)
+            deferred: List[tuple] = []  # (k, weight, feats_d, masks_d, leaves_d)
+            err_pairs: List[tuple] = []  # device (train, valid) when deferred
+
+            # the ALL-features mask never changes: transfer it once instead of per
+            # tree (a host->device put per tree buys nothing)
+            fot_all_features = None
+            if fused and k_sub >= F:
+                fot_all_features = jnp.asarray(np.ones(lay.T, dtype=bool))
                 if replicate_fn is not None:
-                    fot = replicate_fn(fot)
-            if M_forest is not None:
-                feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
-                    codes_j, labels_k, w_k, fot, M_forest)
-            elif pallas_fused:
-                feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
-                    codes_j, codes8_forest, labels_k, w_k, fot)
-            else:
-                feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
-                    codes_j, labels_k, w_k, fot)
-            _record_hist_counters(*sub_counts)
-            deferred.append(
-                (k, 1.0 if (is_gbt and k == 0) else (lr if is_gbt else 1.0),
-                 feats_d, masks_d, leaves_d))
-        else:
-            tree, resting = build_tree(
-                codes_j, labels_k, w_k, slots_np, is_cat_np, cfg, feat_ok,
-                mesh=mesh,
-            )
-            tree_pred = jnp.asarray(tree.leaf_value)[resting]
-        weight_k = 1.0 if (is_gbt and k == 0) else (lr if is_gbt else 1.0)
-        if tree is not None:
-            tree.weight = weight_k
-            trees.append(tree)
-        else:
-            trees.append(None)  # placeholder; assembled after the loop
+                    fot_all_features = replicate_fn(fot_all_features)
 
-        if is_cls:
-            import jax.nn as jnn
-
-            votes = votes + jnn.one_hot(
-                jnp.clip(tree_pred.astype(jnp.int32), 0, cfg.n_classes - 1),
-                cfg.n_classes, dtype=jnp.float32)
-            t_e, v_e = cls_errors_of(votes)
-        elif is_gbt:
-            if cfg.dropout_rate > 0.0 and k > 0:
-                # DART-ish per-row dropout (dt/DTWorker.java:634-640): each
-                # row independently skips this tree's contribution to its
-                # RUNNING prediction (the gradient target), never the model;
-                # keyed per tree so checkpoint resume replays identically
-                keep = (np.random.default_rng([cfg.seed, k, 777])
-                        .random(n_orig) >= cfg.dropout_rate)
-                keep = np.pad(keep.astype(np.float32), (0, n - n_orig),
-                              constant_values=1.0)
-                pred = pred + weight_k * tree_pred * row_put(keep)
+            # ---- per-tree RNG draws, PREPASSED: each tree's stream is keyed by
+            # (seed, tree index) — resume at tree k replays identically — so the
+            # draws are known up front. RF bag counts ship as ONE [K, n] uint16
+            # transfer instead of a [n] f32 per tree (remote TPU links price every
+            # host->device byte); values are exact (Poisson counts nowhere near
+            # 65535). feat_ok stays host-side (tiny, drives layout masks). ----
+            draw_ks = list(range(start_k, cfg.tree_num))
+            feat_oks: Dict[int, np.ndarray] = {}
+            bags_j = None
+            if cfg.algorithm == "RF" and draw_ks:
+                bag_rows = []
+                for k in draw_ks:
+                    rng_k = np.random.default_rng([cfg.seed, k])
+                    if cfg.bagging_with_replacement:
+                        bag = rng_k.poisson(cfg.bagging_sample_rate, size=n_orig)
+                    else:
+                        bag = rng_k.random(n_orig) < cfg.bagging_sample_rate
+                    bag_rows.append(np.pad(bag.astype(np.uint16), (0, n - n_orig)))
+                    feat_ok = np.zeros(F, dtype=bool)
+                    if k_sub >= F:
+                        feat_ok[:] = True
+                    else:
+                        feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
+                    feat_oks[k] = feat_ok
+                if mesh is None:
+                    bags_j = jnp.asarray(np.stack(bag_rows))  # [K, n] u16, one put
+                else:
+                    bags_j = [row_put(b.astype(np.float32)) for b in bag_rows]
             else:
-                pred = pred + weight_k * tree_pred
-            score = (
-                1.0 / (1.0 + jnp.exp(-pred)) if log_loss
-                else jnp.clip(pred, 0.0, 1.0)
-            )
-            t_e, v_e = errors_of(score)
-        else:
-            n_prev = k  # RF running mean over trees built so far
-            pred = tree_pred if k == 0 else (pred * n_prev + tree_pred) / (k + 1)
-            score = jnp.clip(pred, 0.0, 1.0)
-            t_e, v_e = errors_of(score)
-        if not need_sync:
-            err_pairs.append((t_e, v_e))
-            valid_errors.append(None)  # filled after the final sync
-            continue
-        if deferred:  # sync consumers need real trees: drain the backlog
-            _assemble_deferred(trees, deferred, cfg)
-        terr, verr = float(t_e), float(v_e)  # one sync per tree
-        valid_errors.append(verr)
-        if progress_cb:
-            progress_cb(k + 1, terr, verr)
-        if checkpoint_cb:
-            checkpoint_cb(k + 1, trees, valid_errors)
-        if decider is not None and decider.add(verr):
-            log.info("windowed early stop after %d trees "
-                     "(DTEarlyStopDecider)", k + 1)
-            break
-        if cfg.early_stop_rounds and len(valid_errors) > 1:
-            if verr > min(valid_errors):
-                bad_rounds += 1
-                if bad_rounds >= cfg.early_stop_rounds:
-                    log.info("early stop after %d trees", k + 1)
+                for k in draw_ks:
+                    rng_k = np.random.default_rng([cfg.seed, k])
+                    feat_ok = np.zeros(F, dtype=bool)
+                    if k_sub >= F:
+                        feat_ok[:] = True
+                    else:
+                        feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
+                    feat_oks[k] = feat_ok
+
+        # NOTE (round 5, measured): building all K RF trees as ONE program
+        # with fat [blk, K*C*L] x [blk, T] contractions was tried and is
+        # SLOWER than the sequential hoisted-M path (8.2x vs 13.4x one numpy
+        # worker on the rf bench) — the K-times-larger A/one-hot
+        # materialization traffic outweighs the better MXU shape. See git
+        # history for the implementation.
+        for k in range(start_k, cfg.tree_num):
+            with span("train.tree", call=call, k=k):
+                feat_ok = feat_oks[k]
+                if cfg.algorithm == "RF":
+                    if mesh is None:
+                        w_k = base_w_j * bags_j[k - start_k].astype(jnp.float32)
+                    else:
+                        w_k = base_w_j * bags_j[k - start_k]
+                    labels_k = y_j
+                else:  # GBT: fit the negative loss gradient
+                    w_k = base_w_j
+                    if log_loss:
+                        labels_k = y_j - 1.0 / (1.0 + jnp.exp(-pred))
+                    else:
+                        labels_k = y_j - pred
+
+                tree = None
+                if leaf_wise:
+                    tree, resting = build_tree_leafwise(
+                        codes_j, labels_k, w_k, slots_np, is_cat_np, cfg, feat_ok
+                    )
+                    tree_pred = jnp.asarray(tree.leaf_value)[resting]
+                elif fused:
+                    if fot_all_features is not None:
+                        fot = fot_all_features
+                    else:
+                        fot = jnp.asarray(np.asarray(feat_ok, bool)[lay.seg_of_t])
+                        if replicate_fn is not None:
+                            fot = replicate_fn(fot)
+                    if M_forest is not None:
+                        feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
+                            codes_j, labels_k, w_k, fot, M_forest)
+                    elif pallas_fused:
+                        feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
+                            codes_j, codes8_forest, labels_k, w_k, fot)
+                    else:
+                        feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
+                            codes_j, labels_k, w_k, fot)
+                    _record_hist_counters(*sub_counts)
+                    deferred.append(
+                        (k, 1.0 if (is_gbt and k == 0) else (lr if is_gbt else 1.0),
+                         feats_d, masks_d, leaves_d))
+                else:
+                    tree, resting = build_tree(
+                        codes_j, labels_k, w_k, slots_np, is_cat_np, cfg, feat_ok,
+                        mesh=mesh,
+                    )
+                    tree_pred = jnp.asarray(tree.leaf_value)[resting]
+                registry().counter("train.trees").inc()
+                weight_k = 1.0 if (is_gbt and k == 0) else (lr if is_gbt else 1.0)
+                if tree is not None:
+                    tree.weight = weight_k
+                    trees.append(tree)
+                else:
+                    trees.append(None)  # placeholder; assembled after the loop
+
+                if is_cls:
+                    import jax.nn as jnn
+
+                    votes = votes + jnn.one_hot(
+                        jnp.clip(tree_pred.astype(jnp.int32), 0, cfg.n_classes - 1),
+                        cfg.n_classes, dtype=jnp.float32)
+                    t_e, v_e = cls_errors_of(votes)
+                elif is_gbt:
+                    if cfg.dropout_rate > 0.0 and k > 0:
+                        # DART-ish per-row dropout (dt/DTWorker.java:634-640): each
+                        # row independently skips this tree's contribution to its
+                        # RUNNING prediction (the gradient target), never the model;
+                        # keyed per tree so checkpoint resume replays identically
+                        keep = (np.random.default_rng([cfg.seed, k, 777])
+                                .random(n_orig) >= cfg.dropout_rate)
+                        keep = np.pad(keep.astype(np.float32), (0, n - n_orig),
+                                      constant_values=1.0)
+                        pred = pred + weight_k * tree_pred * row_put(keep)
+                    else:
+                        pred = pred + weight_k * tree_pred
+                    score = (
+                        1.0 / (1.0 + jnp.exp(-pred)) if log_loss
+                        else jnp.clip(pred, 0.0, 1.0)
+                    )
+                    t_e, v_e = errors_of(score)
+                else:
+                    n_prev = k  # RF running mean over trees built so far
+                    pred = tree_pred if k == 0 else (pred * n_prev + tree_pred) / (k + 1)
+                    score = jnp.clip(pred, 0.0, 1.0)
+                    t_e, v_e = errors_of(score)
+                if not need_sync:
+                    err_pairs.append((t_e, v_e))
+                    valid_errors.append(None)  # filled after the final sync
+                    continue
+                if deferred:  # sync consumers need real trees: drain the backlog
+                    _assemble_deferred(trees, deferred, cfg, call=call, k=k)
+                with span("train.tree.wait", call=call, k=k):
+                    terr, verr = float(t_e), float(v_e)  # one sync per tree
+                valid_errors.append(verr)
+                if progress_cb:
+                    # the caller's time, not the trainer's
+                    with span("train.tree.progress_cb", call=call, k=k):
+                        progress_cb(k + 1, terr, verr)
+                if checkpoint_cb:
+                    checkpoint_cb(k + 1, trees, valid_errors)
+                if decider is not None and decider.add(verr):
+                    log.info("windowed early stop after %d trees "
+                             "(DTEarlyStopDecider)", k + 1)
                     break
-            else:
-                bad_rounds = 0
+                if cfg.early_stop_rounds and len(valid_errors) > 1:
+                    if verr > min(valid_errors):
+                        bad_rounds += 1
+                        if bad_rounds >= cfg.early_stop_rounds:
+                            log.info("early stop after %d trees", k + 1)
+                            break
+                    else:
+                        bad_rounds = 0
 
-    errs_d = (jnp.stack([jnp.stack(p) for p in err_pairs])
-              if err_pairs else None)
-    if deferred:  # trees + errors ride ONE host round-trip
-        errs_d = _assemble_deferred(trees, deferred, cfg, extra=errs_d)
-    elif errs_d is not None:
-        errs_d = jax.device_get(errs_d)
-    if err_pairs:  # deferred error sync
-        host = np.asarray(errs_d)
-        errs = [(float(t), float(v)) for t, v in host]
-        terr, verr = errs[-1]
-        j = 0
-        for i in range(len(valid_errors)):
-            if valid_errors[i] is None:
-                valid_errors[i] = errs[j][1]
-                j += 1
+        errs_d = (jnp.stack([jnp.stack(p) for p in err_pairs])
+                  if err_pairs else None)
+        k_last = len(trees) - 1
+        if deferred:  # trees + errors ride ONE host round-trip
+            errs_d = _assemble_deferred(trees, deferred, cfg, extra=errs_d,
+                                        call=call, k=k_last)
+        elif errs_d is not None:
+            with span("train.tree.wait", call=call, k=k_last):
+                errs_d = jax.device_get(errs_d)
+        if err_pairs:  # deferred error sync
+            host = np.asarray(errs_d)
+            errs = [(float(t), float(v)) for t, v in host]
+            terr, verr = errs[-1]
+            j = 0
+            for i in range(len(valid_errors)):
+                if valid_errors[i] is None:
+                    valid_errors[i] = errs[j][1]
+                    j += 1
 
-    spec = TreeModelSpec(
-        algorithm=cfg.algorithm,
-        trees=trees,
-        input_columns=list(columns),
-        slots=[int(s) for s in slots],
-        boundaries=boundaries or [None] * F,
-        categories=categories or [None] * F,
-        loss=cfg.loss,
-        learning_rate=lr,
-        init_pred=0.0,
-        convert_to_prob="SIGMOID" if cfg.loss == "log" else "RAW",
-        train_error=terr,
-        valid_error=valid_errors[-1] if valid_errors else None,
-        n_classes=cfg.n_classes,
-    )
-    return TreeTrainResult(spec=spec, train_error=terr,
-                           valid_error=valid_errors[-1] if valid_errors else 0.0)
+        spec = TreeModelSpec(
+            algorithm=cfg.algorithm,
+            trees=trees,
+            input_columns=list(columns),
+            slots=[int(s) for s in slots],
+            boundaries=boundaries or [None] * F,
+            categories=categories or [None] * F,
+            loss=cfg.loss,
+            learning_rate=lr,
+            init_pred=0.0,
+            convert_to_prob="SIGMOID" if cfg.loss == "log" else "RAW",
+            train_error=terr,
+            valid_error=valid_errors[-1] if valid_errors else None,
+            n_classes=cfg.n_classes,
+        )
+        return TreeTrainResult(spec=spec, train_error=terr,
+                               valid_error=valid_errors[-1] if valid_errors else 0.0)

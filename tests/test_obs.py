@@ -177,6 +177,84 @@ class TestTracing:
         assert tr.span_seconds("a") >= 0.0
         assert tr.span_seconds("missing") == 0.0
 
+    def test_span_lands_in_the_profilers_trace(self, tmp_path):
+        """A span is also a `shifu.<name>` TraceAnnotation: in a captured
+        profile it sits on the host plane, on the profiler's clock, inside
+        a `bench.`-style annotation opened around it, with its scalar
+        attributes as the event's stats."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from shifu_tpu.obs.tracing import Tracer
+
+        tr = Tracer()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("bench.control"):
+                with tr.span("probe.outer", call=7, what="x", skipped=None):
+                    with tr.span("probe.inner"):
+                        pass
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[0]
+        found = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("bench.", "shifu.")):
+                        found[ev.name] = (ev.start_ns,
+                                          ev.start_ns + ev.duration_ns,
+                                          dict(ev.stats))
+        assert set(found) == {"bench.control", "shifu.probe.outer",
+                              "shifu.probe.inner"}
+        c, o, i = (found[k] for k in ("bench.control", "shifu.probe.outer",
+                                      "shifu.probe.inner"))
+        assert c[0] <= o[0] <= i[0] and i[1] <= o[1] <= c[1]
+        assert o[2] == {"call": 7, "what": "x"}
+        # the ring keeps the same two spans on the host's clock
+        assert [e["name"] for e in tr.events] == ["probe.inner",
+                                                  "probe.outer"]
+
+    def test_span_without_jax_opens_no_annotation(self, monkeypatch):
+        import contextlib
+        import sys
+
+        from shifu_tpu.obs import tracing
+
+        monkeypatch.setitem(sys.modules, "jax", None)  # import jax raises
+        assert isinstance(tracing.profiler_annotation("shifu.x"),
+                          contextlib.nullcontext)
+        tr = tracing.Tracer()
+        with tr.span("host.only"):
+            pass
+        assert [e["name"] for e in tr.events] == ["host.only"]
+
+    def test_between_clips_by_perf_counter(self):
+        from shifu_tpu.obs.tracing import Tracer
+
+        tr = Tracer()
+        t0 = tr.t0
+        tr.record("warm.a", t0 + 1.0, t0 + 2.0)
+        tr.record("win.a", t0 + 2.5, t0 + 3.5, "outer", {"k": 1})
+        tr.record("win.b", t0 + 1.5, t0 + 4.0)  # starts early, ends inside
+        tr.record("late.a", t0 + 4.5, t0 + 6.0)  # ends after the window
+        got = tr.between(t0 + 3.0, t0 + 5.0)
+        assert [e["name"] for e in got] == ["win.a", "win.b"]
+        assert got[0]["args"] == {"k": 1, "parent": "outer"}
+        assert got[0]["dur"] == pytest.approx(1e6)
+        assert [e["name"] for e in tr.between(t0 + 3.0, t0 + 5.0, "win.b")
+                ] == ["win.b"]
+        assert [e["name"] for e in tr.between(float("-inf"), t0 + 2.0)
+                ] == ["warm.a"]
+        assert tr.between(t0 + 10.0, t0 + 11.0) == []
+
 
 # ---------------------------------------------------------------------------
 # run wrapper + ledger
@@ -380,6 +458,89 @@ class TestJaxProbes:
         before = reg.counter("jax.compiles").value
         f(jnp.ones(17)).block_until_ready()  # cache hit: no new compile
         assert reg.counter("jax.compiles").value == before
+
+    def test_events_say_which_program_and_under_which_span(self):
+        import jax
+        import jax.numpy as jnp
+
+        from shifu_tpu import obs
+
+        assert obs.install_jax_probes()
+        obs.reset()
+
+        @jax.jit
+        def probed_program(x):
+            return x * 7 - 1
+
+        with obs.span("outer.step"):
+            with obs.span("inner.part"):
+                probed_program(jnp.ones(13)).block_until_ready()
+        probed_program(jnp.ones(21)).block_until_ready()  # no span open
+        reg = obs.registry()
+        assert reg.counter("jax.lowers").value >= 2
+        assert reg.timer("jax.lower").seconds > 0
+        hist = reg.snapshot()["histograms"]["jax.lower.duration_seconds"]
+        assert hist["count"] >= 2
+        mine = [e for e in obs.tracer().events
+                if "probed_program" in e["args"].get("fun", "")]
+        assert {e["name"] for e in mine} == {"jax.trace", "jax.lower",
+                                             "jax.compile"}
+        inside = [e for e in mine
+                  if e["args"].get("parent") == "outer.step/inner.part"]
+        outside = [e for e in mine if "parent" not in e["args"]]
+        for kind in ("jax.trace", "jax.lower", "jax.compile"):
+            assert sum(e["name"] == kind for e in inside) == 1
+            assert sum(e["name"] == kind for e in outside) == 1
+        # an event ends when it arrives and starts its duration earlier:
+        # inside the span that was open
+        part = next(e for e in obs.tracer().events
+                    if e["name"] == "inner.part")
+        for e in inside:
+            assert e["dur"] > 0
+            assert part["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= part["ts"] + part["dur"] + 1e3
+
+    def test_persistent_cache_hit_and_miss_are_counted(self, tmp_path):
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from shifu_tpu import obs
+
+        assert obs.install_jax_probes()
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+        was = {k: getattr(jax.config, k) for k in keys}
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        try:
+            x = jnp.ones(19)  # its own little program, before the count
+            obs.reset()
+
+            def cached_program(x):
+                return jnp.tanh(x) * 11 + 3
+
+            jax.jit(cached_program)(x).block_until_ready()
+            reg = obs.registry()
+            assert reg.counter("jax.cache.misses").value == 1
+            assert reg.counter("jax.cache.hits").value == 0
+            # a fresh jit of the same function: jax's in-memory caches do
+            # not know it, the directory does
+            jax.clear_caches()
+            jax.jit(cached_program)(x).block_until_ready()
+            assert reg.counter("jax.cache.hits").value == 1
+            assert reg.counter("jax.cache.misses").value == 1
+            assert reg.timer("jax.cache.retrieval").calls == 1
+            assert reg.timer("jax.cache.retrieval").seconds > 0
+            # the fetch is a backend "compile" event all the same
+            assert reg.counter("jax.compiles").value == 2
+        finally:
+            for k, v in was.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------

@@ -502,6 +502,81 @@ class TestCompileDurations:
         assert "wall-clock" in v["events"][0]["detail"]
 
 
+class TestDispatchSeamTracing:
+    def test_bare_dispatch_installs_the_probes(self, tmp_path):
+        """A process that goes straight to a dispatch seam (the trainers
+        called without BasicProcessor.run: bench.py, the benchmark's
+        drivers) still records which program compiled: the seam installs
+        the jax probes itself."""
+        code = (
+            "import jax, jax.numpy as jnp\n"
+            "from shifu_tpu import obs\n"
+            "from shifu_tpu.obs import jaxprobe, profile\n"
+            "assert not jaxprobe._installed\n"
+            "f = jax.jit(lambda x: x * 2 + 1)\n"
+            "x = jnp.ones(8)\n"
+            "with obs.span('caller.step'):\n"
+            "    profile.dispatch('t.bare', f, x, sync=True)\n"
+            "assert jaxprobe._installed\n"
+            "assert obs.registry().counter('jax.compiles').value >= 1\n"
+            "evs = [e for e in obs.tracer().events\n"
+            "       if e['name'] == 'jax.compile']\n"
+            "assert evs and all(e['args']['parent'] == 'caller.step'\n"
+            "                   for e in evs), evs\n"
+            "assert any('lambda' in e['args']['fun'] for e in evs), evs\n"
+            "print('BARE-OK')\n"
+        )
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))) + os.pathsep
+            + env.get("PYTHONPATH", ""))
+        res = subprocess.run([sys.executable, "-c", code], cwd=str(tmp_path),
+                             capture_output=True, text=True, env=env,
+                             timeout=300)
+        assert res.returncode == 0, res.stderr
+        assert "BARE-OK" in res.stdout
+
+    def test_dispatch_is_a_shifu_prog_annotation_and_no_ring_event(
+            self, tmp_path):
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        from shifu_tpu import obs
+        from shifu_tpu.obs import profile
+
+        f = jax.jit(lambda x: jnp.tanh(x) + 1)
+        x = jnp.ones((16, 4))
+        profile.dispatch("t.annotated", f, x, sync=True)  # compile outside
+        obs.reset()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with obs.span("caller.step"):
+                profile.dispatch("t.annotated", f, x, sync=True)
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[0]
+        host = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name == "/host:CPU":
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name.startswith("shifu."):
+                            host[ev.name] = (ev.start_ns,
+                                             ev.start_ns + ev.duration_ns)
+        assert set(host) == {"shifu.caller.step", "shifu.prog.t.annotated"}
+        step, prog = host["shifu.caller.step"], host["shifu.prog.t.annotated"]
+        assert step[0] <= prog[0] and prog[1] <= step[1]
+        # annotation only: the seam is per request in serve/, so the ring
+        # gets nothing from it
+        assert [e["name"] for e in obs.tracer().events] == ["caller.step"]
+
+
 class TestXlaDeepCapture:
     def test_profile_xla_traces_into_ledger_dir(self, tmp_path):
         """-Dshifu.profile=xla wraps the step in jax.profiler.trace under

@@ -1,0 +1,322 @@
+"""The trainers' own measurement: the spans and counts `train_trees` and
+`train_nn` leave in the tracer's ring, and the names their compiled programs
+carry (a named kernel, one scope a level and a phase).
+
+The span tables are the ones in docs/OBSERVABILITY.md ("Span tracing"); the
+benchmark's per-layer readers (benchmarks/layer_metrics/) read exactly these
+names.
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from shifu_tpu import obs  # noqa: E402
+from shifu_tpu.train import nn_trainer as nt  # noqa: E402
+from shifu_tpu.train import tree_trainer as tt  # noqa: E402
+from shifu_tpu.utils import environment  # noqa: E402
+
+N, F, SLOTS = 2000, 6, 9
+
+
+@pytest.fixture(scope="module")
+def rows():
+    rng = np.random.default_rng(5)
+    codes = rng.integers(0, SLOTS - 1, (N, F)).astype(np.int32)
+    y = ((codes[:, 0] > 3) ^ (codes[:, 1] > 5)).astype(np.float32)
+    x = rng.normal(size=(N, F)).astype(np.float32)
+    return codes, x, y, np.ones(N, np.float32)
+
+
+def _grow(rows, progress_cb=None, trees=3, **cfg):
+    codes, _x, y, w = rows
+    conf = tt.TreeTrainConfig(algorithm="GBT", tree_num=trees, max_depth=3,
+                              valid_set_rate=0.2, seed=3, **cfg)
+    return tt.train_trees(codes, y, w, [SLOTS] * F, [False] * F,
+                          ["f%d" % i for i in range(F)], conf,
+                          progress_cb=progress_cb)
+
+
+def _train_events():
+    """The ring's `train.` events as (name, parent, k), in the order they
+    ended, and the events themselves by name."""
+    evs = [e for e in obs.tracer().events if e["name"].startswith("train.")]
+    return ([(e["name"], e["args"].get("parent", ""), e["args"].get("k"))
+             for e in evs], evs)
+
+
+CALL, TREE = "train.trees.call", "train.trees.call/train.tree"
+
+
+def _tree_spans(k):
+    """What one synced tree leaves, in the order the spans end."""
+    return [("train.tree.wait", TREE + "/train.tree.assemble", k),
+            ("train.tree.assemble", TREE, k),
+            ("train.tree.wait", TREE, k),
+            ("train.tree.progress_cb", TREE, k),
+            ("train.tree", CALL, k)]
+
+
+def test_train_trees_with_progress_cb_leaves_the_tables_spans(rows):
+    _grow(rows, progress_cb=lambda *a: None)  # compile outside the count
+    obs.reset()
+    seen = []
+    res = _grow(rows, progress_cb=lambda k, t, v: seen.append(k))
+    assert seen == [1, 2, 3] and len(res.spec.trees) == 3
+    got, evs = _train_events()
+    want = [("train.trees.prologue", CALL, None)]
+    for k in range(3):
+        want += _tree_spans(k)
+    want.append((CALL, "", None))
+    assert got == want
+    assert {e["args"]["call"] for e in evs} == {1}
+    call = evs[-1]
+    assert call["args"] == {"call": 1, "rows": N, "trees": 3, "depth": 3}
+    reg = obs.registry()
+    assert reg.counter("train.trees").value == 3
+    assert reg.counter("train.calls", engine="tree").value == 1
+    # the divisor tree.hist.* lacked: depth 3 with subtraction builds
+    # 1 + 1 + 2 histograms a tree
+    assert reg.counter("tree.hist.built").value == 3 * 4
+    # every span lies inside its call
+    for e in evs:
+        assert call["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= call["ts"] + call["dur"] + 1.0
+
+
+def test_train_trees_without_a_consumer_syncs_once_at_the_end(rows):
+    _grow(rows)
+    obs.reset()
+    _grow(rows)
+    _grow(rows)
+    got, evs = _train_events()
+    one_call = ([("train.trees.prologue", CALL, None)]
+                + [("train.tree", CALL, k) for k in range(3)]
+                # trees and errors ride one round-trip after the loop
+                + [("train.tree.wait", CALL + "/train.tree.assemble", 2),
+                   ("train.tree.assemble", CALL, 2),
+                   (CALL, "", None)])
+    assert got == one_call + one_call
+    assert [e["args"]["call"] for e in evs] == [1] * 7 + [2] * 7
+    assert obs.registry().counter("train.trees").value == 6
+    assert obs.registry().counter("train.calls", engine="tree").value == 2
+
+
+def test_every_blocking_pull_lands_in_wait_not_in_host_time(rows,
+                                                            monkeypatch):
+    """Plant a slow `device_get`: the time must show in `train.tree.wait`,
+    and the host's share (call less wait less progress_cb, what
+    `gbt_host_ms_per_tree` reads) must not grow by it."""
+    _grow(rows, progress_cb=lambda *a: None)
+    real = jax.device_get
+    slow = 0.05
+
+    def slow_get(x):
+        time.sleep(slow)
+        return real(x)
+
+    def host_and_wait():
+        _got, evs = _train_events()
+        dur = lambda name: sum(e["dur"] for e in evs  # noqa: E731
+                               if e["name"] == name) * 1e-6
+        wait = dur("train.tree.wait")
+        return (dur(CALL) - wait - dur("train.tree.progress_cb")), wait
+
+    obs.reset()
+    _grow(rows, progress_cb=lambda *a: None)
+    host0, wait0 = host_and_wait()
+    monkeypatch.setattr(jax, "device_get", slow_get)
+    obs.reset()
+    _grow(rows, progress_cb=lambda *a: None)
+    host1, wait1 = host_and_wait()
+    assert wait1 - wait0 >= 3 * slow * 0.9  # one pull a tree
+    assert host1 - host0 < slow  # none of the 0.15 s reads as host time
+    # the deferred path's one pull, after the loop, too
+    obs.reset()
+    _grow(rows)
+    _host, wait2 = host_and_wait()
+    assert wait2 >= slow * 0.9
+
+
+def test_progress_cb_time_is_the_callers(rows):
+    _grow(rows, progress_cb=lambda *a: None)
+    obs.reset()
+    _grow(rows, progress_cb=lambda *a: time.sleep(0.02))
+    _got, evs = _train_events()
+    cb = [e["dur"] * 1e-6 for e in evs
+          if e["name"] == "train.tree.progress_cb"]
+    assert len(cb) == 3 and min(cb) >= 0.018
+
+
+def test_train_nn_leaves_the_tables_spans(rows):
+    _codes, x, y, w = rows
+    cfg = nt.NNTrainConfig(hidden_nodes=[8], activations=["tanh"],
+                           num_epochs=2, seed=1)
+    nt.train_nn(x, y, w, cfg)
+    obs.reset()
+    res = nt.train_nn(x, y, w, cfg)
+    nt.train_nn(x, y, w, cfg, fetch_params=False)
+    assert res.iterations == 2
+    got, evs = _train_events()
+    NNCALL = "train.nn.call"
+    one = [("train.nn.prologue", NNCALL, None),
+           ("train.nn.program", NNCALL, None),
+           ("train.nn.pull", NNCALL, None),
+           (NNCALL, "", None)]
+    assert got == one + one
+    assert [e["args"]["call"] for e in evs] == [1] * 4 + [2] * 4
+    assert evs[3]["args"] == {"call": 1, "rows": N, "epochs": 2}
+    assert evs[1]["args"]["limit"] == 2
+    n_flat = F * 8 + 8 + 8 + 1
+    # two f32, one i32 and one f32 scalar; the parameters where asked
+    assert evs[2]["args"]["bytes"] == 16 + 4 * n_flat
+    assert evs[6]["args"]["bytes"] == 16
+    reg = obs.registry()
+    assert reg.counter("train.calls", engine="nn").value == 2
+    assert reg.counter("train.iterations").value == 4
+
+
+def test_checkpointed_train_nn_has_one_program_span_a_segment(rows,
+                                                              tmp_path):
+    _codes, x, y, w = rows
+    cfg = nt.NNTrainConfig(hidden_nodes=[8], activations=["tanh"],
+                           num_epochs=3, seed=1, checkpoint_every=2,
+                           checkpoint_path=str(tmp_path / "ck.npy"))
+    obs.reset()
+    nt.train_nn(x, y, w, cfg)
+    _got, evs = _train_events()
+    assert [e["args"]["limit"] for e in evs
+            if e["name"] == "train.nn.program"] == [2, 3]
+
+
+# ---- names inside the compiled programs ----
+
+def _eqns(jaxpr, out):
+    for e in jaxpr.eqns:
+        out.append(e)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _eqns(inner, out)
+    return out
+
+
+@pytest.fixture
+def pallas_on():
+    environment.set_property("shifu.pallas.mode", "on")
+    try:
+        yield
+    finally:
+        environment.set_property("shifu.pallas.mode", "")
+
+
+def _tree_program_eqns(D, sub_levels):
+    from shifu_tpu.ops import hist_pallas as hp
+
+    lay = tt.make_layout([SLOTS] * 5, [False] * 5)  # no test shares it
+    before = set(tt._PROGRAMS)
+    try:
+        prog = tt._get_tree_program(D, lay, "variance", 1, 0.0,
+                                    sub_levels=sub_levels, lowp=True)
+    finally:
+        for k in set(tt._PROGRAMS) - before:
+            del tt._PROGRAMS[k]  # built under a mode this test set
+    n = 256
+    codes = jnp.zeros((n, 5), jnp.int32)
+    args = (codes, jnp.zeros(n), jnp.ones(n), jnp.ones(lay.T, bool))
+    if tt._pallas_state()[2]:
+        args = (codes, hp.make_codes8_fn(lay)(codes)) + args[1:]
+    return _eqns(jax.make_jaxpr(prog.fn)(*args).jaxpr, [])
+
+
+def test_tree_program_names_its_kernel_and_scopes(pallas_on):
+    eqns = _tree_program_eqns(3, (False, True, True, True))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernels
+    for e in kernels:
+        assert e.params["name"] == "tree_fused_level"
+        assert e.params["metadata"]["kernel"] == "tree_fused_level"
+        assert int(e.params["metadata"]["L"]) in (1, 2)
+    # a level's kernel is built at the width of the smaller children
+    by_scope = {(str(e.source_info.name_stack).split("/")[0],
+                 e.params["metadata"]["L"]) for e in kernels}
+    assert by_scope == {("tree.L1", "1"), ("tree.L2", "1"),
+                        ("tree.L4", "2")}
+    stacks = {str(e.source_info.name_stack) for e in eqns}
+    want = {"tree.L%d/%s" % (L, ph) for L in (1, 2, 4)
+            for ph in ("hist", "route")}
+    want |= {"tree.L%d/%s" % (L, ph) for L in (2, 4)
+             for ph in ("derive", "scan")}
+    want.add("tree.leaf")
+    assert want <= stacks
+    # the two routing gathers of every level sit in its route scope
+    for L in (1, 2, 4):
+        routed = [e for e in eqns if e.primitive.name == "gather"
+                  and str(e.source_info.name_stack).startswith(
+                      "tree.L%d/route" % L)]
+        assert len(routed) >= 2
+
+
+def test_tree_program_scopes_without_the_kernel():
+    """The XLA path (no Pallas: the CPU's default) carries the same scopes;
+    without subtraction a level has no derive phase."""
+    eqns = _tree_program_eqns(2, ())
+    assert not [e for e in eqns if e.primitive.name == "pallas_call"]
+    stacks = {str(e.source_info.name_stack) for e in eqns}
+    for scope in ("tree.L1/hist", "tree.L1/scan", "tree.L1/route",
+                  "tree.L2/hist", "tree.L2/scan", "tree.L2/route",
+                  "tree.leaf"):
+        assert any(s.startswith(scope) for s in stacks), scope
+    assert not any("/derive" in s for s in stacks)
+
+
+def test_hist_mode_kernel_is_named_tree_hist(pallas_on):
+    from shifu_tpu.ops import hist_pallas as hp
+
+    lay = tt.make_layout([SLOTS] * 5, [False] * 5)
+    fn = hp.make_pallas_hist_fn(4, lay)
+    n = 128
+    jp = jax.make_jaxpr(fn)(jnp.zeros((n, 5), jnp.int32), jnp.zeros(n),
+                            jnp.ones(n), jnp.zeros(n, jnp.int32),
+                            jnp.ones(n, bool))
+    kernels = [e for e in _eqns(jp.jaxpr, [])
+               if e.primitive.name == "pallas_call"]
+    assert kernels and all(
+        e.params["name"] == "tree_hist"
+        and dict(e.params["metadata"]) == {"kernel": "tree_hist", "L": "4"}
+        for e in kernels)
+    ann = obs.profiler().snapshot()["annotations"]["ops.hist_pallas"]
+    assert ann["kernel"] == "tree_hist"  # manifest and trace agree
+
+
+def test_nn_program_carries_its_scopes():
+    from shifu_tpu.models.nn import flatten_params, init_params
+
+    cfg = nt.NNTrainConfig(hidden_nodes=[7], activations=["tanh"],
+                           propagation="R")
+    flat0, shapes = flatten_params(
+        init_params([F, 7, 1], seed=0, init=cfg.weight_init))
+    program, init_state = nt._get_program(cfg, shapes, 64)
+    flat = jnp.asarray(flat0)
+    carry = (flat, init_state(flat0.size), jnp.int32(0), jnp.float32(0.1),
+             jnp.float32(np.inf), flat, jnp.int32(0),
+             jnp.zeros((), dtype=bool), jnp.float32(0.0), jnp.float32(0.0))
+    row = jnp.ones(64)
+    jp = jax.make_jaxpr(program)(carry, jnp.int32(2), jnp.ones((64, F)),
+                                 row, row, row, jax.random.PRNGKey(0),
+                                 jnp.float32(1.0))
+    eqns = _eqns(jp.jaxpr, [])
+    stacks = {str(e.source_info.name_stack) for e in eqns}
+    for scope in ("nn.bwd/jvp(nn.fwd)", "nn.bwd/transpose(jvp(nn.fwd))",
+                  "nn.valid", "nn.update"):
+        assert any(scope in s for s in stacks), (scope, sorted(stacks))
+    # both matmuls of the forward pass and their transposes are named
+    dots = [str(e.source_info.name_stack) for e in eqns
+            if e.primitive.name == "dot_general"]
+    assert sum("nn.bwd/jvp(nn.fwd)" in s for s in dots) == 2
+    assert sum("transpose(jvp(nn.fwd))" in s for s in dots) >= 2
