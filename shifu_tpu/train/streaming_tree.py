@@ -51,6 +51,7 @@ from shifu_tpu.train.tree_trainer import (
     _get_update_program,
     _node_batch_size,
     _record_hist_counters,
+    _record_route_counters,
     _scan_batched,
     _sub_acc64,
     _sub_plan,
@@ -142,13 +143,11 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
         for wk, codes_host in _iter_codes(feed, work):
             codes_s = row_put(pad_to_mesh(codes_host))
             if pending is not None:
-                pbf, pbr, prank, psplit, pbase, pL = pending
-                upd = _get_update_program(pL, lay.T)
-                wk["resting"], wk["node"], wk["active"] = upd(
-                    codes_s, wk["node"], wk["active"], wk["resting"],
-                    pbf, pbr, prank, psplit, jnp.int32(pbase), la.off,
-                    la.clip,
-                )
+                pbf, psplit, pmask, pbase = pending
+                wk["resting"], wk["node"], wk["active"] = (
+                    _get_update_program()(
+                        codes_s, wk["node"], wk["active"], wk["resting"],
+                        pbf, psplit, pmask, jnp.int32(pbase), la.clip))
             for bi, (b0, Lb) in enumerate(ranges):
                 # -Dshifu.pallas.mode routes this through the hist-mode
                 # Pallas kernel (inside shard_map on a mesh): per-shard
@@ -186,7 +185,7 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
             n_built += L
             if sub_on and depth >= 1:
                 n_fallback += len(ranges)
-        (bf, br, rank_flat, lv, is_split, _g, lm, nc, lc) = _scan_batched(
+        (bf, _br, _rank, lv, is_split, _g, lm, nc, lc) = _scan_batched(
             scan_parts, la, lay, cfg, L,
         )
         if depth == D:  # final level: leaves only + settle leftovers
@@ -206,11 +205,12 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
             prev = (hist_acc, is_split, lc, nc)
         else:
             prev = None
-        pending = (bf, br, rank_flat, is_split, base, L)
+        pending = (bf, is_split, lm, base)
         feat_levels.append(jnp.where(is_split, bf, -1))
         mask_levels.append(lm)
         leaf_levels.append(lv)
     _record_hist_counters(n_built, n_derived, n_fallback)
+    _record_route_counters(D, lay.s_max)
 
     feature, left_mask, leaf_value = jax.device_get(
         (jnp.concatenate(feat_levels),

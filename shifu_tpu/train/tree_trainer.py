@@ -877,32 +877,85 @@ def _make_cls_scan(L: int, T: int, s_max: int, impurity: str, min_inst: int,
     return cls_scan
 
 
-def _get_update_program(L: int, T: int):
-    key = ("update", L, T)
-    prog = _PROGRAMS.get(key)
-    if prog is not None:
-        return prog
-    import jax
+# Row routing looks its per-row values up in tables that are small and
+# static in length: a level's per-node scalars [L] and its packed mask words
+# [L * ceil(s_max / 32)]. Up to this many entries the lookup is a
+# compare-select over the table's axis, which the TPU's vector unit streams
+# (one v5e, 5.5 M rows: 1.0 ms at 64 entries, 10 ms at 1,024, 40 ms at
+# 4,096); past it a 1-D gather, which costs the same 27-43 ms whatever the
+# table. PERF.md section 6 (PR 27) has the readings.
+_ROUTE_SELECT_CAP = 4096
+
+
+def _mask_words(s_max: int) -> int:
+    return -(-s_max // 32)
+
+
+def route_is_dense(L: int, s_max: int) -> bool:
+    """Whether a level of L nodes routes its rows with no per-row gather at
+    all (`tree.route.dense`), or takes its mask word by a 1-D gather
+    (`tree.route.gather`): a rule on static shapes alone."""
+    return L * _mask_words(s_max) <= _ROUTE_SELECT_CAP
+
+
+def _lookup(table, idx):
+    """table[idx] for every row; `table` is 1-D."""
     import jax.numpy as jnp
 
-    @jax.jit
-    def row_update(codes, node_slot, active, resting, feature, cut_rank,
-                   rank_flat, is_split, base, off_f, clip_f):
-        """Settle non-split rows at base+slot, send the rest left/right
-        (level-wise child numbering: 2i / 2i+1 within the next level)."""
-        nl = jnp.clip(node_slot, 0, L - 1)
-        settled = active & ~is_split[nl]
-        resting2 = jnp.where(settled, base + nl, resting)
-        f = jnp.where(is_split, feature, 0)[nl]
-        code = jnp.take_along_axis(codes, f[:, None], axis=1)[:, 0]
-        cf = off_f[f] + jnp.clip(code, 0, clip_f[f])
-        goes_left = rank_flat[nl, cf] <= cut_rank[nl]
-        new_local = jnp.where(goes_left, 2 * nl, 2 * nl + 1)
-        still = is_split[nl] & active
-        return resting2, jnp.where(still, new_local, 0), still
+    N = table.shape[0]
+    if N > _ROUTE_SELECT_CAP:
+        return table[idx]
+    hit = idx[:, None] == jnp.arange(N, dtype=jnp.int32)
+    return jnp.where(hit, table, 0).sum(axis=1, dtype=table.dtype)
 
-    prog = profile.wrap("tree.row_update", row_update)
-    _PROGRAMS[key] = prog
+
+def route_rows(codes, node, active, resting, feature, is_split, left_mask,
+               base, clip_f):
+    """Move a level's rows one level down: rows of a node that does not
+    split settle at base + node, the others go to child 2i or 2i + 1
+    (level-wise numbering within the next level) as the level's
+    `left_mask` [L, s_max] says of their code at the node's feature: the
+    mask the served model follows, so training and serving route alike.
+
+    No per-row gather from a 2-D table (a TPU v5e runs those at 16-20 ns a
+    row whatever the table): the row's code is a select over the static
+    feature axis that streams `codes` once, its node's feature, clip and
+    mask word a `_lookup` each, the mask packed 32 slots a word. Returns
+    (resting, node, active) for the next level. Traced inside the
+    whole-tree program (every level) and alone as `tree.row_update` (the
+    node-batched and streamed growers)."""
+    import jax.numpy as jnp
+
+    L, s_max = left_mask.shape
+    F = codes.shape[1]
+    W = _mask_words(s_max)
+    bits = jnp.pad(left_mask, ((0, 0), (0, W * 32 - s_max)))
+    words = (bits.reshape(L * W, 32).astype(jnp.uint32)
+             << jnp.arange(32, dtype=jnp.uint32)).sum(axis=1,
+                                                      dtype=jnp.uint32)
+
+    nl = jnp.clip(node, 0, L - 1)
+    f = _lookup(jnp.where(is_split, feature, -1), nl)  # -1: no split
+    split_row = f >= 0
+    resting = jnp.where(active & ~split_row, base + nl, resting)
+    code = jnp.where(f[:, None] == jnp.arange(F, dtype=jnp.int32),
+                     codes, 0).sum(axis=1)
+    c = jnp.clip(code, 0, _lookup(clip_f[feature], nl))
+    word = _lookup(words, nl * W + (c >> 5))
+    goes_left = ((word >> (c & 31).astype(jnp.uint32)) & 1) > 0
+    still = split_row & active
+    return (resting,
+            jnp.where(still, jnp.where(goes_left, 2 * nl, 2 * nl + 1), 0),
+            still)
+
+
+def _get_update_program():
+    prog = _PROGRAMS.get("update")
+    if prog is None:
+        import jax
+
+        prog = profile.wrap("tree.row_update", jax.jit(route_rows))
+        _PROGRAMS["update"] = prog
     return prog
 
 
@@ -1053,6 +1106,26 @@ def _record_hist_counters(built: int, derived: int, fallback: int) -> None:
         reg.counter("tree.hist.derived").inc(derived)
     if fallback:
         reg.counter("tree.hist.fallback_rebuilds").inc(fallback)
+
+
+def _route_counts(D: int, s_max: int) -> Tuple[int, int]:
+    """(dense, gather) levels of one level-wise tree of depth D: how
+    `route_rows` moves the rows of levels 1, 2, ..., 2^(D-1)."""
+    dense = sum(route_is_dense(2**d, s_max) for d in range(D))
+    return dense, D - dense
+
+
+def _record_route_counters(D: int, s_max: int) -> None:
+    """`tree.route.dense` / `tree.route.gather`: a tree's levels routed
+    each way, counted beside `tree.hist.built`."""
+    from shifu_tpu.obs import registry
+
+    dense, gather = _route_counts(D, s_max)
+    reg = registry()
+    if dense:
+        reg.counter("tree.route.dense").inc(dense)
+    if gather:
+        reg.counter("tree.route.gather").inc(gather)
 
 
 @dataclass
@@ -1337,7 +1410,7 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                     scan_d = xla_scan(d - 1, derived.astype(jnp.float32),
                                       raw=True)
                 with phase(L, "derive"):
-                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                    (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = tuple(
                         _interleave_children(left_small, xb, xd)
                         for xb, xd in zip(scan_b, scan_d))
@@ -1350,7 +1423,7 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                     hist, scan_t = fused_fns[d](codes, codes8, labels,
                                                 weights, node, active,
                                                 feat_ok_t)
-                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                    (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = scan_t
                     hist_acc = hist.astype(acc_dt) if acc64 else hist
             elif prev is not None:  # sub_levels[d]: derive from the parent
@@ -1364,31 +1437,21 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                     hist, hist_acc = derive(p_hist, built, p_split,
                                             left_small)
                 with phase(L, "scan"):
-                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                    (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = xla_scan(d, hist)
             else:
                 with phase(L, "hist"):
                     hist = call_hist(d, node, active)
                     hist_acc = hist.astype(acc_dt) if acc64 else hist
                 with phase(L, "scan"):
-                    (bf, br, rank_flat, lv, is_split, _g, lm, nc,
+                    (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = xla_scan(d, hist)
             prev = ((hist_acc, is_split, lc, nc)
                     if d + 1 < D and sub_levels[d + 1] else None)
-            base = L - 1
             with phase(L, "route"):
-                nl = jnp.clip(node, 0, L - 1)
-                settled = active & ~is_split[nl]
-                resting = jnp.where(settled, base + nl, resting)
-                f = jnp.where(is_split, bf, 0)[nl]
-                code = jnp.take_along_axis(codes, f[:, None], axis=1)[:, 0]
-                cf = off_c[f] + jnp.clip(code, 0, clip_c[f])
-                goes_left = rank_flat[nl, cf] <= br[nl]
-                still = is_split[nl] & active
-                node = jnp.where(still,
-                                 jnp.where(goes_left, 2 * nl, 2 * nl + 1),
-                                 0)
-                active = still
+                resting, node, active = route_rows(
+                    codes, node, active, resting, bf, is_split, lm,
+                    L - 1, clip_c)
             feats_l.append(jnp.where(is_split, bf, -1))
             masks_l.append(lm)
             leaves_l.append(lv)
@@ -1406,7 +1469,8 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         mask_flat = jnp.concatenate(
             masks_l + [jnp.zeros((L2, s_max), bool)], axis=0)
         leaf_flat = jnp.concatenate(leaves_l)
-        row_pred = leaf_flat[resting]
+        with jax.named_scope("tree.leaf"):
+            row_pred = _lookup(leaf_flat, resting)
         return feat_flat, mask_flat, leaf_flat, resting, row_pred
 
     if on_mesh:
@@ -1508,6 +1572,7 @@ def build_tree(
 
         _record_hist_counters(
             *_plan_counts(sub_levels[:D], cfg.hist_subtraction))
+        _record_route_counters(D, lay.s_max)
         feats_h, masks_h, leaves_h = jax.device_get(
             (feats_d, masks_d, leaves_d))
         return _assemble_dense_tree(feats_h, masks_h, leaves_h, D), resting
@@ -1585,7 +1650,7 @@ def build_tree(
             if sub_on and depth >= 1:
                 n_fallback += -(-L // batch_cap)
 
-        (bf, br, rank_flat, lv, is_split, _gain, lm, nc, lc) = _scan_batched(
+        (bf, _br, _rank, lv, is_split, _gain, lm, nc, lc) = _scan_batched(
             parts, la, lay, cfg, L
         )
         if final:  # leaf values for the deepest children + settle leftovers
@@ -1595,15 +1660,14 @@ def build_tree(
             resting = jnp.where(active, base + node_local, resting)
             break
         prev = (hist_acc, is_split, lc, nc) if retain_next else None
-        upd = _get_update_program(L, lay.T)
-        resting, node_local, active = upd(
-            codes, node_local, active, resting, bf, br, rank_flat, is_split,
-            jnp.int32(base), la.off, la.clip,
-        )
+        resting, node_local, active = _get_update_program()(
+            codes, node_local, active, resting, bf, is_split, lm,
+            jnp.int32(base), la.clip)
         feat_levels.append(jnp.where(is_split, bf, -1))
         mask_levels.append(lm)
         leaf_levels.append(lv)
     _record_hist_counters(n_built, n_derived, n_fallback)
+    _record_route_counters(D, lay.s_max)
 
     # ONE host sync for the whole tree
     import jax
@@ -2270,6 +2334,7 @@ def train_trees(
                         feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
                             codes_j, labels_k, w_k, fot)
                     _record_hist_counters(*sub_counts)
+                    _record_route_counters(cfg.max_depth, lay.s_max)
                     deferred.append(
                         (k, 1.0 if (is_gbt and k == 0) else (lr if is_gbt else 1.0),
                          feats_d, masks_d, leaves_d))

@@ -254,12 +254,15 @@ def test_tree_program_names_its_kernel_and_scopes(pallas_on):
              for ph in ("derive", "scan")}
     want.add("tree.leaf")
     assert want <= stacks
-    # the two routing gathers of every level sit in its route scope
+    # every level's routing sits in its route scope: selects summed over
+    # a static axis, and no gather from a 2-D table (tests/test_route_rows.py
+    # holds it to that at the GBT cell's shape)
     for L in (1, 2, 4):
-        routed = [e for e in eqns if e.primitive.name == "gather"
-                  and str(e.source_info.name_stack).startswith(
-                      "tree.L%d/route" % L)]
-        assert len(routed) >= 2
+        routed = [e for e in eqns if str(e.source_info.name_stack).startswith(
+            "tree.L%d/route" % L)]
+        assert [e for e in routed if e.primitive.name == "reduce_sum"]
+        assert not [e for e in routed if e.primitive.name == "gather"
+                    and e.invars[0].aval.ndim > 1]
 
 
 def test_tree_program_scopes_without_the_kernel():
