@@ -184,38 +184,63 @@ def round_up_rows(n: int, mesh) -> int:
 
 
 def pad_rows(
-    arrays: Sequence[np.ndarray], multiple: int
+    arrays: Sequence, multiple: int
 ) -> Tuple[list, int]:
     """Pad row dimension to a multiple (sharding needs even splits). Padded
-    rows must carry zero significance — callers pad weights with 0."""
+    rows must carry zero significance — callers pad weights with 0. Host
+    arrays are padded on the host and `jax.Array`s on their devices; an
+    array that already carries padding rows (a code matrix placed over the
+    mesh ahead of the call) is kept and the others are padded up to it.
+    Returns the arrays and the first one's row count as it came in."""
+    import jax
+
     n = arrays[0].shape[0]
-    target = ((n + multiple - 1) // multiple) * multiple
-    if target == n:
-        return list(arrays), n
+    longest = max(a.shape[0] for a in arrays)
+    target = ((longest + multiple - 1) // multiple) * multiple
     out = []
     for a in arrays:
-        pad_shape = (target - n,) + a.shape[1:]
-        out.append(np.concatenate([a, np.zeros(pad_shape, dtype=a.dtype)], axis=0))
+        short = target - a.shape[0]
+        if not short:
+            out.append(a)
+        elif isinstance(a, jax.Array):
+            import jax.numpy as jnp
+
+            out.append(jnp.pad(a, [(0, short)] + [(0, 0)] * (a.ndim - 1)))
+        else:
+            out.append(np.concatenate(
+                [a, np.zeros((short,) + a.shape[1:], dtype=a.dtype)], axis=0))
     return out, n
+
+
+def _row_sharding(mesh, ndim: int):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    axes = row_axes(mesh)
+    return NamedSharding(mesh, P(axes if len(axes) > 1 else axes[0],
+                                 *([None] * (ndim - 1))))
 
 
 def shard_rows(array, mesh):
     """Place an array on the mesh sharded along its leading (row) axis —
-    over (dcn, data) on a multi-slice mesh."""
+    over (dcn, data) on a multi-slice mesh. A `jax.Array` that already lies
+    so is returned as it is, and one that lies elsewhere on the devices
+    moves between them: `mesh.h2d_bytes` counts only what crosses from the
+    host."""
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from shifu_tpu.obs import registry
 
-    axes = row_axes(mesh)
-    spec = P(axes if len(axes) > 1 else axes[0],
-             *([None] * (array.ndim - 1)))
+    sharding = _row_sharding(mesh, array.ndim)
+    on_device = isinstance(array, jax.Array)
+    if on_device and array.sharding.is_equivalent_to(sharding, array.ndim):
+        return array
     # collective-op accounting: every sharded placement seeds a program
     # whose row-sharded consumption XLA closes with a psum over `axes`
     reg = registry()
-    reg.counter("mesh.shard_rows", axes="x".join(axes)).inc()
-    reg.counter("mesh.h2d_bytes").inc(float(getattr(array, "nbytes", 0)))
-    out = jax.device_put(array, NamedSharding(mesh, spec))
+    reg.counter("mesh.shard_rows", axes="x".join(row_axes(mesh))).inc()
+    if not on_device:
+        reg.counter("mesh.h2d_bytes").inc(float(getattr(array, "nbytes", 0)))
+    out = jax.device_put(array, sharding)
     # where the rows actually landed (shape metadata, no transfer): a
     # placement that put everything on one device shows here
     shards = out.addressable_shards
@@ -224,6 +249,19 @@ def shard_rows(array, mesh):
     reg.gauge("mesh.rows_per_device.max").set(max(rows))
     reg.gauge("mesh.row_devices").set(len({s.device.id for s in shards}))
     return out
+
+
+def pull_rows(array) -> np.ndarray:
+    """A row array as a host array; what leaves the devices for it is
+    counted in `mesh.d2h_bytes` (the in-memory tree trainer pulls none on a
+    fresh run: only a resumed forest's per-row scores come back)."""
+    import jax
+
+    if isinstance(array, jax.Array):
+        from shifu_tpu.obs import registry
+
+        registry().counter("mesh.d2h_bytes").inc(float(array.nbytes))
+    return np.asarray(array)
 
 
 def replicate(tree, mesh):

@@ -123,6 +123,13 @@ def train_tree_models(proc, alg) -> None:
     from shifu_tpu.parallel.mesh import data_mesh
 
     mesh = data_mesh() if len(jax.devices()) > 1 else None
+    if mesh is not None and codes is not None:
+        # the code matrix is the big one: padded to the mesh and placed
+        # once for every bag (train_trees keeps a row-sharded array where
+        # it lies, and takes its row count from the tags)
+        from shifu_tpu.parallel.mesh import pad_rows, shard_rows
+
+        codes = shard_rows(pad_rows([codes], mesh.devices.size)[0][0], mesh)
 
     from shifu_tpu.models.tree import TreeModelSpec
 

@@ -1108,6 +1108,31 @@ def _record_hist_counters(built: int, derived: int, fallback: int) -> None:
         reg.counter("tree.hist.fallback_rebuilds").inc(fallback)
 
 
+def _psum_counts(D: int, T: int, sub_levels: tuple,
+                 n_classes: int = 0) -> Tuple[int, int]:
+    """(all-reduces, bytes each chip hands in) of one meshed whole-tree
+    program, from static shapes alone: a float32 [C, L, T] histogram a
+    level (half as wide where the level is built by subtraction) and the
+    [C, 2^D] leaf totals."""
+    c_hist = n_classes if n_classes >= 3 else 3
+    c_leaf = n_classes if n_classes >= 3 else 2
+    nodes = sum(2**d // 2 if d and sub_levels[d] else 2**d
+                for d in range(D))
+    return D + 1, 4 * (c_hist * nodes * T + c_leaf * 2**D)
+
+
+def _record_psum_counters(D: int, T: int, sub_levels: tuple,
+                          n_classes: int = 0) -> None:
+    """`tree.psum` / `tree.psum.bytes`: what one meshed tree all-reduces,
+    counted beside `tree.hist.built`."""
+    from shifu_tpu.obs import registry
+
+    count, nbytes = _psum_counts(D, T, sub_levels, n_classes)
+    reg = registry()
+    reg.counter("tree.psum").inc(count)
+    reg.counter("tree.psum.bytes").inc(nbytes)
+
+
 def _route_counts(D: int, s_max: int) -> Tuple[int, int]:
     """(dense, gather) levels of one level-wise tree of depth D: how
     `route_rows` moves the rows of levels 1, 2, ..., 2^(D-1)."""
@@ -1363,13 +1388,20 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         feats_l, masks_l, leaves_l = [], [], []
         prev = None  # retained parent level (hist_acc, is_split, lcnt, ncnt)
 
-        def call_hist(idx, node_arg, act_arg):
-            if with_m:
-                h = hist_m_fns[idx](M, labels, weights, node_arg, act_arg)
-            else:
-                h = hist_fns[idx](codes, labels, weights, node_arg, act_arg,
-                                  off_c, clip_c, seg_c, pos_c)
-            return jax.lax.psum(h, r_axes) if on_mesh else h
+        def call_hist(L, idx, node_arg, act_arg):
+            with phase(L, "hist"):
+                if with_m:
+                    h = hist_m_fns[idx](M, labels, weights, node_arg,
+                                        act_arg)
+                else:
+                    h = hist_fns[idx](codes, labels, weights, node_arg,
+                                      act_arg, off_c, clip_c, seg_c, pos_c)
+            if on_mesh:
+                # the level's all-reduce under a scope of its own: it waits
+                # for the slowest chip, which the histogram does not
+                with phase(L, "psum"):
+                    h = jax.lax.psum(h, r_axes)
+            return h
 
         def xla_scan(idx, hist, raw=False):
             fn = raw_scan_fns[idx] if raw else scan_fns[idx]
@@ -1382,7 +1414,9 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         # whatever the fusions are numbered. `hist` is the kernel or
         # `call_hist` with its pads and casts, `derive` the subtraction
         # and interleave, `scan` the XLA scan where it runs, `route` the
-        # rows' move to their children.
+        # rows' move to their children; under a mesh `psum` is the
+        # all-reduce of the level's histogram (`tree.leaf/psum`: of the
+        # leaf totals).
         def phase(L, what):
             return jax.named_scope("tree.L%d/%s" % (L, what))
 
@@ -1432,7 +1466,7 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                     left_small = p_lcnt <= p_ncnt - p_lcnt
                     nhalf, build_row = _sub_row_masks(node, active,
                                                       left_small)
-                    built = call_hist(d - 1, nhalf, build_row)
+                built = call_hist(L, d - 1, nhalf, build_row)
                 with phase(L, "derive"):
                     hist, hist_acc = derive(p_hist, built, p_split,
                                             left_small)
@@ -1440,9 +1474,8 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                     (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = xla_scan(d, hist)
             else:
-                with phase(L, "hist"):
-                    hist = call_hist(d, node, active)
-                    hist_acc = hist.astype(acc_dt) if acc64 else hist
+                hist = call_hist(L, d, node, active)
+                hist_acc = hist.astype(acc_dt) if acc64 else hist
                 with phase(L, "scan"):
                     (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = xla_scan(d, hist)
@@ -1461,7 +1494,8 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         with jax.named_scope("tree.leaf"):
             acc = leaf_acc(labels, weights, node, active)
             if on_mesh:
-                acc = jax.lax.psum(acc, r_axes)
+                with jax.named_scope("psum"):  # tree.leaf/psum
+                    acc = jax.lax.psum(acc, r_axes)
             leaves_l.append(leaf_finalize(acc))
             resting = jnp.where(active, (L2 - 1) + node, resting)
         feat_flat = jnp.concatenate(
@@ -1573,6 +1607,8 @@ def build_tree(
         _record_hist_counters(
             *_plan_counts(sub_levels[:D], cfg.hist_subtraction))
         _record_route_counters(D, lay.s_max)
+        if mesh is not None:
+            _record_psum_counters(D, lay.T, sub_levels, cfg.n_classes)
         feats_h, masks_h, leaves_h = jax.device_get(
             (feats_d, masks_d, leaves_d))
         return _assemble_dense_tree(feats_h, masks_h, leaves_h, D), resting
@@ -2065,29 +2101,41 @@ def train_trees(
               trees=int(cfg.tree_num), depth=int(cfg.max_depth)):
         with span("train.trees.prologue", call=call):
             n, F = codes.shape
-            n_orig = n  # rng draws always use the UNpadded count so the stream (and
-            # therefore every tree) is identical with and without a mesh
-            valid_mask = np.random.default_rng([cfg.seed, 999_983]).random(n) \
-                < cfg.valid_set_rate
-            if mesh is not None:
-                from shifu_tpu.parallel.mesh import pad_rows, shard_rows
+            # rng draws always use the UNpadded count so the stream (and
+            # therefore every tree) is identical with and without a mesh; a
+            # code matrix placed over the mesh ahead of the call may carry
+            # the mesh's padding rows, the tags never do
+            n_orig = int(np.shape(tags)[0])
+            valid_mask = np.random.default_rng([cfg.seed, 999_983]).random(
+                n_orig) < cfg.valid_set_rate
+            from shifu_tpu.parallel.mesh import (pad_rows, pull_rows,
+                                                 shard_rows)
 
+            if mesh is not None:
                 row_put = lambda a: shard_rows(a, mesh)  # noqa: E731
-                codes_np = np.asarray(codes, np.int32)
-                y_np = np.asarray(tags, np.float32)
-                base_w_np = np.where(valid_mask, 0.0,
-                                     np.asarray(weights)).astype(np.float32)
-                real_np = np.ones(n, dtype=bool)
-                n_dev = mesh.devices.size
-                (codes_np, y_np, base_w_np, valid_mask, real_np), _ = pad_rows(
-                    [codes_np, y_np, base_w_np, valid_mask, real_np], n_dev
-                )
-                n = codes_np.shape[0]
-                codes_j = shard_rows(codes_np, mesh)
-                y_j = shard_rows(y_np, mesh)
-                vm_j = shard_rows(valid_mask, mesh)
-                base_w_j = shard_rows(base_w_np, mesh)
-                real_j = shard_rows(real_np, mesh)
+                # row-sharded jax.Arrays stay on the mesh as they lie (cast
+                # and, where the row count does not divide, padded on the
+                # devices); host arrays are padded on the host and put once.
+                # Either way the validity draw is the one array made here.
+                on_device = isinstance(codes, jax.Array)
+                h2d = registry().counter("mesh.h2d_bytes")
+                with span("train.trees.shard", call=call,
+                          source="device" if on_device else "host") as sh:
+                    crossed = h2d.value
+                    rows_in = [
+                        a.astype(dt) if isinstance(a, jax.Array)
+                        else np.asarray(a, dt)
+                        for a, dt in ((codes, np.int32), (tags, np.float32),
+                                      (weights, np.float32))]
+                    rows_in += [valid_mask,
+                                jnp.ones(n_orig, bool) if on_device
+                                else np.ones(n_orig, bool)]
+                    padded, _ = pad_rows(rows_in, mesh.devices.size)
+                    n = padded[0].shape[0]
+                    codes_j, y_j, w_j, vm_j, real_j = [
+                        shard_rows(a, mesh) for a in padded]
+                    base_w_j = jnp.where(vm_j, 0.0, w_j)
+                    sh["bytes"] = int(h2d.value - crossed)
             else:
                 # device-resident inputs stay on device (the code matrix is the
                 # big one and may already live in HBM from a previous run)
@@ -2139,7 +2187,7 @@ def train_trees(
                 if start_k:
                     from shifu_tpu.models.tree import traverse_trees
 
-                    per_tree = np.asarray(
+                    per_tree = pull_rows(
                         traverse_trees(trees, codes_j))  # [n, k] class
                     votes_np = np.zeros((n, cfg.n_classes), np.float32)
                     for col in range(per_tree.shape[1]):
@@ -2156,7 +2204,7 @@ def train_trees(
                     # so the running prediction matches the uninterrupted run
                     from shifu_tpu.models.tree import traverse_trees
 
-                    per_tree = np.asarray(
+                    per_tree = pull_rows(
                         traverse_trees(trees, codes_j))  # [n, k]
                     s = np.zeros(n, np.float32)
                     for col in range(per_tree.shape[1]):
@@ -2169,7 +2217,7 @@ def train_trees(
                             contrib = contrib * keep
                         s += contrib
                 else:
-                    s = np.asarray(_score_existing(trees, codes_j))
+                    s = pull_rows(_score_existing(trees, codes_j))
                 pred = row_put((s if is_gbt else s / start_k).astype(np.float32))
             else:
                 pred = row_put(jnp.zeros(n, dtype=jnp.float32))
@@ -2335,6 +2383,9 @@ def train_trees(
                             codes_j, labels_k, w_k, fot)
                     _record_hist_counters(*sub_counts)
                     _record_route_counters(cfg.max_depth, lay.s_max)
+                    if mesh is not None:
+                        _record_psum_counters(cfg.max_depth, lay.T,
+                                              sub_levels, cfg.n_classes)
                     deferred.append(
                         (k, 1.0 if (is_gbt and k == 0) else (lr if is_gbt else 1.0),
                          feats_d, masks_d, leaves_d))
