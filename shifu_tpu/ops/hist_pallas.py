@@ -16,9 +16,9 @@ and the scan. This kernel keeps BOTH in VMEM:
                          component, revisited across the grid (init at
                          block 0, += afterwards)
     per block          — the chunk's code one-hot M is built by ONE
-                         broadcast-compare over a LANE-ALIGNED padded
-                         column layout (below); a dot per component
-                         plane contracts the row axis on the MXU
+                         broadcast-compare over a DENSELY PACKED column
+                         layout (below); a dot per component plane
+                         contracts the row axis on the MXU
     last block         — the split scan runs in-kernel on the resident
                          planes (pairwise-rank formulation, below) and
                          emits per-column gain/rank/left-count planes,
@@ -26,20 +26,26 @@ and the scan. This kernel keeps BOTH in VMEM:
                          HBM by a second scan dispatch
 
 Three changes over the round-5 kernel (which was slower than the XLA
-lowering and shipped dark behind an env var; neither kernel's speed has
-been measured on today's chip — PERF.md):
+lowering and shipped dark behind an env var; what this kernel costs on
+the chip is in PERF.md, sections 5 and 6):
 
-1. LANE-ALIGNED COLUMN LAYOUT. The old kernel wrote each feature's
-   one-hot segment at its raw flat-T offset with per-run slice stores;
-   33/65-wide segments land mid-lane and Mosaic emits masked unaligned
-   lane stores. The rebuilt kernel pads every
-   feature piece to the 128-lane boundary INSIDE the kernel layout
-   (gaps are dead columns, masked out of the gain scan and dropped at
-   the [C, L, T] compaction — the output contract is unchanged) and
-   builds M with zero per-feature stores: a static selection matmul
-   broadcasts each column's code (codes_f32 @ E, exact in f32), then one
-   full-width compare against the static slot-position row writes the
-   whole [blk, W] block aligned.
+1. DENSELY PACKED COLUMN LAYOUT, NO PER-FEATURE STORES. The old kernel
+   wrote each feature's one-hot segment at its raw flat-T offset with
+   per-run slice stores; 33/65-wide segments land mid-lane and Mosaic
+   emits masked unaligned lane stores. The rebuilt kernel builds M with
+   zero per-feature stores: a static selection matmul broadcasts each
+   column's code (codes_f32 @ E, exact in f32), then one full-width
+   compare against the static slot-position row writes the whole
+   [blk, W] block at once. A chunk's feature pieces sit SIDE BY SIDE in
+   the lanes and only the chunk's total width is rounded up to 128
+   (the tail is dead columns, masked out of the gain scan and dropped
+   at the [C, L, T] compaction — the output contract is unchanged).
+   Until PR 29 every piece started at a 128-lane boundary, a leftover
+   of the per-feature stores: nothing in this kernel finds a column by
+   its position (the scan goes by the seg / size / iscat metadata
+   rows), so the alignment bought nothing and cost HIGGS's 28 x 33
+   slots 3,584 columns for 924 and 7 calls a level for 2 (PERF.md,
+   section 6).
 
 2. LOW-PRECISION PLANES. Bin codes travel int8 in HBM for chunks whose
    features all fit 128 slots (4x less code-read bandwidth than i32 —
@@ -86,7 +92,7 @@ from typing import List, Optional
 
 import numpy as np
 
-_LANE = 128  # TPU lane width: every feature piece starts lane-aligned
+_LANE = 128  # TPU lane width: a chunk's total width is a multiple of it
 
 # VMEM budget shaping: rows per grid step x max padded chunk columns.
 # M [BLK, W] + the [W, W] scan indicator + C [L, W] planes must sit well
@@ -106,7 +112,7 @@ _W_MAX = 1024
 # Compiled for a described v5e: at W = 1024 only L <= 2 fits (L = 8 asks
 # 27.5 MiB); at W = 512 every level the grower fuses (L <= 32, f32 and
 # bf16 planes) compiles on the gbt / rf / gbt_wide bench layouts. So
-# fused-scan chunking clamps to 512 padded columns even when
+# fused-scan chunking clamps to 512 columns even when
 # -Dshifu.pallas.wmax asks for wider (hist-only chunks honor the raw
 # knob); tests/test_chip_compile.py holds the rule to the compiler.
 _SCAN_W_CAP = 512
@@ -162,9 +168,11 @@ def _pad_lane(w: int) -> int:
 
 
 class _Chunk:
-    """One lane-aligned kernel chunk: a contiguous run of feature pieces,
-    each padded to the 128-lane boundary, plus the static per-column
-    metadata the kernel and the epilogue need."""
+    """One kernel chunk: a contiguous run of feature pieces (f, lo, hi,
+    col0) packed side by side from column 0, the total rounded up to the
+    128-lane boundary, plus the static per-column metadata the kernel and
+    the epilogue need. Only the tail past the last piece is dead
+    (`pos` = `seg` = -1, `scan_ok` = 0)."""
 
     __slots__ = ("pieces", "w", "f_lo", "f_hi", "pos", "feat_rel", "clip",
                  "seg", "size", "iscat", "scan_ok", "seg0", "t_idx",
@@ -174,7 +182,7 @@ class _Chunk:
         self.pieces = pieces
         self.f_lo = pieces[0][0]
         self.f_hi = pieces[-1][0] + 1
-        w = pieces[-1][3] + _pad_lane(pieces[-1][2] - pieces[-1][1])
+        w = _pad_lane(pieces[-1][3] + pieces[-1][2] - pieces[-1][1])
         self.w = w
         pos = np.full(w, -1, np.int32)
         feat_rel = np.zeros(w, np.int32)
@@ -209,18 +217,28 @@ class _Chunk:
                           for (f, _lo, _hi, _c0) in pieces)
 
 
-def _chunks(lay, target: Optional[int] = None) -> List[_Chunk]:
-    """Split the flat T axis into lane-aligned chunks of <= target padded
-    columns. Every feature piece starts at a 128-lane boundary; a feature
-    wider than the target spans several pieces/chunks (and is then
-    excluded from the in-kernel scan — the epilogue's XLA fallback owns
-    it). Chunks cover whole features of [0, T) in order, so the caller
-    can hand the kernel a contiguous column slice of the code matrix."""
+def _target(fused: bool = False, target: Optional[int] = None) -> int:
+    """Most columns a chunk may hold, in whole lanes: `target`, else the
+    wmax knob (under the fused scan clamped to `_SCAN_W_CAP`)."""
     if target is None:
         target = wmax_setting()
-    target = max(_LANE, (target // _LANE) * _LANE)
+        if fused:
+            target = min(target, _SCAN_W_CAP)
+    return max(_LANE, (target // _LANE) * _LANE)
+
+
+def _chunks(lay, target: Optional[int] = None) -> List[_Chunk]:
+    """Split the flat T axis into chunks of <= target columns, feature
+    pieces packed side by side (a piece starts where the last one ended;
+    the chunk's width alone is rounded up to 128 lanes). A feature that
+    fits the target lies whole in one chunk; a feature wider than the
+    target spans several pieces/chunks (and is then excluded from the
+    in-kernel scan — the epilogue's XLA fallback owns it). Chunks cover
+    whole features of [0, T) in order, so the caller can hand the kernel
+    a contiguous column slice of the code matrix."""
+    target = _target(target=target)
     slots = [int(s) for s in lay.slots]
-    whole = [_pad_lane(s) <= target for s in slots]
+    whole = [s <= target for s in slots]
     chunks: List[_Chunk] = []
     cur: List[tuple] = []
     cur_w = 0
@@ -232,14 +250,17 @@ def _chunks(lay, target: Optional[int] = None) -> List[_Chunk]:
             # its in-kernel scan sees only its own chunk's columns, so a
             # split would scan partial histograms — start a fresh chunk
             # instead (only over-wide features split, and those are the
-            # epilogue's XLA-fallback set)
-            if avail < _LANE or (whole[f] and _pad_lane(s) > avail):
+            # epilogue's XLA-fallback set; a piece of one joins a chunk
+            # only for a lane or more, since it turns the chunk's codes
+            # from int8 to int32)
+            fresh = s > avail if whole[f] else avail < _LANE
+            if fresh:
                 chunks.append(_Chunk(cur, lay, whole))
                 cur, cur_w = [], 0
                 continue
             take = min(s - lo, avail)
             cur.append((f, lo, lo + take, cur_w))
-            cur_w += _pad_lane(take)
+            cur_w += take
             lo += take
     if cur:
         chunks.append(_Chunk(cur, lay, whole))
@@ -249,11 +270,15 @@ def _chunks(lay, target: Optional[int] = None) -> List[_Chunk]:
 def wide_features(lay, target: Optional[int] = None) -> List[int]:
     """Features too wide for one chunk at this shaping — scanned by the
     XLA reference fallback instead of the in-kernel scan."""
-    if target is None:
-        target = wmax_setting()
-    target = max(_LANE, (target // _LANE) * _LANE)
+    target = _target(target=target)
     return [f for f, s in enumerate(int(x) for x in lay.slots)
-            if _pad_lane(s) > target]
+            if s > target]
+
+
+def kernel_calls(lay, fused: bool) -> int:
+    """Mosaic calls one histogram build makes: the layout's chunks under
+    the fused scan's column cap, or in hist mode."""
+    return len(_chunks(lay, _target(fused)))
 
 
 def kernel_name(do_scan: bool) -> str:
@@ -324,8 +349,8 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
         i = pl.program_id(0)
         grid_n = pl.num_programs(0)
 
-        # ---- M build: selection matmul + one aligned full-width compare
-        # (no per-feature stores: those land mid-lane, unaligned) ----
+        # ---- M build: selection matmul + one full-width compare (no
+        # per-feature stores: those land mid-lane, unaligned) ----
         codes_f = codes_ref[...].astype(jnp.float32)  # [blk, nf]
         sel = (jax.lax.broadcasted_iota(jnp.int32, (nf, W), 0)
                == featrel_ref[...]).astype(jnp.float32)  # [nf, W]
@@ -333,7 +358,7 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
             codes_f, sel, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)  # [blk, W]: code per col
         cb = jnp.clip(cb, 0.0, clip_ref[...].astype(jnp.float32))
-        # gap columns carry pos -1: clipped codes are >= 0, so M is 0
+        # tail columns carry pos -1: clipped codes are >= 0, so M is 0
         m_ref[...] = (cb == pos_ref[...].astype(jnp.float32)).astype(m_dt)
 
         comps = comps_ref[...]  # [blk, C]
@@ -396,7 +421,7 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
             seg_eq = segc_ref[...] == segr_ref[...]  # [W, W] static
             tie = (jax.lax.broadcasted_iota(jnp.int32, (W, W), 0)
                    <= jax.lax.broadcasted_iota(jnp.int32, (W, W), 1))
-            fok = featok_ref[...]  # [1, W] f32, gaps/wide already 0
+            fok = featok_ref[...]  # [1, W] f32, tail/wide already 0
             sizef = size_ref[...]  # [1, W] f32
             gain_rows, rank_rows, lcnt_rows = [], [], []
             for l in range(L):
@@ -605,7 +630,7 @@ def make_pallas_hist_fn(L: int, lay, n_classes: int = 0,
 
     C = n_classes if n_classes >= 3 else 3
     blk_max = blk_setting()
-    target = wmax_setting()
+    target = _target()
     chunks = _chunks(lay, target)
     comp_dt = jnp.bfloat16 if low_precision else jnp.float32
     _annotate(lay, chunks, L, False, low_precision, 0, interpret)
@@ -664,7 +689,7 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
 
     C = n_classes if n_classes >= 3 else 3
     blk_max = blk_setting()
-    target = min(wmax_setting(), _SCAN_W_CAP)
+    target = _target(fused=True)
     chunks = _chunks(lay, target)
     wide = wide_features(lay, target)
     comp_dt = jnp.bfloat16 if low_precision else jnp.float32
@@ -725,7 +750,7 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
             call = _build_call(lay.key, target, ci, L, C, blk, use_i8,
                                low_precision, scan_key, interpret)
             # dynamic per-tree feature mask folded with the static
-            # scannable/gap mask into one [1, W] plane
+            # scannable/tail mask into one [1, W] plane
             t_clamp = np.where(ch.t_idx >= 0, ch.t_idx, 0)
             fok = (fok_f[jnp.asarray(t_clamp)]
                    * jnp.asarray((ch.scan_ok > 0)

@@ -20,12 +20,14 @@ import jax.numpy as jnp  # noqa: E402
 from shifu_tpu.ops import hist_pallas as hp  # noqa: E402
 from shifu_tpu.train import tree_trainer as tt  # noqa: E402
 
-# the three bench layouts (bench.py GBT / _rf_slots / _gbt_wide_slots)
+# the three bench layouts (bench.py GBT / _rf_slots / _gbt_wide_slots) and
+# the benchmark's GBT cells' (benchmarks/configs/higgs_gbt*.json)
 LAYOUTS = {
     "gbt": ([33] * 30, [False] * 30),
     "rf": ([33] * 20 + [65] * 10, [False] * 20 + [True] * 10),
     "gbt_wide": ([33] * 180 + [65] * 19 + [2001],
                  [False] * 180 + [True] * 20),
+    "higgs": ([33] * 28, [False] * 28),
 }
 N_ROWS = 65_536
 L_MAX = tt._FUSED_SCAN_L_CAP  # the largest level the static rule fuses
@@ -69,14 +71,20 @@ def _compiled_kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("lowp", [False, True], ids=["f32", "bf16"])
-@pytest.mark.parametrize("L", [1, 64])
-def test_hist_kernel_compiles(one_chip, L, lowp):
-    slots, is_cat = LAYOUTS["gbt"]
+# higgs at wmax 1,024 is ONE chunk of 28 features side by side (924 of
+# 1,024 columns), L_MAX the widest level the mesh4 cell builds
+@pytest.mark.parametrize("layout,L,lowp", [
+    ("gbt", 1, False), ("gbt", 1, True), ("gbt", 64, False),
+    ("gbt", 64, True), ("higgs", 1, True), ("higgs", L_MAX, True)])
+def test_hist_kernel_compiles(one_chip, layout, L, lowp):
+    slots, is_cat = LAYOUTS[layout]
     lay = tt.make_layout(slots, is_cat)
     fn = hp.make_pallas_hist_fn(L, lay, low_precision=lowp)
     compiled = jax.jit(fn).lower(*_row_args(one_chip, len(slots))).compile()
     assert _compiled_kernels(compiled) == len(hp._chunks(lay))
+    if layout == "higgs":
+        assert [(ch.w, ch.f_hi - ch.f_lo) for ch in hp._chunks(lay)] == [
+            (1024, 28)]
 
 
 def _distinct_kernels(lay):
@@ -93,7 +101,7 @@ def _distinct_kernels(lay):
 
 # f32 planes are RF's, bf16 planes are GBT's (tree_trainer._low_precision)
 @pytest.mark.parametrize("layout,lowp", [
-    ("gbt", True), ("rf", False), ("gbt_wide", True)])
+    ("gbt", True), ("rf", False), ("gbt_wide", True), ("higgs", True)])
 @pytest.mark.parametrize("L", [1, L_MAX], ids=["Lmin", "Lmax"])
 def test_fused_kernels_compile_where_the_rule_admits(one_chip, layout,
                                                      lowp, L):
@@ -139,6 +147,37 @@ def test_fused_level_entry_compiles(one_chip, layout):
         hp._chunks(lay, hp._SCAN_W_CAP))
     assert hp.wide_features(lay, hp._SCAN_W_CAP) == (
         [199] if layout == "gbt_wide" else [])
+
+
+@pytest.mark.parametrize("meshed,want", [(False, 12), (True, 6)],
+                         ids=["fused", "hist-mode"])
+def test_whole_tree_program_kernel_count_at_higgs(one_chip, monkeypatch,
+                                                  meshed, want):
+    """The whole-tree program of the benchmark's GBT cells (28 x 33 slots,
+    depth 6, subtraction on, bf16 planes): 2 chunks x 6 built levels under
+    the fused scan, 1 x 6 where every level runs the hist-mode kernel, as
+    the meshed grower's do (here on one described chip, without the mesh:
+    `_pallas_state` is steered in the test, as it is off the chip anyway).
+    The counter's arithmetic and the compiler's count agree."""
+    slots, is_cat = LAYOUTS["higgs"]
+    lay = tt.make_layout(slots, is_cat)
+    D, sub_levels = 6, (False,) + (True,) * 6
+    monkeypatch.setattr(tt, "_pallas_state",
+                        lambda mesh=None: (True, False, not meshed))
+    key_before = set(tt._PROGRAMS)
+    try:
+        prog = tt._get_tree_program(D, lay, "variance", 5, 0.0,
+                                    sub_levels=sub_levels, lowp=True)
+    finally:
+        for k in set(tt._PROGRAMS) - key_before:
+            del tt._PROGRAMS[k]  # built under a steered state: never reuse
+    codes, labels, weights, _node, _active = _row_args(one_chip, len(slots))
+    args = (codes, labels, weights, _shape(one_chip, (lay.T,), jnp.bool_))
+    if not meshed:
+        args = (codes, _shape(one_chip, codes.shape, jnp.int8)) + args[1:]
+    compiled = prog.fn.lower(*args).compile()
+    assert _compiled_kernels(compiled) == want
+    assert tt._tree_kernel_calls(D, lay, sub_levels) == want
 
 
 def test_nn_train_step_compiles_at_small_width(one_chip):

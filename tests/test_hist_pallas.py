@@ -1,6 +1,6 @@
 """Fused Pallas histogram→split-scan kernel vs the XLA references
 (interpret mode on CPU; on TPU the same kernels compile via Mosaic — see
-ops/hist_pallas.py for the lane-aligned layout and precision policy).
+ops/hist_pallas.py for the densely packed layout and precision policy).
 
 Covers the PR-11 acceptance matrix: hist parity vs the scatter
 reference, in-kernel split scan == the reference split_scan on
@@ -73,7 +73,7 @@ def _mixed_case(n=1500, seed=0, full_range=False):
     rng = np.random.default_rng(seed)
     # narrow numerics + 33/65-wide categoricals (the Mosaic unaligned-
     # store shapes of the round-5 measured loss) + one wide categorical
-    # that must split across lane-aligned chunks
+    # that must split across chunks
     slots = [9] * 6 + [33, 65] + [1500]
     is_cat = [False] * 6 + [True] * 3
     hi = [s if full_range else s - 1 for s in slots]
@@ -134,29 +134,58 @@ def test_bf16_plane_parity_bounds():
 
 
 # ---------------------------------------------------------------------------
-# lane-aligned chunk layout
+# densely packed chunk layout
 # ---------------------------------------------------------------------------
 
+_MIXED_SLOTS = [9] * 6 + [33, 65] + [1500]  # _mixed_case's
 
-def test_chunks_cover_layout_lane_aligned():
-    slots, is_cat, *_ = _mixed_case()
-    lay = make_layout(slots, is_cat)
-    chunks = _chunks(lay)
-    kept = 0
-    for ch in chunks:
-        assert ch.w % 128 == 0
-        for (_f, lo, hi, col0) in ch.pieces:
-            assert col0 % 128 == 0  # every piece starts lane-aligned
+
+# (slots, target, features a chunk, wide features)
+@pytest.mark.parametrize("slots,target,want_nf,want_wide", [
+    (_MIXED_SLOTS, 1024, None, [8]),
+    (_MIXED_SLOTS, 512, None, [8]),
+    ([33] * 28, 512, [15, 13], []),   # HIGGS under the fused scan's cap
+    ([33] * 28, 1024, [28], []),      # HIGGS in hist mode: one chunk
+    ([850, 300], 1024, [1, 1], []),   # 300 does not fit beside 850
+    ([1500], 1024, [1, 1], [0]),
+    ([128] * 5, 512, [4, 1], []),     # exact lanes pack as they did
+], ids=["mixed-1024", "mixed-512", "higgs-512", "higgs-1024", "850+300",
+        "1500", "128x5"])
+def test_chunks_cover_layout_densely_packed(slots, target, want_nf,
+                                            want_wide):
+    lay = make_layout(slots, [False] * len(slots))
+    chunks = _chunks(lay, target)
+    kept, cover, home = 0, [], {}
+    for ci, ch in enumerate(chunks):
+        assert ch.w % 128 == 0 and ch.w <= target
+        end = 0
+        for (f, lo, hi, col0) in ch.pieces:
+            assert col0 == end  # pieces side by side, no gap between them
+            end = col0 + hi - lo
+            np.testing.assert_array_equal(ch.pos[col0:end],
+                                          np.arange(lo, hi))
+            assert (ch.seg[col0:end] == f).all()
+            cover += [(f, c) for c in range(lo, hi)]
+            home.setdefault(f, set()).add(ci)
+        # only the tail past the last piece is dead, and under a lane
+        assert ch.w - end < 128
+        assert (ch.pos[end:] == -1).all() and (ch.seg[end:] == -1).all()
+        assert not ch.scan_ok[end:].any()
+        np.testing.assert_array_equal(ch.keep, np.arange(end))
         kept += len(ch.keep)
-    assert kept == lay.T  # gaps dropped at compaction, contract unchanged
-    # the 1500-wide categorical exceeds one chunk: handled by the
-    # epilogue's XLA fallback, not the in-kernel scan
-    assert wide_features(lay) == [8]
-    # chunks whose features all fit 128 slots are int8-code eligible;
-    # the 1500-wide feature's chunks are not
-    assert chunks[0].narrow
-    assert not any(ch.narrow for ch in chunks if 8 in
-                   {f for (f, _lo, _hi, _c0) in ch.pieces})
+    assert kept == lay.T  # the tail drops at compaction, contract unchanged
+    assert cover == [(f, c) for f, s in enumerate(slots) for c in range(s)]
+    # a feature that fits lies whole in one chunk (its in-kernel scan sees
+    # only that chunk); wider ones are the epilogue's XLA fallback
+    assert wide_features(lay, target) == want_wide
+    for f, s in enumerate(slots):
+        assert (len(home[f]) == 1) == (s <= target)
+    if want_nf is not None:
+        assert [ch.f_hi - ch.f_lo for ch in chunks] == want_nf
+    # chunks whose features all fit 128 slots are int8-code eligible
+    for ch in chunks:
+        assert ch.narrow == all(slots[f] <= 128
+                                for (f, _lo, _hi, _c0) in ch.pieces)
 
 
 def test_codes8_planes():
@@ -459,7 +488,7 @@ def test_shaping_knobs_and_profiler_annotation():
     obs.reset()
     try:
         assert blk_setting() == 128 and wmax_setting() == 256
-        # the narrower wmax splits the lane-aligned layout into more
+        # the narrower wmax splits the packed layout into more
         # chunks
         assert len(_chunks(lay)) > len(_chunks(lay, target=1024))
         h_pl = _pallas_hist(L, lay, codes, y, w, node, active)

@@ -297,6 +297,87 @@ def test_hist_mode_kernel_is_named_tree_hist(pallas_on):
     assert ann["kernel"] == "tree_hist"  # manifest and trace agree
 
 
+HIGGS = ([33] * 28, [False] * 28)
+
+
+def test_tree_kernel_calls_counts_a_trees_mosaic_calls(pallas_on):
+    """`tree.kernel.calls` over `train.trees`: chunks x built levels. A
+    depth-6 forest at HIGGS's layout (28 x 33 slots: two chunks of 15 and
+    13 features under the fused scan's cap) makes 2 x 6 = 12 calls a tree,
+    and the traced program holds as many kernels."""
+    slots, is_cat = HIGGS
+    rng = np.random.default_rng(11)
+    n = 600
+    codes = rng.integers(0, 32, (n, len(slots))).astype(np.int32)
+    y = (codes[:, 0] > 15).astype(np.float32)
+    conf = tt.TreeTrainConfig(algorithm="GBT", tree_num=2, max_depth=6,
+                              valid_set_rate=0.2, seed=3)
+    obs.reset()
+    res = tt.train_trees(codes, y, np.ones(n, np.float32), slots, is_cat,
+                         ["f%d" % i for i in range(len(slots))], conf)
+    assert len(res.spec.trees) == 2
+    reg = obs.registry()
+    assert reg.counter("train.trees").value == 2
+    assert reg.counter("tree.hist.built").value == 2 * 32
+    assert reg.counter("tree.kernel.calls").value == 2 * 12
+    ann = obs.profiler().snapshot()["annotations"]["ops.hist_pallas"]
+    assert (ann["chunks"], ann["paddedT"], ann["T"]) == (2, 1024, 924)
+
+
+@pytest.mark.parametrize("slots,D,sub,meshed,want", [
+    (HIGGS[0], 6, True, False, 2 * 6),   # the fused cell: 42 before PR 29
+    (HIGGS[0], 6, True, True, 1 * 6),    # hist mode under a mesh: 24 before
+    (HIGGS[0], 6, False, False, 2 * 6),
+    # subtraction off at depth 8: levels 64 and 128 leave the fused scan
+    (HIGGS[0], 8, False, False, 2 * 6 + 1 * 2),
+    # subtraction on: level 64 is built by the fused kernel of level 32
+    (HIGGS[0], 8, True, False, 2 * 7 + 1 * 1),
+    ([33] * 20 + [65] * 10, 3, True, False, 3 * 3),
+])
+def test_tree_kernel_calls_from_static_shapes(pallas_on, slots, D, sub,
+                                              meshed, want):
+    from shifu_tpu.ops import hist_pallas as hp
+
+    lay = tt.make_layout(slots, [False] * len(slots))
+    sub_levels = tuple(sub and d >= 1 for d in range(D + 1))
+    mesh = object() if meshed else None  # only asked whether it is there
+    assert tt._tree_kernel_calls(D, lay, sub_levels, mesh) == want
+    assert hp.kernel_calls(lay, fused=True) == len(
+        hp._chunks(lay, hp._SCAN_W_CAP))
+    assert hp.kernel_calls(lay, fused=False) == len(hp._chunks(lay))
+
+
+def test_tree_kernel_calls_is_silent_with_the_kernel_off(rows):
+    obs.reset()
+    _grow(rows)  # the CPU's default: the XLA lowering
+    assert "tree.kernel.calls" not in obs.registry().snapshot()["counters"]
+    lay = tt.make_layout(*HIGGS)
+    assert tt._tree_kernel_calls(6, lay, (False,) + (True,) * 6) == 0
+
+
+def test_hist_program_counts_its_kernel_calls_at_each_dispatch(pallas_on):
+    """The host-driven growers dispatch one hist program a level or a
+    batch: each dispatch is the layout's hist-mode chunks."""
+    lay = tt.make_layout([9] * 5 + [1500], [False] * 6)  # no test shares it
+    before = set(tt._PROGRAMS)
+    try:
+        prog = tt._get_hist_program(2, lay)
+    finally:
+        for k in set(tt._PROGRAMS) - before:
+            del tt._PROGRAMS[k]  # built under a mode this test set
+    n = 64
+    la = tt._device_layout(lay, np.ones(6, bool))
+    args = (jnp.zeros((n, 6), jnp.int32), jnp.zeros(n), jnp.ones(n),
+            jnp.zeros(n, jnp.int32), jnp.ones(n, bool), la.off, la.clip,
+            la.seg_t, la.pos_t)
+    obs.reset()
+    h = prog(*args)
+    prog(*args)
+    assert h.shape == (3, 2, lay.T)
+    # 45 + 1,500 columns at wmax 1,024: two chunks
+    assert obs.registry().counter("tree.kernel.calls").value == 2 * 2
+
+
 def test_nn_program_carries_its_scopes():
     from shifu_tpu.models.nn import flatten_params, init_params
 
