@@ -1,3164 +1,15 @@
-"""Benchmark: TPU training throughput vs a PINNED measured CPU baseline.
-
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...extras}.
-
-The reference publishes no numbers (BASELINE.md), so the baseline is
-MEASURED: each engine's one-worker unit is the same training step in
-single-core float64 numpy — what one reference Hadoop worker does per
-iteration — scaled by the reference's nominal 100-worker cluster.
-vs_baseline > 1.0 means one TPU chip out-trains the modeled 100-node
-Hadoop deployment.
-
-Engines covered (round-5 verdict: the two newest engines shipped
-perf-blind, GBT needed a representative config):
-  small      30-col 1-hidden MLP, the tutorial shape (headline metric)
-  dense      2048x2048 MLP — MFU against the chip's pinned peak bf16
-  gbt        500k x 30 numeric, 5 trees (round-over-round continuity)
-  gbt_wide   200k x 200 mixed (19 cat-64 + one 2000-category column),
-             20 trees — the reference's wide-categorical envelope
-  rf         500k x 30 with 10 native categorical columns, Poisson
-             bagging + TWOTHIRDS subsets (north-star config #4)
-  wdl        wide&deep: 20 dense + 10 wide vocab-100 columns
-  streamed   the larger-than-memory NN path from disk shards
-
-Timing discipline: steady-state benches pre-place training data in HBM
-(real deployments keep it there) and skip end-of-run weight pulls
-(fetch_params=False), so they time the device and not the host link. The
-streamed bench deliberately KEEPS its per-shard host->device transfers —
-streaming from host is the thing it measures. GBT runs train_trees end
-to end including per-tree host assembly of the forest.
-
-One process per chip: a chip belongs to one process at a time, so the
-tree-sweep children (which train on the default backend) run BEFORE
-anything in this parent initializes jax; the children that pin
-JAX_PLATFORMS=cpu never need the chip. The parent refuses to go on when
-the platform its first child found is not `tpu` — a number from a CPU
-run is never written under the name of a device metric.
-
-The gbt/gbt_wide/rf sections additionally time histogram subtraction
-on vs off on the identical workload (subtraction_speedup = off/on
-wall-clock, same pattern as streamed_stats serial-vs-prefetch) and embed
-the tree.hist.built/derived/fallback_rebuilds counters per mode.
-
-Every scenario's `profile` section is profiler-derived (obs/profile.py):
-FLOPs/bytes are XLA cost-analysis deltas over the timed reps, so MFU,
-achieved bandwidth, arithmetic intensity and the roofline verdict come
-from ONE instrument across all engines instead of per-engine hand math.
-The dense scenario keeps the corrected closed-form count (hand_tflops)
-as a cross-check; tests pin the two within 5%."""
-
-from __future__ import annotations
-
-import json
-import os
-import statistics
-import sys
-import time
-
-# single-core baseline: pin BLAS threads BEFORE numpy loads
-os.environ.setdefault("OPENBLAS_NUM_THREADS", "1")
-os.environ.setdefault("OMP_NUM_THREADS", "1")
-os.environ.setdefault("MKL_NUM_THREADS", "1")
-
-import numpy as np
-
-N_REFERENCE_WORKERS = 100  # north-star cluster size (BASELINE.md)
-BASELINE_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "BASELINE_MEASURED.json")
-
-SMALL = dict(d=30, hidden=[50], n=1_000_000, epochs=50)
-DENSE = dict(d=1024, hidden=[2048, 2048], n=131_072, epochs=30)
-GBT = dict(n=500_000, f=30, bins=32, trees=5, depth=6)
-GBT_WIDE = dict(n=200_000, numeric=180, cat64=19, wide_cat=2000, trees=20,
-                depth=6)
-RF = dict(n=500_000, numeric=20, cat65=10, trees=10, depth=8)
-WDL = dict(n=200_000, dense=20, wide=10, vocab=100, embed=8,
-           hidden=[100, 50], epochs=20)
-STREAMED = dict(d=30, hidden=[50], n=250_000, epochs=2, shards=8)
-# streamed-stats is self-relative (serial vs prefetch on identical chunks),
-# so it carries no numpy one-worker unit and stays out of the pinned
-# BASELINE_MEASURED.json configs
-STREAMED_STATS = dict(n=120_000, numeric=8, cat=2, chunk_rows=8192)
-# serve_latency is also self-relative (latency/QPS of the online scoring
-# subsystem, no reference analog — the reference has no serving path at
-# all), so it too stays out of BASELINE_MEASURED.json
-SERVE = dict(cols=30, hidden=[50], bags=3, requests=240,
-             concurrency=(1, 4, 16), queue_depth=256,
-             # wire_format section: rows per request — the batched-
-             # scoring shape the columnar binary protocol exists for
-             wire_rows=64)
-# model_zoo: 3 tenants whose working sets differ by hidden width, under
-# an HBM budget that fits only the two smallest — residency churns, the
-# ledger gates peak <= budget, warm p99 gates <= 1.10x single-tenant
-MODEL_ZOO = dict(cols=16, hiddens=(16, 32, 64), bags=2, requests=120,
-                 concurrency=4, reps=3)
-# serve_fleet sweeps FORCED host-device replica counts in subprocesses
-# (like sharded_stats — the device count must be fixed before jax
-# initializes). Children run single-thread XLA compute (thunk runtime +
-# multi-thread eigen off) so "one forced device = one core-sized
-# compute resource" and replica overlap is measurable; the model is
-# sized cache-resident (2 x depth-8 256-wide bags) with 512-row
-# requests so device time dominates the GIL-held host featurize.
-# Each child also measures a CONTROL: the same N device-pinned
-# registries driven directly from N threads — replicated scoring minus
-# the fleet layer — which is the host's measured parallel-scoring
-# ceiling. Efficiency gates: monotone QPS, absolute >= 0.7 at 2
-# replicas, absolute >= 0.7 at 8 on accelerator backends; on the
-# GIL-bound CPU harness the 8-replica gate binds the fleet layer
-# against the control ceiling instead (the absolute number is still
-# recorded) — same policy as sharded_stats' efficiency note and the
-# PR-11 TPU-only profile gates.
-SERVE_FLEET = dict(cols=8, hidden=256, depth=8, bags=2, rows=512,
-                   replica_counts=(1, 2, 8), threads_per_replica=2,
-                   per_thread=16, queue_depth=64, reps=2,
-                   eff2_floor=0.7, eff8_floor=0.7, fleet_vs_ceiling=0.75)
-# failover is self-relative (failure-domain mechanics, not throughput):
-# a 2-replica in-process fleet under closed-loop load has replica 1's
-# device killed persistently (`device_dead@replica=1`), and the gates
-# are correctness properties — zero unanswered / zero double-answered
-# requests across the trip, the breaker opens, and after healing the
-# half-open probes close it again (recovery time reported). Small probe
-# backoffs so the full closed->open->half-open->closed arc fits the
-# scenario.
-FAILOVER = dict(cols=10, hidden=[16], bags=2, concurrency=8,
-                per_thread=30, queue_depth=512,
-                breaker_failures=3, probe_base_ms=40, probe_cap_ms=200,
-                recover_timeout_s=30)
-# continuous_loop is self-relative too (warm-start vs cold-start on the
-# same shifted stream, GBT append vs scratch, serve p99 with the drift
-# fold on vs off): every number is a ratio of two runs inside the
-# scenario, so it stays out of BASELINE_MEASURED.json
-CONTINUOUS = dict(n=40_000, d=30, hidden=[50], epochs=60, shift=0.35,
-                  gbt=dict(n=120_000, f=30, bins=32, parent_trees=15,
-                           append=5, depth=6),
-                  serve=dict(cols=20, hidden=[50], bins=16, requests=960,
-                             concurrency=8, queue_depth=256))
-# coresident_loop: the co-resident retrainer (coresident/trainer.py)
-# running as a background HBM-ledger tenant ON the serving fleet's
-# forced-8-device harness while closed-loop traffic scores. Gated:
-# serve p99 with the trainer resident <= 1.2x solo-serve p99 (min over
-# passes on both sides — a host load spike must not masquerade as
-# co-residency cost), and evict -> resume bit-identity of the final
-# weights (the PR-7 chaos contract, on the same forced devices the
-# production path uses). epochs-to-target is recorded, not gated.
-CORESIDENT = dict(cols=8, serve_hidden=64, bags=2, rows=256, replicas=2,
-                  concurrency=4, per_thread=12, reps=2,
-                  train_rows=4096, train_cols=16, train_hidden=(16,),
-                  train_shards=4, stages=2, microbatches=2, epochs=30,
-                  throttle_ms=10, ckpt_epochs=6, evict_epoch=3,
-                  p99_ceiling=1.2)
-# sharded_stats sweeps FORCED host-device counts in subprocesses (the
-# device count must be fixed before jax initializes), measuring the
-# sharded lifecycle fold's work division and sync budget. CPU-harness
-# rows/s efficiency is REPORTED, not gated — on a GIL-bound CPU harness
-# 8 virtual devices buy no wall-clock — the gates are the structural
-# wins: each shard folds <= ceil(K/S)+1 chunks, and d2h syncs per
-# window stay at 1 (the psum tree) instead of O(S).
-SHARDED_STATS = dict(n=36_000, numeric=6, cat=2, chunk_rows=3072,
-                     device_counts=(1, 2, 8), reps=2)
-# host_affinity (inside sharded_stats) runs the SAME child as one host
-# of a 2-process fleet, concurrently with its peer, against a shared
-# dataset. Scaling efficiency IS gated here (>= 0.7) — hosts are
-# separate processes, so the GIL excuse above does not apply — which
-# needs a parse-dominated workload: the per-run constant tax (stats
-# finalize, sketch merge, the two hostsync barriers) does not split,
-# so at sharded_stats' smoke scale it would eat the halved parse time.
-HOST_AFFINITY = dict(n=400_000, numeric=6, cat=2, chunk_rows=8192,
-                     reps=2)
-# tree_sweep probes -Dshifu.pallas.blk/.wmax shapings of the fused
-# Pallas histogram→split-scan kernel, one subprocess per shaping (the
-# built kernels and the trainer's program cache are per-process, so a
-# shaping is a process property — same pattern as sharded_stats). On a
-# TPU backend the children run the full gbt/gbt_wide/rf configs and the
-# best shaping per chip is annotated into the profiler snapshot
-# (profile.annotate -> every scenario/manifest records it); on the CPU
-# harness the kernel runs in interpret mode, so children shrink to a
-# structural smoke and vs_xla is REPORTED, not gated (interpret mode
-# loses to XLA by construction — the number that matters comes from the
-# TPU run).
-TREE_SWEEP = dict(grid_blk=(256, 512), grid_wmax=(512, 1024), reps=2,
-                  cpu_scale=dict(n=8_000, trees=2, depth=4))
-
-def chip_peak_tflops():
-    """(pinned peak bf16 TFLOP/s, device_kind) of the chip under test,
-    from the shared chip table (obs/costmodel.py — the same numbers the
-    profiler's roofline uses) or the explicit -Dshifu.profile.peak*
-    override. An accelerator that is not in the table raises there: it
-    is never graded against another device's peaks. The nominal CPU
-    entry yields None — CPU MFU is not a benchmark number."""
-    from shifu_tpu.obs import costmodel
-
-    peaks = costmodel.detect()
-    return (None if peaks.source == "nominal" else peaks.peak_tflops,
-            peaks.kind.lower())
-
-
-def _gbt_wide_slots():
-    spec = GBT_WIDE
-    slots = ([33] * spec["numeric"] + [65] * spec["cat64"]
-             + [spec["wide_cat"] + 1])
-    is_cat = [False] * spec["numeric"] + [True] * (spec["cat64"] + 1)
-    return slots, is_cat
-
-
-def _rf_slots():
-    slots = [33] * RF["numeric"] + [65] * RF["cat65"]
-    is_cat = [False] * RF["numeric"] + [True] * RF["cat65"]
-    return slots, is_cat
-
-
-# ---------------------------------------------------------------------------
-# one-worker numpy units (all single-core float64)
-# ---------------------------------------------------------------------------
+"""The independent copy of the NN cell's FLOP count that
+tests/benchmark/test_benchmark_lib.py::test_mlp_flops_match_bench_py holds
+benchmarks/lib/work.py equal to. Nothing else may be added to this file; it
+goes with that test's read of it at the next `benchmark` issue."""
 
 
 def _mlp_flops_per_row_epoch(d: int, hidden: list) -> float:
     """Exact training-step matmul FLOPs per row: forward (2/MAC) plus
     backward weight-grad and input-grad (4/MAC), MINUS the first layer's
     input gradient — dL/dx is never computed (inputs need no grad), so
-    the textbook 6x-forward count overstates the dense bench by ~11%.
-    Pinned against XLA's own cost_analysis in tests/test_profile.py."""
+    the textbook 6x-forward count overstates a 1024 -> 2048 x 2 -> 1 net
+    by ~11%."""
     sizes = [d] + list(hidden) + [1]
     macs = sum(a * b for a, b in zip(sizes[:-1], sizes[1:]))
     return 6.0 * macs - 2.0 * sizes[0] * sizes[1]
-
-
-def numpy_worker_row_epochs_per_s(d: int, hidden: list, n: int = 20_000,
-                                  reps: int = 10) -> float:
-    """One Encog-worker-equivalent: full-batch fwd+backprop in float64.
-    Median of `reps` to damp scheduler noise."""
-    rng = np.random.default_rng(0)
-    x = rng.normal(size=(n, d))
-    t = (rng.random(n) < 0.5).astype(np.float64)
-    sizes = [d] + list(hidden) + [1]
-    ws = [rng.normal(size=(a, b)) * 0.1 for a, b in zip(sizes[:-1], sizes[1:])]
-    bs = [np.zeros(b) for b in sizes[1:]]
-
-    def step():
-        hs = [x]
-        for w, b in zip(ws[:-1], bs[:-1]):
-            hs.append(np.tanh(hs[-1] @ w + b))
-        z = hs[-1] @ ws[-1] + bs[-1]
-        p = 1.0 / (1.0 + np.exp(-z[:, 0]))
-        delta = ((t - p) * p * (1 - p))[:, None]
-        acc = 0.0
-        for li in range(len(ws) - 1, -1, -1):
-            acc += (hs[li].T @ delta).sum()
-            if li:
-                delta = (delta @ ws[li].T) * (1 - hs[li] * hs[li])
-        return acc
-
-    step()  # warm caches
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        step()
-        times.append(time.perf_counter() - t0)
-    return n / statistics.median(times)
-
-
-def numpy_worker_gbt_row_trees_per_s(slots, n: int = 100_000,
-                                     depth: int = 6,
-                                     reps: int = 3) -> float:
-    """One worker-equivalent FULL level-wise tree build over a mixed slot
-    layout — per-node histograms (count/sum/sqsum), variance split scan,
-    row repositioning: the DTWorker featureUpdate + DTMaster split loop
-    (dt/DTWorker.java:851, DTMaster.java:274-360) in vectorized
-    single-core numpy. NOTE this is a HARSH baseline: vectorized numpy
-    bincounts run roughly an order of magnitude faster per worker than
-    the reference's per-record Java loop, so gbt vs_baseline is a
-    conservative lower bound on the real margin."""
-    rng = np.random.default_rng(0)
-    f = len(slots)
-    codes = np.stack([rng.integers(0, s, size=n) for s in slots],
-                     1).astype(np.int32)
-    y = rng.random(n)
-    w = np.ones(n)
-
-    def build():
-        node = np.zeros(n, np.int64)
-        active = np.ones(n, bool)
-        acc = 0.0
-        for d in range(depth):
-            level = 2 ** d
-            best_gain = np.full(level, -np.inf)
-            best_f = np.zeros(level, int)
-            best_cut = np.zeros(level, int)
-            na = node[active]
-            for j in range(f):
-                bins = int(slots[j])
-                key = na * bins + codes[active, j]
-                cnt = np.bincount(key, weights=w[active],
-                                  minlength=level * bins).reshape(level, bins)
-                s1 = np.bincount(key, weights=(w * y)[active],
-                                 minlength=level * bins).reshape(level, bins)
-                s2 = np.bincount(key, weights=(w * y * y)[active],
-                                 minlength=level * bins).reshape(level, bins)
-                c0, c1, c2 = cnt.cumsum(1), s1.cumsum(1), s2.cumsum(1)
-                tc, t1, t2 = c0[:, -1:], c1[:, -1:], c2[:, -1:]
-                rc, r1, r2 = tc - c0, t1 - c1, t2 - c2
-
-                def sse(c, s, q):
-                    return q - s * s / np.maximum(c, 1e-12)
-
-                gain = sse(tc, t1, t2) - sse(c0, c1, c2) - sse(rc, r1, r2)
-                gain[(c0 < 1) | (rc < 1)] = -np.inf
-                g = gain.max(1)
-                cut = gain.argmax(1)
-                upd = g > best_gain
-                best_gain[upd] = g[upd]
-                best_f[upd] = j
-                best_cut[upd] = cut[upd]
-            fsel = best_f[node]
-            cut = best_cut[node]
-            code = codes[np.arange(n), fsel]
-            node = np.where(active, 2 * node + (code > cut).astype(int), node)
-            acc += best_gain.sum()
-        return acc
-
-    build()
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        build()
-        times.append(time.perf_counter() - t0)
-    return n / statistics.median(times)
-
-
-def numpy_worker_wdl_row_epochs_per_s(n: int = 20_000,
-                                      reps: int = 5) -> float:
-    """One worker-equivalent wide&deep step in float64: embedding lookup +
-    deep MLP fwd/bwd + wide-weight update + embedding scatter grads — the
-    WDLWorker per-record pass (wdl/WDLWorker.java) vectorized."""
-    spec = WDL
-    rng = np.random.default_rng(0)
-    dd, wn, vocab, emb = spec["dense"], spec["wide"], spec["vocab"], spec["embed"]
-    x = rng.normal(size=(n, dd))
-    ids = rng.integers(0, vocab, size=(n, wn))
-    t = (rng.random(n) < 0.5).astype(np.float64)
-    E = rng.normal(size=(wn, vocab, emb)) * 0.1
-    Wwide = rng.normal(size=(wn, vocab)) * 0.1
-    sizes = [dd + wn * emb] + list(spec["hidden"]) + [1]
-    ws = [rng.normal(size=(a, b)) * 0.1 for a, b in zip(sizes[:-1], sizes[1:])]
-
-    def step():
-        embs = np.concatenate(
-            [E[j, ids[:, j]] for j in range(wn)], axis=1)  # [n, wn*emb]
-        h0 = np.concatenate([x, embs], axis=1)
-        hs = [h0]
-        for w_ in ws[:-1]:
-            hs.append(np.maximum(hs[-1] @ w_, 0.0))  # relu
-        z = (hs[-1] @ ws[-1])[:, 0]
-        z += sum(Wwide[j, ids[:, j]] for j in range(wn))  # wide logits
-        p = 1.0 / (1.0 + np.exp(-z))
-        delta = (t - p)[:, None]
-        acc = 0.0
-        dh = delta
-        for li in range(len(ws) - 1, -1, -1):
-            acc += (hs[li].T @ dh).sum()
-            if li:
-                dh = (dh @ ws[li].T) * (hs[li] > 0)
-        # gradient at the concatenated input layer (dense ++ embeddings):
-        # one more matmul through the first weight block, then the
-        # embedding columns scatter back per wide column
-        din = dh @ ws[0].T  # [n, dd + wn*emb]
-        for j in range(wn):
-            np.add.at(Wwide[j], ids[:, j], delta[:, 0] * 1e-9)
-            np.add.at(E[j], ids[:, j],
-                      din[:, dd + j * emb:dd + (j + 1) * emb] * 1e-9)
-        return acc
-
-    step()
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        step()
-        times.append(time.perf_counter() - t0)
-    return n / statistics.median(times)
-
-
-# ---------------------------------------------------------------------------
-# baseline pinning
-# ---------------------------------------------------------------------------
-
-
-def load_or_measure_baseline(remeasure: bool = False) -> dict:
-    configs = {"small": SMALL, "dense": DENSE, "gbt": GBT,
-               "gbt_wide": GBT_WIDE, "rf": RF, "wdl": WDL,
-               "streamed": STREAMED}
-    exists = os.path.isfile(BASELINE_FILE)
-    if remeasure and exists:
-        with open(BASELINE_FILE) as fh:
-            old = json.load(fh)
-        if old.get("calibrated") and "--force-remeasure" not in sys.argv:
-            # the checked-in file carries round-1-pinned + cross-calibrated
-            # units; re-measuring on the current host would silently break
-            # round-over-round vs_baseline comparability
-            raise SystemExit(
-                f"{BASELINE_FILE} holds calibrated pinned units (see its "
-                "note). Re-measuring replaces them with this host's raw "
-                "numbers; pass --force-remeasure if that is intended.")
-    if not remeasure:
-        if not exists:
-            # re-measuring silently would reintroduce the unstable-denominator
-            # problem this file exists to fix
-            raise SystemExit(
-                f"{BASELINE_FILE} missing — it must be checked in; run "
-                "`python bench.py --remeasure-baseline` once to regenerate")
-        with open(BASELINE_FILE) as fh:
-            base = json.load(fh)
-        if base.get("configs") != json.loads(json.dumps(configs)):
-            raise SystemExit(
-                "BASELINE_MEASURED.json was measured for different bench "
-                "configs — update the file for the new configs (or, if its "
-                "`calibrated` flag is unset, rerun `python bench.py "
-                "--remeasure-baseline`)")
-        return base
-    wide_slots, _ = _gbt_wide_slots()
-    base = {
-        "configs": configs,
-        "note": ("single-core f64 numpy one-worker units (MLP/WDL fwd+bwd "
-                 "row-epochs/s; GBT level-histogram row-trees/s); median "
-                 "of reps; pinned so vs_baseline is stable across runs"),
-        "n_reference_workers": N_REFERENCE_WORKERS,
-        "small_row_epochs_per_s": round(
-            numpy_worker_row_epochs_per_s(SMALL["d"], SMALL["hidden"]), 1),
-        "dense_row_epochs_per_s": round(
-            numpy_worker_row_epochs_per_s(DENSE["d"], DENSE["hidden"],
-                                          n=2_000, reps=5), 1),
-        "gbt_row_trees_per_s": round(
-            # 32-bin histograms, matching the round-1 pinned unit exactly
-            numpy_worker_gbt_row_trees_per_s([GBT["bins"]] * GBT["f"],
-                                             depth=GBT["depth"]), 1),
-        "gbt_wide_row_trees_per_s": round(
-            numpy_worker_gbt_row_trees_per_s(wide_slots, n=50_000,
-                                             depth=GBT_WIDE["depth"],
-                                             reps=2), 1),
-        "rf_row_trees_per_s": round(
-            numpy_worker_gbt_row_trees_per_s(_rf_slots()[0], n=50_000,
-                                             depth=RF["depth"], reps=2), 1),
-        "wdl_row_epochs_per_s": round(numpy_worker_wdl_row_epochs_per_s(), 1),
-        "streamed_row_epochs_per_s": round(
-            numpy_worker_row_epochs_per_s(STREAMED["d"],
-                                          STREAMED["hidden"]), 1),
-    }
-    with open(BASELINE_FILE, "w") as fh:
-        json.dump(base, fh, indent=2)
-    return base
-
-
-def _median_timed(fn, reps: int):
-    """Median wall-clock of reps calls (fn must block until done)."""
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fn()
-        times.append(time.perf_counter() - t0)
-    return statistics.median(times), min(times), max(times)
-
-
-def _profile_totals():
-    from shifu_tpu.obs import profile as obsprofile
-
-    return obsprofile.profiler().totals()
-
-
-def _profile_delta(t0, t1, reps: int, seconds: float) -> dict:
-    """Per-rep profiler-derived roofline numbers for a timed region:
-    FLOPs/bytes are the ProgramProfiler's XLA cost-analysis deltas across
-    the region (divided by reps), achieved rates divide by the measured
-    median wall-clock — so every scenario's MFU comes from the same
-    instrument, not a per-engine hand formula."""
-    from shifu_tpu.obs import costmodel
-
-    peaks = costmodel.detect()
-    reps = max(reps, 1)
-    flops = (t1["flops"] - t0["flops"]) / reps
-    bytes_ = (t1["bytesAccessed"] - t0["bytesAccessed"]) / reps
-    d = costmodel.derive(flops or None, bytes_ or None,
-                         seconds if seconds > 0 else None, peaks)
-    return {
-        "flops_per_rep": round(flops, 1),
-        "bytes_per_rep": round(bytes_, 1),
-        "achieved_tflops": d["achievedTflops"],
-        "mfu": d["mfu"],
-        "achieved_gbps": d["achievedGBps"],
-        "arithmetic_intensity": d["arithmeticIntensity"],
-        "roofline": d["roofline"],
-        "chip": costmodel.peaks_dict(peaks),
-    }
-
-
-def _median_timed_profiled(fn, reps: int):
-    """_median_timed plus the profiler delta over the timed region."""
-    p0 = _profile_totals()
-    med, lo, hi = _median_timed(fn, reps)
-    prof = _profile_delta(p0, _profile_totals(), reps, med)
-    return med, lo, hi, prof
-
-
-# ---------------------------------------------------------------------------
-# TPU-side benches
-# ---------------------------------------------------------------------------
-
-
-def bench_nn(spec: dict, mixed_precision: bool, reps: int):
-    import jax
-
-    from shifu_tpu.train.nn_trainer import NNTrainConfig, train_nn
-
-    rng = np.random.default_rng(0)
-    n, d = spec["n"], spec["d"]
-    x = rng.normal(size=(n, d)).astype(np.float32)
-    logits = x[:, 0] * 1.5 - x[:, 1] + 0.5 * x[:, 2] * x[:, 3]
-    t = (logits + rng.normal(scale=0.5, size=n) > 0).astype(np.float32)
-    w = np.ones(n, dtype=np.float32)
-    cfg = NNTrainConfig(
-        hidden_nodes=list(spec["hidden"]),
-        activations=["tanh"] * len(spec["hidden"]),
-        propagation="R", num_epochs=spec["epochs"], valid_set_rate=0.1,
-        seed=1, mixed_precision=mixed_precision,
-    )
-    x_dev = jax.device_put(x)
-    t_dev = jax.device_put(t)
-    w_dev = jax.device_put(w)
-    # warmup compiles the program (epoch count is traced, so 2 epochs warm
-    # the full run); fetch_params=False keeps the steady-state timing free
-    # of the end-of-run weight pull (see module docstring)
-    warm = NNTrainConfig(**{**cfg.__dict__, "num_epochs": 2})
-    train_nn(x_dev, t_dev, w_dev, warm)
-    med, lo, hi, prof = _median_timed_profiled(
-        lambda: train_nn(x_dev, t_dev, w_dev, cfg, fetch_params=False),
-        reps)
-    row_epochs = n * spec["epochs"]
-    hand_tflops = (row_epochs * _mlp_flops_per_row_epoch(d, spec["hidden"])
-                   / med / 1e12)
-    return {
-        "row_epochs_per_s": row_epochs / med,
-        "spread": [round(row_epochs / hi, 1), round(row_epochs / lo, 1)],
-        # achieved TFLOP/s now comes from the profiler (XLA cost
-        # analysis x epochs / median wall); the corrected hand formula
-        # stays as a cross-check (tests pin them within 5%)
-        "tflops": (prof["achieved_tflops"]
-                   if prof["achieved_tflops"] is not None else hand_tflops),
-        "hand_tflops": hand_tflops,
-        "profile": prof,
-    }
-
-
-def _tree_hist_counters(fn):
-    """tree.hist.* counter DELTAS over one call (delta, not reset, so the
-    enclosing _with_obs_metrics scope keeps its scenario-wide snapshot)."""
-    from shifu_tpu import obs
-
-    def grab():
-        snap = obs.registry().snapshot().get("counters", {})
-        return {k.split(".")[-1]: v for k, v in snap.items()
-                if k.startswith("tree.hist.")}
-
-    before = grab()
-    fn()
-    return {k: round(v - before.get(k, 0.0), 1)
-            for k, v in grab().items()}
-
-
-def _sub_onoff(run, cfg_off, reps):
-    """Shared GBT/RF measurement protocol: one warmup+counter run per
-    subtraction mode, then timed medians for both. Returns
-    (med_on, lo_on, hi_on, extras) — extras is the off/on wall-clock
-    ratio (same pattern as streamed_stats serial-vs-prefetch) plus the
-    histogram build-vs-derive counters behind it."""
-    hist_on = _tree_hist_counters(run)
-    hist_off = _tree_hist_counters(lambda: run(cfg_off))
-    med, lo, hi, prof = _median_timed_profiled(run, reps)
-    med_off, _lo_off, _hi_off = _median_timed(lambda: run(cfg_off), reps)
-    return med, lo, hi, {
-        "subtraction_speedup": med_off / med,
-        "hist_counters": {"on": hist_on, "off": hist_off},
-        "profile": prof,
-    }
-
-
-def _bench_trees(codes_np, slots, is_cat, trees, depth, reps):
-    import jax
-
-    from shifu_tpu.train.tree_trainer import TreeTrainConfig, train_trees
-
-    rng = np.random.default_rng(0)
-    n, F = codes_np.shape
-    y = (codes_np[:, 0].astype(np.int64) + codes_np[:, 1]
-         + rng.integers(0, 32, size=n) > 48).astype(np.float32)
-    w = np.ones(n, dtype=np.float32)
-    # training data lives in HBM (like every other engine's bench); the
-    # per-tree forest assembly/host sync stays inside the timed region
-    codes_dev = jax.device_put(codes_np.astype(np.int32))
-    y_dev = jax.device_put(y)
-    w_dev = jax.device_put(w)
-    cfg = TreeTrainConfig(algorithm="GBT", tree_num=trees, max_depth=depth,
-                          learning_rate=0.1, valid_set_rate=0.1, seed=3)
-    cfg_off = TreeTrainConfig(**{**cfg.__dict__, "hist_subtraction": False})
-    cols = [f"f{i}" for i in range(F)]
-
-    def run(c=cfg):
-        train_trees(codes_dev, y_dev, w_dev, slots, is_cat, cols, c)
-
-    med, lo, hi, extras = _sub_onoff(run, cfg_off, reps)
-    return {
-        "row_trees_per_s": n * trees / med,
-        "spread": [round(n * trees / hi, 1), round(n * trees / lo, 1)],
-        **extras,
-    }
-
-
-def bench_gbt(reps: int):
-    rng = np.random.default_rng(0)
-    n, F, bins = GBT["n"], GBT["f"], GBT["bins"]
-    codes = rng.integers(0, bins, size=(n, F)).astype(np.int32)
-    return _bench_trees(codes, [bins + 1] * F, [False] * F, GBT["trees"],
-                        GBT["depth"], reps)
-
-
-def bench_gbt_wide(reps: int):
-    rng = np.random.default_rng(0)
-    slots, is_cat = _gbt_wide_slots()
-    n = GBT_WIDE["n"]
-    codes = np.stack([rng.integers(0, s - 1, size=n) for s in slots],
-                     1).astype(np.int32)
-    return _bench_trees(codes, slots, is_cat, GBT_WIDE["trees"],
-                        GBT_WIDE["depth"], reps)
-
-
-def bench_rf(reps: int):
-    """RF with native categorical columns (north-star config #4): Poisson
-    bagging + TWOTHIRDS feature subsets per tree."""
-    import jax
-
-    from shifu_tpu.train.tree_trainer import TreeTrainConfig, train_trees
-
-    rng = np.random.default_rng(0)
-    slots, is_cat = _rf_slots()
-    n, F = RF["n"], len(slots)
-    codes = np.stack([rng.integers(0, s - 1, size=n) for s in slots],
-                     1).astype(np.int32)
-    y = ((codes[:, 0] >= 16).astype(np.int8)
-         | (codes[:, RF["numeric"]] >= 32).astype(np.int8))
-    w = np.ones(n, dtype=np.float32)
-    codes_dev = jax.device_put(codes)
-    y_dev = jax.device_put(y.astype(np.float32))
-    w_dev = jax.device_put(w)
-    cfg = TreeTrainConfig(algorithm="RF", tree_num=RF["trees"],
-                          max_depth=RF["depth"],
-                          feature_subset_strategy="TWOTHIRDS",
-                          valid_set_rate=0.1, seed=3)
-    cfg_off = TreeTrainConfig(**{**cfg.__dict__, "hist_subtraction": False})
-    cols = [f"f{i}" for i in range(F)]
-
-    def run(c=cfg):
-        train_trees(codes_dev, y_dev, w_dev, slots, is_cat, cols, c)
-
-    med, lo, hi, extras = _sub_onoff(run, cfg_off, reps)
-    return {
-        "row_trees_per_s": n * RF["trees"] / med,
-        "spread": [round(n * RF["trees"] / hi, 1),
-                   round(n * RF["trees"] / lo, 1)],
-        **extras,
-    }
-
-
-def bench_wdl(reps: int):
-    import jax
-
-    from shifu_tpu.train.wdl_trainer import WDLTrainConfig, train_wdl
-
-    spec = WDL
-    rng = np.random.default_rng(0)
-    n = spec["n"]
-    dense = rng.normal(size=(n, spec["dense"])).astype(np.float32)
-    codes = rng.integers(0, spec["vocab"],
-                         size=(n, spec["wide"])).astype(np.int32)
-    t = (dense[:, 0] + 0.1 * codes[:, 0] - 5
-         + rng.normal(scale=2.0, size=n) > 0).astype(np.float32)
-    w = np.ones(n, dtype=np.float32)
-    cfg = WDLTrainConfig(hidden=list(spec["hidden"]),
-                         embed_dim=spec["embed"],
-                         num_epochs=spec["epochs"], valid_set_rate=0.1,
-                         seed=1)
-    dense_dev = jax.device_put(dense)
-    codes_dev = jax.device_put(codes)
-    vocab_sizes = [spec["vocab"]] * spec["wide"]
-    warm = WDLTrainConfig(**{**cfg.__dict__, "num_epochs": 2})
-    train_wdl(dense_dev, codes_dev, t, w, vocab_sizes, warm)
-    med, lo, hi, prof = _median_timed_profiled(
-        lambda: train_wdl(dense_dev, codes_dev, t, w, vocab_sizes, cfg),
-        reps)
-    row_epochs = n * spec["epochs"]
-    return {
-        "row_epochs_per_s": row_epochs / med,
-        "spread": [round(row_epochs / hi, 1), round(row_epochs / lo, 1)],
-        "profile": prof,
-    }
-
-
-def bench_streamed_nn(reps: int):
-    """Larger-than-memory NN path: per-shard host->device streaming is the
-    measured quantity."""
-    import shutil
-    import tempfile
-
-    from shifu_tpu.norm.dataset import write_normalized
-    from shifu_tpu.train.nn_trainer import NNTrainConfig
-    from shifu_tpu.train.streaming import train_nn_streamed
-
-    spec = STREAMED
-    rng = np.random.default_rng(0)
-    n, d = spec["n"], spec["d"]
-    x = rng.normal(size=(n, d)).astype(np.float32)
-    t = (x[:, 0] - x[:, 1] > 0).astype(np.float32)
-    w = np.ones(n, dtype=np.float32)
-    cfg = NNTrainConfig(hidden_nodes=list(spec["hidden"]),
-                        activations=["tanh"], propagation="R",
-                        num_epochs=spec["epochs"], valid_set_rate=0.1,
-                        seed=1)
-    tmp = tempfile.mkdtemp(prefix="bench-streamed-")
-    try:
-        write_normalized(tmp, x, t, w, [f"c{i}" for i in range(d)],
-                         n_shards=spec["shards"])
-        train_nn_streamed(tmp, NNTrainConfig(
-            **{**cfg.__dict__, "num_epochs": 1}))  # warmup/compile
-        med, lo, hi, prof = _median_timed_profiled(
-            lambda: train_nn_streamed(tmp, cfg), reps)
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    row_epochs = n * spec["epochs"]
-    return {
-        "row_epochs_per_s": row_epochs / med,
-        "spread": [round(row_epochs / hi, 1), round(row_epochs / lo, 1)],
-        "profile": prof,
-    }
-
-
-def bench_streamed_stats(reps: int):
-    """Two-pass streaming stats (CSV parse -> bin-code -> device aggregate)
-    rows/s through the overlapped ingest pipeline, measured twice on the
-    identical chunk stream: serial (shifu.ingest.prefetchChunks=0) and
-    prefetched (default depth). The serial/prefetch wall-clock ratio is the
-    parse/device overlap win; results are bit-identical either way (one
-    prefetch worker, FIFO order), so any ratio < 1 is a regression."""
-    import shutil
-    import tempfile
-
-    from shifu_tpu.config import ColumnConfig, ColumnType
-    from shifu_tpu.config.column_config import ColumnFlag
-    from shifu_tpu.config.model_config import Algorithm, new_model_config
-    from shifu_tpu.data.stream import chunk_source
-    from shifu_tpu.stats.engine import compute_stats_streaming
-    from shifu_tpu.utils import environment
-
-    spec = STREAMED_STATS
-    rng = np.random.default_rng(0)
-    n = spec["n"]
-    y = (rng.random(n) < 0.3).astype(int)
-    num = rng.normal(loc=y[:, None] * 0.8, size=(n, spec["numeric"]))
-    cat_vals = np.array(["aa", "bb", "cc", "dd", "ee"])
-    cats = cat_vals[rng.integers(0, len(cat_vals),
-                                 size=(n, spec["cat"]))]
-    names = (["target"] + [f"n{j}" for j in range(spec["numeric"])]
-             + [f"c{j}" for j in range(spec["cat"])])
-
-    tmp = tempfile.mkdtemp(prefix="bench-sstats-")
-    data_path = os.path.join(tmp, "data.txt")
-    with open(data_path, "w") as fh:
-        for i in range(n):
-            fields = ([str(y[i])] + [f"{v:.5f}" for v in num[i]]
-                      + list(cats[i]))
-            fh.write("|".join(fields) + "\n")
-
-    mc = new_model_config("BenchStats", Algorithm.NN)
-    mc.data_set.target_column_name = "target"
-    mc.data_set.pos_tags = ["1"]
-    mc.data_set.neg_tags = ["0"]
-
-    def fresh_cols():
-        cols = [ColumnConfig(column_num=0, column_name="target",
-                             column_flag=ColumnFlag.TARGET)]
-        for j in range(spec["numeric"]):
-            cols.append(ColumnConfig(column_num=1 + j, column_name=f"n{j}",
-                                     column_type=ColumnType.N))
-        for j in range(spec["cat"]):
-            cols.append(ColumnConfig(column_num=1 + spec["numeric"] + j,
-                                     column_name=f"c{j}",
-                                     column_type=ColumnType.C))
-        return cols
-
-    factory = chunk_source(data_path, names, delimiter="|",
-                           chunk_rows=spec["chunk_rows"])
-
-    def run(prefetch: int, ckpt_root=None):
-        environment.set_property("shifu.ingest.prefetchChunks",
-                                 str(prefetch))
-        compute_stats_streaming(mc, fresh_cols(), factory,
-                                checkpoint_root=ckpt_root)
-
-    # checkpointing-on pass: default cadence snapshots into a scratch
-    # ledger dir; the on/off wall-clock ratio is the overhead the
-    # preemption-safety layer costs (acceptance target <= 1.05x)
-    ck_root = os.path.join(tmp, "ckroot")
-    try:
-        run(2)  # warmup: compiles the bucketed shapes both modes share
-        med_s, lo_s, hi_s = _median_timed(lambda: run(0), reps)
-        med_c, lo_c, hi_c = _median_timed(
-            lambda: run(2, ckpt_root=ck_root), reps)
-        med_p, lo_p, hi_p, prof = _median_timed_profiled(
-            lambda: run(2), reps)
-    finally:
-        environment.set_property("shifu.ingest.prefetchChunks", "")
-        shutil.rmtree(tmp, ignore_errors=True)
-    return {
-        "rows_per_s": n / med_p,
-        "serial_rows_per_s": n / med_s,
-        "prefetch_speedup": med_s / med_p,
-        "checkpoint_overhead": med_c / med_p,
-        "ckpt_rows_per_s": n / med_c,
-        "spread": [round(n / hi_p, 1), round(n / lo_p, 1)],
-        "profile": prof,
-    }
-
-
-def _sharded_stats_child() -> None:
-    """Entry for `bench.py --sharded-stats-child [workdir hosts hostIdx]`:
-    one forced-device-count measurement of the sharded streaming-stats
-    fold. Runs in its own process because the XLA host-device count must
-    be fixed BEFORE jax initializes — the parent sets
-    XLA_FLAGS/JAX_PLATFORMS in this child's environment. With the
-    optional trailing args the child is one HOST of a multi-process
-    data-plane run: the dataset lives in the shared `workdir`, the
-    lifecycle knobs pin this process's slot in the HostPlan, and the
-    parent launches all hosts CONCURRENTLY (the hostsync merge barrier
-    deadlocks a sequential schedule). Prints ONE JSON line."""
-    import shutil
-    import tempfile
-
-    from shifu_tpu import obs
-    from shifu_tpu.config import ColumnConfig, ColumnType
-    from shifu_tpu.config.column_config import ColumnFlag
-    from shifu_tpu.config.model_config import Algorithm, new_model_config
-    from shifu_tpu.data.stream import chunk_source
-    from shifu_tpu.parallel.mesh import lifecycle_shards
-    from shifu_tpu.stats.engine import compute_stats_streaming
-    from shifu_tpu.utils import environment
-
-    argi = sys.argv.index("--sharded-stats-child")
-    rest = sys.argv[argi + 1:argi + 4]
-    workdir = rest[0] if rest else ""
-    n_hosts = int(rest[1]) if len(rest) > 1 else 1
-    host_index = int(rest[2]) if len(rest) > 2 else 0
-    if n_hosts > 1:
-        environment.set_property("shifu.lifecycle.hosts", str(n_hosts))
-        environment.set_property("shifu.lifecycle.hostIndex",
-                                 str(host_index))
-
-    # a workdir marks a host_affinity child (solo baseline or one host
-    # of the fleet) — those run the bigger parse-dominated spec
-    spec = HOST_AFFINITY if workdir else SHARDED_STATS
-    n, chunk_rows = spec["n"], spec["chunk_rows"]
-    rng = np.random.default_rng(0)
-    y = (rng.random(n) < 0.3).astype(int)
-    num = rng.normal(loc=y[:, None] * 0.8, size=(n, spec["numeric"]))
-    cat_vals = np.array(["aa", "bb", "cc", "dd", "ee"])
-    cats = cat_vals[rng.integers(0, len(cat_vals), size=(n, spec["cat"]))]
-    names = (["target"] + [f"n{j}" for j in range(spec["numeric"])]
-             + [f"c{j}" for j in range(spec["cat"])])
-
-    tmp = workdir or tempfile.mkdtemp(prefix="bench-shstats-")
-    data_path = os.path.join(tmp, "data.txt")
-    if not os.path.exists(data_path):
-        # Only the solo baseline child ever writes (the parent runs it
-        # first); host children find the shared dataset already there.
-        staged = data_path + f".w{os.getpid()}"
-        with open(staged, "w") as fh:
-            for i in range(n):
-                fh.write("|".join([str(y[i])]
-                                  + [f"{v:.5f}" for v in num[i]]
-                                  + list(cats[i])) + "\n")
-        os.replace(staged, data_path)
-
-    mc = new_model_config("BenchShardedStats", Algorithm.NN)
-    mc.data_set.target_column_name = "target"
-    mc.data_set.pos_tags = ["1"]
-    mc.data_set.neg_tags = ["0"]
-
-    def fresh_cols():
-        cols = [ColumnConfig(column_num=0, column_name="target",
-                             column_flag=ColumnFlag.TARGET)]
-        for j in range(spec["numeric"]):
-            cols.append(ColumnConfig(column_num=1 + j, column_name=f"n{j}",
-                                     column_type=ColumnType.N))
-        for j in range(spec["cat"]):
-            cols.append(ColumnConfig(column_num=1 + spec["numeric"] + j,
-                                     column_name=f"c{j}",
-                                     column_type=ColumnType.C))
-        return cols
-
-    factory = chunk_source(data_path, names, delimiter="|",
-                           chunk_rows=chunk_rows)
-    S = lifecycle_shards()
-    K = -(-n // chunk_rows)
-    ck_root = os.path.join(tmp, "ck") if workdir else None
-    kwargs = {"checkpoint_root": ck_root} if ck_root else {}
-    try:
-        # warm compile (multi-host: every host must run the SAME number
-        # of folds — each one crosses the merge barrier)
-        compute_stats_streaming(mc, fresh_cols(), factory, **kwargs)
-        times = []
-        for _ in range(spec["reps"]):
-            obs.reset()
-            t0 = time.perf_counter()
-            compute_stats_streaming(mc, fresh_cols(), factory, **kwargs)
-            times.append(time.perf_counter() - t0)
-        reg = obs.registry()  # counters of the LAST measured run
-        shard_chunks = {
-            stage: [int(reg.counter("shard.chunks", shard=str(s),
-                                    stage=f"stats.{stage}").value)
-                    for s in range(S)]
-            for stage in ("pass1", "pass2")}
-        host_chunks = {
-            stage: int(reg.counter("host.chunks", host=str(host_index),
-                                   stage=f"stats.{stage}").value)
-            for stage in ("pass1", "pass2")}
-        med = statistics.median(times)
-        print(json.dumps({
-            "devices": S,
-            "host": host_index,
-            "hosts": n_hosts,
-            "chunks": K,
-            "rows_per_s": n / med,
-            "seconds": med,
-            "shard_chunks": shard_chunks,
-            "max_shard_chunks": max(max(v) for v in
-                                    shard_chunks.values()),
-            "host_chunks": host_chunks,
-            "d2h_syncs": int(reg.counter("device.d2h_syncs").value),
-            "psum_windows": int(reg.counter(
-                "reduce.psum_windows").value),
-        }))
-    finally:
-        if not workdir:  # shared workdirs are the parent's to clean
-            shutil.rmtree(tmp, ignore_errors=True)
-
-
-def _tree_sweep_child() -> None:
-    """Entry for `bench.py --tree-sweep-child <scenario> <mode> <blk>
-    <wmax>`: one kernel-shaping measurement of one tree scenario. Runs
-    in its own process because the pallas kernels and the trainer's
-    compiled-program cache bind the -Dshifu.pallas.* knobs at build
-    time. Prints ONE JSON line."""
-    import jax
-
-    from shifu_tpu.utils import environment
-
-    i = sys.argv.index("--tree-sweep-child")
-    scenario, mode, blk, wmax = sys.argv[i + 1:i + 5]
-    environment.set_property("shifu.pallas.mode", mode)
-    if int(blk):
-        environment.set_property("shifu.pallas.blk", blk)
-    if int(wmax):
-        environment.set_property("shifu.pallas.wmax", wmax)
-
-    from shifu_tpu.train.tree_trainer import TreeTrainConfig, train_trees
-
-    on_tpu = jax.default_backend() == "tpu"
-    if scenario == "gbt":
-        spec = GBT
-        slots = [spec["bins"] + 1] * spec["f"]
-        is_cat = [False] * spec["f"]
-    elif scenario == "gbt_wide":
-        slots, is_cat = _gbt_wide_slots()
-        spec = GBT_WIDE
-    else:
-        slots, is_cat = _rf_slots()
-        spec = RF
-    scale = TREE_SWEEP["cpu_scale"]
-    n = spec["n"] if on_tpu else scale["n"]
-    trees = spec["trees"] if on_tpu else scale["trees"]
-    depth = spec["depth"] if on_tpu else min(spec["depth"], scale["depth"])
-    rng = np.random.default_rng(0)
-    F = len(slots)
-    codes = np.stack([rng.integers(0, s - 1, size=n) for s in slots],
-                     1).astype(np.int32)
-    y = (codes[:, 0].astype(np.int64) + codes[:, 1]
-         + rng.integers(0, 16, size=n)
-         > (slots[0] + slots[1]) // 2).astype(np.float32)
-    w = np.ones(n, dtype=np.float32)
-    cols = [f"f{i}" for i in range(F)]
-    codes_dev = jax.device_put(codes)
-    y_dev = jax.device_put(y)
-    w_dev = jax.device_put(w)
-    alg = "RF" if scenario == "rf" else "GBT"
-    cfg = TreeTrainConfig(
-        algorithm=alg, tree_num=trees, max_depth=depth,
-        learning_rate=0.1, valid_set_rate=0.1, seed=3,
-        feature_subset_strategy="TWOTHIRDS" if alg == "RF" else "ALL")
-
-    def run():
-        train_trees(codes_dev, y_dev, w_dev, slots, is_cat, cols, cfg)
-
-    run()  # warm the compile caches
-    med, _lo, _hi = _median_timed(run, TREE_SWEEP["reps"])
-    print(json.dumps({
-        "scenario": scenario, "mode": mode, "blk": int(blk),
-        "wmax": int(wmax), "rows": n, "trees": trees, "depth": depth,
-        "row_trees_per_s": n * trees / med, "seconds": med,
-        "backend": jax.default_backend(),
-    }))
-
-
-def bench_tree_sweep():
-    """(blk, wmax) knob sweep of the fused Pallas tree kernel over the
-    gbt/gbt_wide/rf scenarios, one subprocess per shaping plus one
-    kernel-off XLA reference each. The best shaping per scenario is
-    recorded via profile.annotate against the `tree.pallas_fused` seam
-    (process-global), so every LATER scenario snapshot and manifest in
-    this bench run carries which shaping this chip prefers."""
-    import subprocess
-
-    from shifu_tpu.obs import profile as _profile
-
-    spec = TREE_SWEEP
-    out = {}
-    for scenario in ("gbt", "gbt_wide", "rf"):
-        def child(mode, blk=0, wmax=0):
-            proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__),
-                 "--tree-sweep-child", scenario, mode, str(blk),
-                 str(wmax)],
-                env=dict(os.environ), capture_output=True, text=True,
-                timeout=3600)
-            if proc.returncode != 0:
-                raise RuntimeError(
-                    f"tree_sweep child ({scenario} {mode} {blk}x{wmax}) "
-                    f"failed:\n{proc.stderr[-2000:]}")
-            return json.loads(proc.stdout.strip().splitlines()[-1])
-
-        xla = child("off")
-        if xla["backend"] != "tpu":
-            raise SystemExit(
-                f"bench.py measures the chip, but its first child found "
-                f"backend={xla['backend']!r}: refusing to report CPU "
-                f"numbers under device metric names")
-        shapings = {}
-        best = None
-        for blk in spec["grid_blk"]:
-            for wmax in spec["grid_wmax"]:
-                r = child("on", blk, wmax)
-                rt = r["row_trees_per_s"]
-                shapings[f"{blk}x{wmax}"] = {
-                    "row_trees_per_s": round(rt, 1),
-                    "vs_xla": round(rt / xla["row_trees_per_s"], 3),
-                }
-                if best is None or rt > best[2]:
-                    best = (blk, wmax, rt)
-        best_key = f"{best[0]}x{best[1]}"
-        _profile.annotate(
-            "tree.pallas_fused",
-            **{f"{scenario}BestBlk": best[0],
-               f"{scenario}BestWmax": best[1],
-               f"{scenario}BestVsXla": shapings[best_key]["vs_xla"]})
-        out[scenario] = {
-            "xla_row_trees_per_s": round(xla["row_trees_per_s"], 1),
-            "shapings": shapings,
-            "best": {"blk": best[0], "wmax": best[1],
-                     "vs_xla": shapings[best_key]["vs_xla"]},
-            "rows": xla["rows"], "trees": xla["trees"],
-            "depth": xla["depth"], "backend": xla["backend"],
-        }
-    out["note"] = (
-        "per-process -Dshifu.pallas.blk/.wmax shapings of the fused "
-        "kernel vs the kernel-off XLA path on the identical workload; "
-        "best shaping annotated into tree.pallas_fused so later "
-        "scenario snapshots/manifests record it. On a CPU harness the "
-        "kernel runs in INTERPRET mode at smoke scale — vs_xla < 1 "
-        "there is expected and not gated; the TPU run's numbers gate.")
-    return out
-
-
-def bench_sharded_stats():
-    """Sweep forced host-device counts (1/2/8) over the sharded
-    streaming-stats fold, one subprocess per count. Gates the structural
-    acceptance — work division <= ceil(K/S)+1 chunks per shard and ONE
-    d2h sync per psum window — and reports CPU-harness rows/s + scaling
-    efficiency vs 1-shard ungated."""
-    import subprocess
-
-    spec = SHARDED_STATS
-    counts = {}
-    gates = {"work_division": True, "single_sync_per_window": True}
-    base = None
-    for n_dev in spec["device_counts"]:
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n_dev}").strip()
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--sharded-stats-child"],
-            env=env, capture_output=True, text=True, timeout=1800)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"sharded_stats child ({n_dev} devices) failed:\n"
-                f"{proc.stderr[-2000:]}")
-        res = json.loads(proc.stdout.strip().splitlines()[-1])
-        K, S = res["chunks"], res["devices"]
-        bound = -(-K // S) + 1
-        division_ok = res["max_shard_chunks"] <= bound
-        sync_ok = (res["psum_windows"] >= 1
-                   and res["d2h_syncs"] == res["psum_windows"])
-        gates["work_division"] &= division_ok
-        gates["single_sync_per_window"] &= sync_ok
-        if base is None:
-            base = res["rows_per_s"]
-        counts[str(n_dev)] = {
-            "rows_per_s": round(res["rows_per_s"], 1),
-            "chunks": K,
-            "max_shard_chunks": res["max_shard_chunks"],
-            "chunk_bound": bound,
-            "shard_chunks": res["shard_chunks"],
-            "d2h_syncs": res["d2h_syncs"],
-            "psum_windows": res["psum_windows"],
-            "scaling_efficiency_vs_1shard": round(
-                res["rows_per_s"] / base / n_dev, 4),
-        }
-    if not (gates["work_division"] and gates["single_sync_per_window"]):
-        raise RuntimeError(f"sharded_stats gates failed: {gates} "
-                           f"{json.dumps(counts)}")
-    return {
-        "shard_counts": counts,
-        "gates": gates,
-        "host_affinity": _bench_host_affinity(HOST_AFFINITY),
-        "note": ("forced host-device sweep of the sharded lifecycle "
-                 "fold; gated: each shard folds <= ceil(K/S)+1 chunks "
-                 "and host d2h syncs per window == 1 (psum-tree "
-                 "reduce). CPU-harness rows/s and scaling efficiency "
-                 "are reported, not gated — the GIL bounds parse "
-                 "overlap here; the division + sync structure is what "
-                 "carries to a real mesh"),
-    }
-
-
-def _bench_host_affinity(spec):
-    """Pod-scale data plane: the identical streamed-stats workload run
-    by ONE process and then by TWO concurrent host processes
-    (-Dshifu.lifecycle.hosts=2) splitting the same chunk list by
-    HostPlan affinity. Gated: per-host chunk count <= ceil(K/H)+1 (the
-    work-division bound) and scaling efficiency t1/(H*max(t2)) >= 0.7.
-    Unlike shard scaling, host scaling IS gated on the CPU harness —
-    the hosts are separate processes, so the GIL excuse does not
-    apply; only the merge barrier and the per-host fold tax the
-    split."""
-    import shutil
-    import subprocess
-    import tempfile
-
-    H = 2
-    workdir = tempfile.mkdtemp(prefix="bench-hostaff-")
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=1"
-                        ).strip()
-
-    def launch(hosts, h):
-        return subprocess.Popen(
-            [sys.executable, os.path.abspath(__file__),
-             "--sharded-stats-child", workdir, str(hosts), str(h)],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-            text=True)
-
-    def collect(proc, tag):
-        out, err = proc.communicate(timeout=1800)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"host_affinity child ({tag}) failed:\n{err[-2000:]}")
-        return json.loads(out.strip().splitlines()[-1])
-
-    try:
-        # solo first: it also writes the shared dataset the host
-        # children reuse (same bytes, same chunk list)
-        solo = collect(launch(1, 0), "solo")
-        # the two hosts MUST run concurrently — each streamed-stats pass
-        # ends at a hostsync merge barrier that waits for the peer
-        procs = [launch(H, h) for h in range(H)]
-        hosts_res = [collect(p, f"host{h}")
-                     for h, p in enumerate(procs)]
-    finally:
-        shutil.rmtree(workdir, ignore_errors=True)
-
-    K = solo["chunks"]
-    bound = -(-K // H) + 1
-    per_host = {str(r["host"]): r["host_chunks"] for r in hosts_res}
-    max_host_chunks = max(max(c.values()) for c in per_host.values())
-    t2 = max(r["seconds"] for r in hosts_res)
-    eff = solo["seconds"] / (H * t2)
-    ha_gates = {
-        "host_division": max_host_chunks <= bound,
-        "scaling_efficiency": eff >= 0.7,
-    }
-    out = {
-        "hosts": H,
-        "chunks": K,
-        "solo_rows_per_s": round(solo["rows_per_s"], 1),
-        "fleet_rows_per_s": round(spec["n"] / t2, 1),
-        "scaling_efficiency": round(eff, 4),
-        "per_host_chunks": per_host,
-        "host_chunk_bound": bound,
-        "gates": ha_gates,
-        "note": ("1-process vs 2-concurrent-process streamed stats over "
-                 "the same dataset; per_host_chunks counts the LAST "
-                 "measured rep's host.chunks counters per pass — "
-                 "disjoint affinity slices summing to K"),
-    }
-    if not all(ha_gates.values()):
-        raise RuntimeError(
-            f"host_affinity gates failed: {json.dumps(out)}")
-    return out
-
-
-def _stage_breakdown(trace_summaries, total_latencies=None):
-    """Per-stage p50/p99 (ms) over captured request traces, plus the
-    featurize share of tail latency — the tracked number for the
-    ROADMAP host-featurize target (a C-native/device-side featurize
-    must move THIS, measurably, per request)."""
-    sums = {}
-    totals = []
-    for s in trace_summaries:
-        totals.append(s.get("totalMs", 0.0))
-        for stage, ms in (s.get("stages") or {}).items():
-            sums.setdefault(stage, []).append(ms)
-    stages = {
-        stage: {"p50_ms": round(float(np.percentile(v, 50)), 3),
-                "p99_ms": round(float(np.percentile(v, 99)), 3),
-                "mean_ms": round(float(np.mean(v)), 3)}
-        for stage, v in sorted(sums.items())
-    }
-    if total_latencies is not None and len(total_latencies):
-        total_p99 = float(np.percentile(total_latencies, 99)) * 1e3
-    else:
-        total_p99 = float(np.percentile(totals, 99)) if totals else 0.0
-    feat_p99 = stages.get("featurize", {}).get("p99_ms", 0.0)
-    return {
-        "traces": len(trace_summaries),
-        "stages": stages,
-        "total_p99_ms": round(total_p99, 3),
-        "featurize_share_of_p99": (round(feat_p99 / total_p99, 4)
-                                   if total_p99 else None),
-        "note": "featurize_share_of_p99 is the tracked host-featurize "
-                "number (ROADMAP serving hot-path target)",
-    }
-
-
-def _serve_fleet_child() -> None:
-    """Entry for `bench.py --serve-fleet-child N`: one forced-device
-    fleet measurement. Prints ONE JSON line:
-    fleet closed-loop QPS/p50/p99 + per-replica routing counts, then
-    the control (N device-pinned registries driven directly from N
-    threads — the harness's replicated-scoring ceiling without the
-    fleet layer)."""
-    import tempfile
-    import threading
-
-    import jax
-
-    from shifu_tpu import obs
-    from shifu_tpu.models.nn import NNModelSpec, init_params
-    from shifu_tpu.obs import reqtrace
-    from shifu_tpu.serve.fleet import ReplicaFleet
-    from shifu_tpu.serve.registry import ModelRegistry, records_to_columnar
-    from shifu_tpu.utils import environment
-
-    # trace every request so the child reports the per-stage breakdown
-    # per replica count (queue/coalesce/device attribution is the whole
-    # point of the replica sweep's tail numbers)
-    environment.set_property("shifu.trace.sample", "1.0")
-    environment.set_property("shifu.trace.maxTraces", "4096")
-
-    spec = SERVE_FLEET
-    i = sys.argv.index("--serve-fleet-child")
-    n = int(sys.argv[i + 1])
-    cols = [f"c{k}" for k in range(spec["cols"])]
-    sizes = [spec["cols"]] + [spec["hidden"]] * spec["depth"] + [1]
-    tmp = tempfile.mkdtemp(prefix="bench-fleet-")
-    for b in range(spec["bags"]):
-        norm_specs = [
-            {"name": c, "kind": "value", "outNames": [c], "mean": 0.0,
-             "std": 1.0, "fill": 0.0, "zscore": True} for c in cols]
-        NNModelSpec(layer_sizes=sizes, activations=["tanh"],
-                    input_columns=cols, norm_specs=norm_specs,
-                    params=init_params(sizes, seed=b),
-                    ).save(os.path.join(tmp, f"model{b}.nn"))
-    rng = np.random.default_rng(0)
-    pool = []
-    for _ in range(8):
-        rows = rng.normal(size=(spec["rows"], spec["cols"]))
-        recs = [{c: f"{v:.5f}" for c, v in zip(cols, row)}
-                for row in rows]
-        pool.append(records_to_columnar(recs, cols))
-
-    # ---- fleet: closed loop through router -> queue -> batcher ----
-    obs.reset()
-    fleet = ReplicaFleet.build(tmp, n_replicas=n,
-                               max_batch_rows=spec["rows"],
-                               queue_depth=spec["queue_depth"])
-    fleet.warm([spec["rows"]])
-    threads_n = spec["threads_per_replica"] * n
-    per = spec["per_thread"]
-    lat = [[] for _ in range(threads_n)]
-
-    def client(ti):
-        for k in range(per):
-            t0 = time.perf_counter()
-            tr = reqtrace.RequestTrace(sampled=True)
-            fleet.submit(pool[(ti + k) % len(pool)], trace=tr).wait(120)
-            fleet.finish_trace(tr)
-            lat[ti].append(time.perf_counter() - t0)
-
-    threads = [threading.Thread(target=client, args=(ti,))
-               for ti in range(threads_n)]
-    t0 = time.perf_counter()
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    fleet_wall = time.perf_counter() - t0
-    flat = np.asarray([v for ts in lat for v in ts])
-    counters = obs.registry().snapshot()["counters"]
-    routed = {str(r): int(counters.get(
-        f'serve.router.routed{{replica="{r}"}}', 0)) for r in range(n)}
-    stages = _stage_breakdown(reqtrace.buffer().traces(), flat)
-    fleet.close(60)
-
-    # ---- control: same registries, no fleet layer ----
-    regs = [ModelRegistry(tmp, device=jax.devices()[k % len(jax.devices())])
-            for k in range(n)]
-    for reg in regs:
-        reg.score_raw(pool[0])  # compile the bucket
-    ctrl_per = spec["per_thread"] * spec["threads_per_replica"]
-
-    def direct(k):
-        for j in range(ctrl_per):
-            regs[k].score_raw(pool[(k + j) % len(pool)])
-
-    threads = [threading.Thread(target=direct, args=(k,))
-               for k in range(n)]
-    t0 = time.perf_counter()
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    ctrl_wall = time.perf_counter() - t0
-    print(json.dumps({
-        "replicas": n,
-        "requests": int(flat.size),
-        "qps": round(flat.size / fleet_wall, 2),
-        "p50_ms": round(float(np.percentile(flat, 50)) * 1e3, 2),
-        "p99_ms": round(float(np.percentile(flat, 99)) * 1e3, 2),
-        "routed": routed,
-        "stages": stages,
-        "control_qps": round(n * ctrl_per / ctrl_wall, 2),
-        "backend": jax.default_backend(),
-    }))
-
-
-def bench_serve_fleet():
-    """Replica sweep of the serving fleet (forced host-device counts
-    1/2/8 in subprocess children, single-thread XLA compute): QPS +
-    p50/p99 vs replicas, scaling efficiency vs 1 replica, and the
-    control ceiling (replicated scoring without the fleet layer).
-
-    Gated in this output: QPS monotone in replicas; absolute scaling
-    efficiency >= 0.7 at 2 and at 8 replicas, armed on EVERY backend
-    with the cores to express the scaling (CPU harness included — the
-    columnar wire path's one staging device_put per coalesced batch
-    took the GIL-held per-request featurize convoy off the hot path,
-    which was the reason this gate used to except CPU; a harness with
-    fewer cores than replicas is core-starved physics no wire format
-    fixes, so there only the non-degrading + fleet-vs-control gates
-    bind); fleet QPS vs the measured control ceiling >= 0.75 is gated
-    everywhere."""
-    import subprocess
-
-    spec = SERVE_FLEET
-    points = {}
-    backend = None
-    for n in spec["replica_counts"]:
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-            + " --xla_cpu_multi_thread_eigen=false").strip()
-        best = None
-        # best-of-reps per point: the gates below compare closed-loop
-        # wall-clock QPS across points, and a transient host load spike
-        # during one child must not masquerade as a scaling regression
-        for _rep in range(max(1, spec["reps"])):
-            proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__),
-                 "--serve-fleet-child", str(n)],
-                env=env, capture_output=True, text=True, timeout=1800)
-            if proc.returncode != 0:
-                raise RuntimeError(
-                    f"serve_fleet child ({n} replicas) failed:\n"
-                    f"{proc.stderr[-2000:]}")
-            res = json.loads(proc.stdout.strip().splitlines()[-1])
-            if best is None or res["qps"] > best["qps"]:
-                best = res
-        backend = best["backend"]
-        points[str(n)] = best
-    base = points["1"]["qps"]
-    ctrl_base = points["1"]["control_qps"]
-    for n_str, res in points.items():
-        n = int(n_str)
-        res["scaling_efficiency"] = round(res["qps"] / base / n, 4)
-        res["control_efficiency"] = round(
-            res["control_qps"] / ctrl_base / n, 4)
-        res["fleet_vs_control"] = round(
-            res["qps"] / res["control_qps"], 4)
-    counts = spec["replica_counts"]
-    qps_seq = [points[str(n)]["qps"] for n in counts]
-    eff2 = points["2"]["scaling_efficiency"]
-    eff8 = points["8"]["scaling_efficiency"]
-    cpu_harness = backend == "cpu"
-    # a forced host device only behaves like a replica-sized compute
-    # resource when a real core backs it: with fewer cores than
-    # replicas NO implementation can scale (the device math itself
-    # serializes — the CONTROL collapses identically), so each
-    # absolute gate arms only where the harness can physically express
-    # the scaling it checks. That arming is core-count physics, not
-    # the old GIL exception: the zero-copy wire path's single staging
-    # device_put per coalesced batch removed the per-request featurize
-    # convoy, so a CPU harness WITH the cores now clears the same
-    # absolute floors accelerators do. The fleet layer's own overhead
-    # (fleet vs the measured control ceiling) is gated everywhere.
-    cores = os.cpu_count() or 1
-    eff2_armed = not cpu_harness or cores >= 2
-    eff8_armed = not cpu_harness or cores >= counts[-1]
-    if cpu_harness:
-        # strict scaling only across the points a core actually backs;
-        # past the core count the closed loop saturates (control
-        # included), so the gate is non-degrading — adding replicas
-        # must never cost throughput (a slightly wider band when the
-        # forced-device scheduler itself is core-starved)
-        strict = [q for n, q in zip(counts, qps_seq) if n <= cores]
-        band = 0.9 if cores >= counts[-1] else 0.85
-        monotone = (all(b > a for a, b in zip(strict, strict[1:]))
-                    and qps_seq[-1] >= band * max(qps_seq))
-    else:
-        monotone = all(b > a for a, b in zip(qps_seq, qps_seq[1:]))
-    gates = {
-        "monotone_qps": monotone,
-        "efficiency_at_2": (eff2 >= spec["eff2_floor"]
-                            if eff2_armed else True),
-        "efficiency_at_8": (eff8 >= spec["eff8_floor"]
-                            if eff8_armed else True),
-        "fleet_vs_control_at_8": (
-            points["8"]["fleet_vs_control"] >= spec["fleet_vs_ceiling"]),
-    }
-    out = {
-        "replica_counts": {str(n): points[str(n)] for n in counts},
-        "gates": gates,
-        "cores": cores,
-        "efficiency_gates_armed": {"at_2": eff2_armed,
-                                   "at_8": eff8_armed},
-        "gate_policy": ((f"cpu-harness ({cores} core(s)): strict "
-                         "monotone across replica counts a core backs, "
-                         "non-degrading past them; "
-                         if cpu_harness else
-                         "accelerator backend: strict monotone QPS "
-                         "gated; ")
-                        + "absolute efficiency floors "
-                        f"(>= {spec['eff2_floor']} at 2, >= "
-                        f"{spec['eff8_floor']} at 8) armed wherever "
-                        "the harness has the cores to express scaling "
-                        "— the columnar wire path's single staging "
-                        "device_put per coalesced batch retired the "
-                        "per-request featurize convoy this gate used "
-                        "to except ANY CPU harness for; plus fleet vs "
-                        "the measured control ceiling >= "
-                        f"{spec['fleet_vs_ceiling']} everywhere"),
-        "note": ("closed-loop 512-row requests through the drain-aware "
-                 "router across N per-device replicas (forced host "
-                 "devices, single-thread XLA compute so one device = "
-                 "one core-sized resource). control_qps = the same N "
-                 "device-pinned registries driven directly from N "
-                 "threads — the host's replicated-scoring ceiling "
-                 "without the fleet layer; on the GIL-bound CPU "
-                 "harness the absolute 8-replica wall-clock efficiency "
-                 "used to be bounded by the shared interpreter lock "
-                 "(per-request parse + featurize + device_put all "
-                 "GIL-held); the columnar wire path collapses that to "
-                 "one vectorized staging fill and ONE device_put per "
-                 "coalesced batch, so the absolute >= 0.7 gate now "
-                 "arms on every backend, with the fleet-vs-ceiling "
-                 "gate kept beside it."),
-    }
-    if not all(gates.values()):
-        raise RuntimeError(
-            f"serve_fleet gates failed: {gates} {json.dumps(points)}")
-    return out
-
-
-def _coresident_loop_child() -> None:
-    """Entry for `bench.py --coresident-loop-child`: one forced-8-device
-    measurement of co-resident retraining as a serving-fleet tenant.
-    Prints ONE JSON line: solo-serve p99, co-serve p99 with the
-    pipeline trainer resident on the same devices, epochs-to-target,
-    and the evict -> resume bit-identity verdict."""
-    import tempfile
-    import threading
-
-    import jax
-
-    from shifu_tpu.coresident import (
-        CoresidentConfig,
-        EvictedError,
-        GrantFullError,
-        LocalGrant,
-        train_nn_coresident,
-    )
-    from shifu_tpu.models.nn import NNModelSpec, flatten_params, init_params
-    from shifu_tpu.norm.dataset import write_normalized
-    from shifu_tpu.serve.fleet import ReplicaFleet
-    from shifu_tpu.serve.registry import records_to_columnar
-    from shifu_tpu.train.nn_trainer import NNTrainConfig
-
-    spec = CORESIDENT
-    cols = [f"c{k}" for k in range(spec["cols"])]
-    sizes = [spec["cols"], spec["serve_hidden"], 1]
-    tmp = tempfile.mkdtemp(prefix="bench-coresident-")
-    models = os.path.join(tmp, "models")
-    os.makedirs(models)
-    for b in range(spec["bags"]):
-        norm_specs = [
-            {"name": c, "kind": "value", "outNames": [c], "mean": 0.0,
-             "std": 1.0, "fill": 0.0, "zscore": True} for c in cols]
-        NNModelSpec(layer_sizes=sizes, activations=["tanh"],
-                    input_columns=cols, norm_specs=norm_specs,
-                    params=init_params(sizes, seed=b),
-                    ).save(os.path.join(models, f"model{b}.nn"))
-    rng = np.random.default_rng(0)
-    pool = []
-    for _ in range(8):
-        rows = rng.normal(size=(spec["rows"], spec["cols"]))
-        recs = [{c: f"{v:.5f}" for c, v in zip(cols, row)}
-                for row in rows]
-        pool.append(records_to_columnar(recs, cols))
-
-    # the retrain stream on disk — the co-resident trainer is always
-    # shard-streamed, so the bench feeds it the same way production does
-    n, d = spec["train_rows"], spec["train_cols"]
-    trng = np.random.default_rng(7)
-    x = trng.normal(size=(n, d)).astype(np.float32)
-    t = (x @ trng.normal(size=d) > 0).astype(np.float32)
-    data_dir = os.path.join(tmp, "norm")
-    write_normalized(data_dir, x, t, np.ones(n, np.float32),
-                     [f"f{i}" for i in range(d)],
-                     n_shards=spec["train_shards"])
-
-    fleet = ReplicaFleet.build(models, n_replicas=spec["replicas"],
-                               max_batch_rows=spec["rows"],
-                               queue_depth=64)
-    fleet.warm([spec["rows"]])
-
-    def serve_pass() -> float:
-        lat = [[] for _ in range(spec["concurrency"])]
-
-        def client(ti):
-            for k in range(spec["per_thread"]):
-                t0 = time.perf_counter()
-                fleet.submit(pool[(ti + k) % len(pool)]).wait(120)
-                lat[ti].append(time.perf_counter() - t0)
-
-        threads = [threading.Thread(target=client, args=(ti,))
-                   for ti in range(spec["concurrency"])]
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join()
-        flat = np.asarray([v for ts in lat for v in ts])
-        return round(float(np.percentile(flat, 99)) * 1e3, 3)
-
-    solo = min(serve_pass() for _ in range(spec["reps"]))
-
-    # ---- co-serve: the stage pipeline resident on the SAME devices ----
-    curve = []
-    cfg = NNTrainConfig(hidden_nodes=list(spec["train_hidden"]),
-                        activations=["tanh"], propagation="R",
-                        num_epochs=spec["epochs"], valid_set_rate=0.1,
-                        seed=5)
-    cfg.checkpoint_every = 1
-    cfg.progress_cb = lambda ep, tr, va: curve.append((ep, float(tr)))
-    ccfg = CoresidentConfig(
-        stages=spec["stages"], microbatches=spec["microbatches"],
-        replicas=1, tenant="bench", throttle_ms=spec["throttle_ms"],
-        family_dir=os.path.join(tmp, "fam-serve")).resolve()
-    trainer_out = {}
-
-    def run_trainer():
-        t0 = time.perf_counter()
-        trainer_out["res"] = train_nn_coresident(
-            data_dir, cfg, ccfg=ccfg, grant=LocalGrant("bench"))
-        trainer_out["seconds"] = time.perf_counter() - t0
-
-    th = threading.Thread(target=run_trainer)
-    th.start()
-    # measure past the one-time stage-program compiles: those are
-    # admission cost, not steady-state co-residency cost
-    while len(curve) < 2 and th.is_alive():
-        time.sleep(0.05)
-    co_p99s = []
-    while th.is_alive() and len(co_p99s) < spec["reps"] + 1:
-        co_p99s.append(serve_pass())
-    th.join()
-    fleet.close(60)
-    if not co_p99s:
-        raise RuntimeError("trainer finished before any co-serve pass "
-                           "overlapped it; raise CORESIDENT['epochs']")
-    co = min(co_p99s)
-    final_tr = curve[-1][1]
-    target = final_tr * 1.05
-    epochs_to_target = next((ep for ep, tr in curve if tr <= target),
-                            curve[-1][0])
-
-    # ---- evict -> resume bit-identity on the same forced devices ----
-    def ckpt_cfg() -> NNTrainConfig:
-        c = NNTrainConfig(hidden_nodes=list(spec["train_hidden"]),
-                          activations=["tanh"], propagation="R",
-                          num_epochs=spec["ckpt_epochs"],
-                          valid_set_rate=0.1, seed=5)
-        c.checkpoint_every = 10_000  # the family still saves each epoch
-        return c
-
-    def cc(tag, **kw) -> CoresidentConfig:
-        return CoresidentConfig(
-            stages=spec["stages"], microbatches=spec["microbatches"],
-            replicas=1, tenant="bench-ckpt",
-            family_dir=os.path.join(tmp, tag), **kw).resolve()
-
-    flat_a, _ = flatten_params(train_nn_coresident(
-        data_dir, ckpt_cfg(), ccfg=cc("fam-a"),
-        grant=LocalGrant("bench-ckpt")).params)
-
-    class EvictingGrant(LocalGrant):
-        """Serving pressure at a fixed epoch: the heartbeat flags the
-        eviction and re-admission never fits (wait_ms=0 surfaces
-        EvictedError immediately, as a saturated fleet would)."""
-
-        def __init__(self, name, evict_at):
-            super().__init__(name)
-            self.evict_at = evict_at
-            self.tripped = False
-
-        def heartbeat(self, epoch):
-            if epoch >= self.evict_at:
-                self.tripped = True
-            return self.tripped
-
-        def acquire(self, nbytes):
-            if self.tripped:
-                raise GrantFullError("serving pressure", int(nbytes))
-            super().acquire(nbytes)
-
-    evicted_at = None
-    try:
-        train_nn_coresident(data_dir, ckpt_cfg(), ccfg=cc(
-            "fam-b", wait_ms=0.0), grant=EvictingGrant(
-                "bench-ckpt", spec["evict_epoch"]))
-    except EvictedError as e:
-        evicted_at = e.epoch
-    flat_b, _ = flatten_params(train_nn_coresident(
-        data_dir, ckpt_cfg(), ccfg=cc("fam-b"),
-        grant=LocalGrant("bench-ckpt"), resume=True).params)
-
-    print(json.dumps({
-        "solo_p99_ms": solo,
-        "coserve_p99_ms": co,
-        "p99_ratio": round(co / solo, 4),
-        "coserve_passes": co_p99s,
-        "epochs": curve[-1][0],
-        "trainer_seconds": round(trainer_out.get("seconds", 0.0), 2),
-        "train_error": round(final_tr, 6),
-        "epochs_to_target": int(epochs_to_target),
-        "evicted_at_epoch": evicted_at,
-        "resume_bit_identical": bool(np.array_equal(flat_a, flat_b)),
-        "backend": jax.default_backend(),
-        "cores": os.cpu_count() or 1,
-    }))
-
-
-def bench_coresident_loop():
-    """Co-resident retraining as an HBM-ledger tenant of the serving
-    fleet, on the forced-8-device harness (subprocess child — the
-    device count must be fixed before jax initializes). Gated: serve
-    p99 with the trainer resident <= 1.2x solo-serve p99, and the
-    evicted trainer resumes to bit-identical final weights."""
-    import subprocess
-
-    spec = CORESIDENT
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-        + " --xla_cpu_multi_thread_eigen=false").strip()
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--coresident-loop-child"],
-        env=env, capture_output=True, text=True, timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"coresident_loop child failed:\n{proc.stderr[-2000:]}")
-    res = json.loads(proc.stdout.strip().splitlines()[-1])
-    # like serve_fleet's efficiency floors: the p99 interference gate
-    # arms only where the harness has the cores to express
-    # co-residency — the serving replicas AND the trainer each need a
-    # core-sized compute resource, or any trainer activity steals the
-    # serving core by scheduling physics no implementation can avoid
-    # (with 1 core the ratio measures the OS scheduler, not the
-    # co-resident design). Recorded everywhere; gated where armed.
-    # The evict -> resume bit-identity gate is physics-free and is
-    # armed on every harness.
-    p99_armed = (res["backend"] != "cpu"
-                 or res["cores"] >= spec["replicas"] + spec["stages"])
-    gates = {
-        "p99_within_ceiling": (res["p99_ratio"] <= spec["p99_ceiling"]
-                               if p99_armed else True),
-        "evict_resume_bit_identical": res["resume_bit_identical"],
-    }
-    out = {
-        **res,
-        "p99_ceiling": spec["p99_ceiling"],
-        "p99_gate_armed": p99_armed,
-        "gates": gates,
-        "note": ("closed-loop scoring through a "
-                 f"{spec['replicas']}-replica forced-device fleet, "
-                 "solo vs with the K-stage pipeline retrainer resident "
-                 "as a background ledger tenant on the same devices "
-                 f"(stages={spec['stages']}, microbatches="
-                 f"{spec['microbatches']}, throttleMs="
-                 f"{spec['throttle_ms']}); p99s are min-over-passes on "
-                 "both sides so a host load spike is not booked as "
-                 "co-residency cost. The p99 <= "
-                 f"{spec['p99_ceiling']}x gate arms where the harness "
-                 "has cores for the replicas AND the trainer stages "
-                 "(accelerator backends always); a core-starved CPU "
-                 "harness records the ratio — there it measures the OS "
-                 "scheduler, not the design. epochs_to_target = first "
-                 "epoch whose train error is within 5% of the final "
-                 "error (recorded, not gated). The evict leg "
-                 f"checkpoints at epoch {spec['evict_epoch']} under "
-                 "synthetic serving pressure, resumes in a fresh run, "
-                 "and the final weights must be bit-identical to the "
-                 "uninterrupted run — gated on every harness."),
-    }
-    if not all(gates.values()):
-        raise RuntimeError(
-            f"coresident_loop gates failed: {gates} {json.dumps(res)}")
-    return out
-
-
-def bench_failover():
-    """Failure-domain scenario (shifu_tpu/serve/ breaker + failover):
-    closed-loop load on a 2-replica fleet while replica 1's device dies
-    persistently (`device_dead@replica=1` — the chaos grammar's
-    replica-targeted seam). Measures p50/p99 before and during the trip
-    and the recovery-to-closed time through half-open probing after the
-    device heals. GATED: every request of every phase answered exactly
-    once (zero unanswered, zero double-answered — per-replica resolved
-    counters sum to submissions), the breaker trips open, and recovery
-    reaches closed within the timeout."""
-    import shutil
-    import tempfile
-    import threading
-
-    from shifu_tpu import obs
-    from shifu_tpu.models.nn import NNModelSpec, init_params
-    from shifu_tpu.resilience import faults
-    from shifu_tpu.serve.fleet import ReplicaFleet
-    from shifu_tpu.serve.health import BREAKER_CLOSED, BREAKER_OPEN
-    from shifu_tpu.utils import environment
-
-    spec = FAILOVER
-    cols = [f"c{i}" for i in range(spec["cols"])]
-    tmp = tempfile.mkdtemp(prefix="bench-failover-")
-    props = {
-        "shifu.serve.breaker.failures": str(spec["breaker_failures"]),
-        "shifu.serve.breaker.probeBaseMs": str(spec["probe_base_ms"]),
-        "shifu.serve.breaker.probeCapMs": str(spec["probe_cap_ms"]),
-    }
-    try:
-        rng = np.random.default_rng(0)
-        sizes = [spec["cols"]] + list(spec["hidden"]) + [1]
-        for b in range(spec["bags"]):
-            norm_specs = [
-                {"name": c, "kind": "value", "outNames": [c],
-                 "mean": float(rng.normal()), "std": 1.0, "fill": 0.0,
-                 "zscore": True}
-                for c in cols
-            ]
-            NNModelSpec(
-                layer_sizes=sizes, activations=["tanh"],
-                input_columns=cols, norm_specs=norm_specs,
-                params=init_params(sizes, seed=b),
-            ).save(os.path.join(tmp, f"model{b}.nn"))
-        for k, v in props.items():
-            environment.set_property(k, v)
-        fleet = ReplicaFleet.build(tmp, n_replicas=2,
-                                   queue_depth=spec["queue_depth"])
-        fleet.warm([1, spec["concurrency"]])
-        victim = fleet.replicas[1]
-
-        def record(i):
-            return {c: f"{0.1 * (i % 7) - 0.3:.4f}" for c in cols}
-
-        submitted = [0]
-        failed = []
-
-        def run_phase(tag):
-            conc, per = spec["concurrency"], spec["per_thread"]
-            lat = [[] for _ in range(conc)]
-
-            def client(ti):
-                for k in range(per):
-                    t0 = time.perf_counter()
-                    try:
-                        res = fleet.score_batch([record(k)], timeout=60)
-                        assert len(res.mean) == 1
-                    except Exception as e:  # noqa: BLE001 - gated below
-                        failed.append((tag, repr(e)))
-                    lat[ti].append(time.perf_counter() - t0)
-
-            threads = [threading.Thread(target=client, args=(ti,))
-                       for ti in range(conc)]
-            t0 = time.perf_counter()
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            elapsed = time.perf_counter() - t0
-            submitted[0] += conc * per
-            flat = np.asarray([v for ts in lat for v in ts])
-            return {
-                "p50_ms": round(float(np.percentile(flat, 50)) * 1e3, 3),
-                "p99_ms": round(float(np.percentile(flat, 99)) * 1e3, 3),
-                "qps": round(len(flat) / elapsed, 1),
-            }
-
-        baseline = run_phase("baseline")
-        # ---- the trip: replica 1's device dies persistently ----
-        t_arm = time.perf_counter()
-        with faults.activate(faults.FaultPlan.parse(
-                "device_dead@replica=1")):
-            during = run_phase("device_dead")
-            tripped = victim.breaker.state == BREAKER_OPEN
-            breaker_snap = victim.breaker.snapshot()
-        # ---- healed: light traffic carries the half-open probes ----
-        t_heal = time.perf_counter()
-        recovered_in = None
-        deadline = t_heal + spec["recover_timeout_s"]
-        i = 0
-        while time.perf_counter() < deadline:
-            try:
-                fleet.score_batch([record(i)], timeout=60)
-            except Exception as e:  # noqa: BLE001 - gated below
-                failed.append(("recovery", repr(e)))
-            submitted[0] += 1
-            i += 1
-            if victim.breaker.state == BREAKER_CLOSED:
-                recovered_in = time.perf_counter() - t_heal
-                break
-            time.sleep(0.005)
-        counters = obs.registry().snapshot()["counters"]
-        resolved = sum(v for k, v in counters.items()
-                       if k.startswith("serve.requests{"))
-        failovers = sum(v for k, v in counters.items()
-                        if k.startswith("serve.failover.requests"))
-        fleet.close(30)
-        gates = {
-            # answered exactly once each: no unanswered (every
-            # score_batch returned), no double-answered (resolved
-            # counters == submissions), no errors surfaced to clients
-            "zero_unanswered": not failed,
-            "zero_double_answered": resolved == submitted[0],
-            "breaker_tripped": bool(tripped),
-            "recovered_to_closed": recovered_in is not None,
-        }
-        out = {
-            "baseline": baseline,
-            "during_trip": during,
-            "requests": submitted[0],
-            "resolved": int(resolved),
-            "failed_requests": len(failed),
-            "failovers": int(failovers),
-            "breaker_at_trip": breaker_snap,
-            "trip_window_s": round(t_heal - t_arm, 3),
-            "recovery_to_closed_s": (None if recovered_in is None
-                                     else round(recovered_in, 3)),
-            "gates": gates,
-            "note": ("closed-loop 1-record requests on a 2-replica "
-                     "fleet; during_trip has replica 1 failing every "
-                     "dispatch (device_dead@replica=1) — its batches "
-                     "fail over to replica 0 under the bounded budget, "
-                     "so clients see latency, never errors; recovery = "
-                     "disarm to breaker-closed via jittered half-open "
-                     "probes riding live traffic"),
-        }
-        if not all(gates.values()):
-            raise RuntimeError(
-                f"failover gates failed: {gates} "
-                f"{json.dumps({k: v for k, v in out.items() if k != 'note'})}"
-            )
-        return out
-    finally:
-        for k in props:
-            environment.set_property(k, "")
-        shutil.rmtree(tmp, ignore_errors=True)
-
-
-def bench_model_zoo():
-    """Multi-tenant model zoo on a bounded HBM budget (serve/zoo.py):
-    tenant-count x working-set sweep under a budget that fits only TWO
-    of the three tenants, so residency churns.
-
-    GATED: (1) every tenant's routed scores are BYTE-identical to a
-    single-tenant registry serving the same set; (2) the budget
-    ledger's peak occupancy stays <= budget at every sample — including
-    through a streamed shadow stage + promote on the near-full budget;
-    (3) the warm tenant's p99 stays within 1.10x of the single-tenant
-    baseline (interleaved best-of-reps, the tracing_overhead idiom).
-    Warm vs cold p50/p99 and the eviction rate are the reported
-    working-set numbers."""
-    import shutil
-    import tempfile
-    import threading
-
-    from shifu_tpu import obs
-    from shifu_tpu.models.nn import NNModelSpec, init_params
-    from shifu_tpu.serve.registry import ModelRegistry
-    from shifu_tpu.serve.server import Scorer
-    from shifu_tpu.serve.zoo import ModelZoo
-
-    spec = MODEL_ZOO
-    cols = [f"c{i}" for i in range(spec["cols"])]
-    tmp = tempfile.mkdtemp(prefix="bench-zoo-")
-    rng = np.random.default_rng(0)
-
-    def build_set(name, hidden, seed):
-        d = os.path.join(tmp, name, "models")
-        os.makedirs(d)
-        sizes = [spec["cols"], hidden, 1]
-        for b in range(spec["bags"]):
-            norm_specs = [
-                {"name": c, "kind": "value", "outNames": [c],
-                 "mean": float(rng.normal()), "std": 1.0, "fill": 0.0,
-                 "zscore": True}
-                for c in cols
-            ]
-            NNModelSpec(
-                layer_sizes=sizes, activations=["tanh"],
-                input_columns=cols, norm_specs=norm_specs,
-                params=init_params(sizes, seed=seed + b),
-            ).save(os.path.join(d, f"model{b}.nn"))
-        return d
-
-    def record(i):
-        return {c: f"{0.07 * (i % 11) - 0.3:.4f}" for c in cols}
-
-    def closed_loop(score_one, n_requests, conc):
-        lat = [[] for _ in range(conc)]
-        per = n_requests // conc
-
-        def run(ti):
-            for k in range(per):
-                t0 = time.perf_counter()
-                score_one(ti * per + k)
-                lat[ti].append(time.perf_counter() - t0)
-
-        threads = [threading.Thread(target=run, args=(ti,))
-                   for ti in range(conc)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        flat = np.asarray([v for ts in lat for v in ts])
-        return (float(np.percentile(flat, 50)) * 1e3,
-                float(np.percentile(flat, 99)) * 1e3)
-
-    try:
-        tenants = {}
-        for name, hidden, seed in (("t0", spec["hiddens"][0], 0),
-                                   ("t1", spec["hiddens"][1], 100),
-                                   ("t2", spec["hiddens"][2], 200)):
-            tenants[name] = build_set(name, hidden, seed)
-        # reference scores + measured per-set cost from single-tenant
-        # registries (the bench's own memory_analysis read)
-        parity_recs = [record(i) for i in range(16)]
-        reference = {}
-        costs = {}
-        for name, mdir in tenants.items():
-            reg = ModelRegistry(mdir)
-            # the buckets live single-record traffic actually compiles
-            # (16-record parity batch -> 16; coalesced singles -> 8),
-            # so the bench-measured cost matches what the zoo charges
-            reg.warm([1, 8, 16])
-            reference[name] = reg.score_records(parity_recs)
-            costs[name] = reg.memory_analysis()["residentBytes"]
-            reg.release()
-        # budget: the two SMALLEST working sets fit, all three do not —
-        # residency must churn when the sweep touches every tenant
-        by_cost = sorted(costs.values())
-        budget_bytes = int(by_cost[0] + by_cost[1] + 0.5 * by_cost[2])
-        budget_mb = budget_bytes / (1024.0 * 1024.0)
-        zoo = ModelZoo(tmp, n_replicas=1, budget_mb=budget_mb)
-        for name, mdir in tenants.items():
-            zoo.register(name, os.path.dirname(mdir))
-        # ---- parity gate: routed zoo scores == single-tenant scores
-        parity = True
-        for name in tenants:
-            zoo.ensure_resident(name)  # LRU-evicts as needed
-            res = zoo.score_batch(name, parity_recs)
-            parity &= bool(
-                np.array_equal(res.model_scores,
-                               reference[name].model_scores)
-                and np.array_equal(res.mean, reference[name].mean))
-        # ---- warm p99 vs single-tenant baseline, interleaved reps
-        single_reg = ModelRegistry(tenants["t0"])
-        single = Scorer(single_reg)
-        single_reg.warm([1, 8])
-        zoo.ensure_resident("t0")
-        single_p99, zoo_p99 = [], []
-        single_p50, zoo_p50 = [], []
-        for _rep in range(spec["reps"]):
-            p50, p99 = closed_loop(
-                lambda i: single.score_batch([record(i)]),
-                spec["requests"], spec["concurrency"])
-            single_p50.append(p50)
-            single_p99.append(p99)
-            p50, p99 = closed_loop(
-                lambda i: zoo.score_batch("t0", [record(i)]),
-                spec["requests"], spec["concurrency"])
-            zoo_p50.append(p50)
-            zoo_p99.append(p99)
-        single.close()
-        warm_ratio = min(zoo_p99) / max(min(single_p99), 1e-9)
-        # ---- churn sweep: touch every tenant round-robin so the
-        # working set exceeds the budget and evictions happen; cold
-        # admissions are timed (the re-admission p99 the ROADMAP asks
-        # for), warm scores separately
-        cold_s = []
-        warm_ms = []
-        ledger_samples = []
-        c0 = obs.registry().snapshot()["counters"]
-        evict_before = sum(v for k, v in c0.items()
-                           if k.startswith("serve.zoo.evictions"))
-        order = ["t0", "t1", "t2", "t1", "t2", "t0", "t2", "t0", "t1"]
-        for i, name in enumerate(order):
-            if zoo._get(name).state != "resident":
-                t0 = time.perf_counter()
-                zoo.ensure_resident(name)
-                cold_s.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            zoo.score_batch(name, [record(i)])
-            warm_ms.append((time.perf_counter() - t0) * 1e3)
-            ledger_samples.append(zoo.ledger.used)
-        c1 = obs.registry().snapshot()["counters"]
-        evictions = sum(v for k, v in c1.items()
-                        if k.startswith("serve.zoo.evictions")) \
-            - evict_before
-        # ---- streamed shadow stage + promote on the near-full budget
-        zoo.ensure_resident("t0")
-        staged = zoo.stage("t0", tenants["t1"])
-        ledger_samples.append(zoo.ledger.used)
-        swap = zoo.promote("t0", expected_sha=staged["sha"])
-        ledger_samples.append(zoo.ledger.used)
-        peak = zoo.ledger.peak
-        zoo.close()
-        gates = {
-            "parity_bit_identical": parity,
-            "peak_ledgered_le_budget": bool(
-                peak <= budget_bytes
-                and max(ledger_samples) <= budget_bytes),
-            "warm_p99_within_1_10x": bool(warm_ratio <= 1.10),
-        }
-        out = {
-            "tenants": {
-                name: {"hidden": h,
-                       "workingSetBytes": costs[name]}
-                for (name, h) in zip(("t0", "t1", "t2"),
-                                     spec["hiddens"])
-            },
-            "budget_bytes": budget_bytes,
-            "sum_working_sets_bytes": int(sum(costs.values())),
-            "peak_ledgered_bytes": int(peak),
-            "evictions": int(evictions),
-            "eviction_rate": round(evictions / len(order), 3),
-            "warm_p50_ms": round(min(zoo_p50), 3),
-            "warm_p99_ms": round(min(zoo_p99), 3),
-            "single_tenant_p50_ms": round(min(single_p50), 3),
-            "single_tenant_p99_ms": round(min(single_p99), 3),
-            "warm_p99_ratio": round(warm_ratio, 3),
-            "cold_admissions": len(cold_s),
-            "cold_admission_p50_ms": (round(
-                float(np.percentile(cold_s, 50)) * 1e3, 1)
-                if cold_s else None),
-            "cold_admission_p99_ms": (round(
-                float(np.percentile(cold_s, 99)) * 1e3, 1)
-                if cold_s else None),
-            "promote": {"from": swap["from"], "to": swap["to"]},
-            "gates": gates,
-            "note": ("3 tenants (working-set sweep via hidden width) "
-                     "under a budget fitting only 2: routed scores "
-                     "byte-identical to single-tenant serving per set, "
-                     "peak LEDGERED residency <= budget at every "
-                     "sample incl. the streamed shadow stage + "
-                     "promote, warm p99 within 1.10x single-tenant "
-                     "(interleaved best-of-reps), cold p50/p99 = "
-                     "admission (rebuild+warm) on re-admission, "
-                     "eviction rate over the churn sweep"),
-        }
-        if not all(gates.values()):
-            raise RuntimeError(
-                f"model_zoo gates failed: {gates} "
-                f"{json.dumps({k: v for k, v in out.items() if k != 'note'})}")
-        return out
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-
-
-def bench_serve_latency():
-    """Online scoring (shifu_tpu/serve/): p50/p99 single-record latency +
-    QPS at several closed-loop concurrency levels, through the full
-    admission -> micro-batcher -> fused raw->score program path. The
-    registry snapshot in the output proves the steady-state compile bound:
-    every batch pads to a power-of-two row bucket, so `warmBuckets` (and
-    the jax.compiles counter beside it) stays O(log max_batch_rows) no
-    matter how many requests run. The transfer guard is armed on this
-    scenario — the scoring seam does ONE explicit device_put per batch and
-    must move nothing else."""
-    import shutil
-    import tempfile
-    import threading
-
-    from shifu_tpu.models.nn import NNModelSpec, init_params
-    from shifu_tpu.serve.queue import AdmissionQueue
-    from shifu_tpu.serve.registry import ModelRegistry
-    from shifu_tpu.serve.server import Scorer
-
-    spec = SERVE
-    cols = [f"c{i}" for i in range(spec["cols"])]
-    tmp = tempfile.mkdtemp(prefix="bench-serve-")
-    try:
-        rng = np.random.default_rng(0)
-        sizes = [spec["cols"]] + list(spec["hidden"]) + [1]
-        for b in range(spec["bags"]):
-            norm_specs = [
-                {"name": c, "kind": "value", "outNames": [c],
-                 "mean": float(rng.normal()), "std": 1.0, "fill": 0.0,
-                 "zscore": True}
-                for c in cols
-            ]
-            NNModelSpec(
-                layer_sizes=sizes, activations=["tanh"],
-                input_columns=cols, norm_specs=norm_specs,
-                params=init_params(sizes, seed=b),
-            ).save(os.path.join(tmp, f"model{b}.nn"))
-        registry = ModelRegistry(tmp)
-        scorer = Scorer(registry, AdmissionQueue(spec["queue_depth"]))
-        # warm every bucket the concurrency sweep can produce (single-
-        # record requests coalesce to at most `concurrency` rows)
-        registry.warm([1, max(spec["concurrency"])])
-
-        def record(i):
-            return {c: f"{0.1 * (i % 7) - 0.3:.4f}" for c in cols}
-
-        out = {}
-        p0 = _profile_totals()
-        sweep_elapsed = 0.0
-        for conc in spec["concurrency"]:
-            per_thread = spec["requests"] // conc
-            lat = [[] for _ in range(conc)]
-
-            def run(ti):
-                for k in range(per_thread):
-                    t0 = time.perf_counter()
-                    scorer.score_batch([record(ti * per_thread + k)])
-                    lat[ti].append(time.perf_counter() - t0)
-
-            threads = [threading.Thread(target=run, args=(ti,))
-                       for ti in range(conc)]
-            t0 = time.perf_counter()
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            elapsed = time.perf_counter() - t0
-            sweep_elapsed += elapsed
-            flat = np.asarray([v for ts in lat for v in ts])
-            out[f"concurrency_{conc}"] = {
-                "requests": int(flat.size),
-                "p50_ms": round(float(np.percentile(flat, 50)) * 1e3, 3),
-                "p99_ms": round(float(np.percentile(flat, 99)) * 1e3, 3),
-                "qps": round(flat.size / elapsed, 1),
-            }
-        scorer.close()
-
-        # continuous vs barrier batching at the TOP concurrency level:
-        # the fleet PR's continuous mode closes buckets on capacity or
-        # queue-dry, so p99 stops paying the maxWaitMs coalesce
-        # deadline the barrier mode waits out on every non-full batch.
-        # GATED: continuous must beat barrier on p99 (the barrier pass
-        # pays the default 2 ms deadline per dispatch by construction).
-        def batching_pass(mode, conc):
-            reg2 = ModelRegistry(tmp)
-            sc = Scorer(reg2, AdmissionQueue(spec["queue_depth"]),
-                        batching=mode)
-            reg2.warm([1, conc])
-            # a larger sample than the headline sweep: the gate below
-            # compares two p99s whose true gap is ~maxWaitMs, so both
-            # passes get enough requests for a stable tail estimate
-            per = max(30, spec["requests"] // conc)
-            lat2 = [[] for _ in range(conc)]
-
-            def run2(ti):
-                for k in range(per):
-                    t0 = time.perf_counter()
-                    sc.score_batch([record(ti * per + k)])
-                    lat2[ti].append(time.perf_counter() - t0)
-
-            threads = [threading.Thread(target=run2, args=(ti,))
-                       for ti in range(conc)]
-            t0 = time.perf_counter()
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            wall = time.perf_counter() - t0
-            sc.close()
-            flat2 = np.asarray([v for ts in lat2 for v in ts])
-            return {
-                "p50_ms": round(float(np.percentile(flat2, 50)) * 1e3, 3),
-                "p99_ms": round(float(np.percentile(flat2, 99)) * 1e3, 3),
-                "qps": round(flat2.size / wall, 1),
-            }
-
-        top = max(spec["concurrency"])
-        # best-of-3 per mode (the serve_fleet best-of-reps policy), and
-        # the BINDING gate moved to low concurrency: at conc=2 a
-        # barrier bucket pays the full maxWaitMs deadline per dispatch
-        # (the row cap is never reached), so continuous beating barrier
-        # on p50 there is the structural claim and reproduces every
-        # run; at top concurrency the closed loop converges the two
-        # policies (barrier's wait also coalesces more), so the p99
-        # comparison is recorded with a 1.10 noise band instead of a
-        # strict inequality that flips on host load
-        low = 2
-        barrier_low = min((batching_pass("barrier", low)
-                           for _ in range(3)),
-                          key=lambda r: r["p50_ms"])
-        continuous_low = min((batching_pass("continuous", low)
-                              for _ in range(3)),
-                             key=lambda r: r["p50_ms"])
-        barrier = min((batching_pass("barrier", top) for _ in range(3)),
-                      key=lambda r: r["p99_ms"])
-        continuous = min((batching_pass("continuous", top)
-                          for _ in range(3)),
-                         key=lambda r: r["p99_ms"])
-        gates = {
-            "continuous_beats_barrier_p50_low_conc":
-                continuous_low["p50_ms"] < barrier_low["p50_ms"],
-            "continuous_within_noise_of_barrier_p99":
-                continuous["p99_ms"] < barrier["p99_ms"] * 1.10,
-        }
-        out["batching"] = {
-            "concurrency": top,
-            "barrier": barrier,
-            "continuous": continuous,
-            "low_concurrency": {
-                "concurrency": low,
-                "barrier": barrier_low,
-                "continuous": continuous_low,
-                "continuous_over_barrier_p50": round(
-                    continuous_low["p50_ms"] / barrier_low["p50_ms"], 3),
-            },
-            "continuous_over_barrier_p99": round(
-                continuous["p99_ms"] / barrier["p99_ms"], 3),
-            "gates": gates,
-        }
-        if not all(gates.values()):
-            raise RuntimeError(
-                f"serve_latency batching gate failed: {gates} "
-                f"(low-conc p50 barrier {barrier_low['p50_ms']} vs "
-                f"continuous {continuous_low['p50_ms']}; top-conc p99 "
-                f"barrier {barrier['p99_ms']} vs continuous "
-                f"{continuous['p99_ms']})")
-
-        # race-sanitizer overhead: the same closed loop at the top
-        # concurrency level, serve stack rebuilt per mode because
-        # arming is read at lock CONSTRUCTION time. Unarmed,
-        # tracked_lock returns a plain threading.Lock, so off_p50 must
-        # sit within noise of the main sweep; the armed multiplier is
-        # recorded, not gated — race is a debugging mode, never the
-        # production default. The armed pass's verdict rides the
-        # scenario sanitizer snapshot like transfer/nan trips.
-        from shifu_tpu.analysis import racetrack
-
-        def race_pass(conc):
-            reg = ModelRegistry(tmp)
-            sc = Scorer(reg, AdmissionQueue(spec["queue_depth"]))
-            reg.warm([1, conc])
-            per = spec["requests"] // conc
-            lat = [[] for _ in range(conc)]
-
-            def run(ti):
-                for k in range(per):
-                    t0 = time.perf_counter()
-                    sc.score_batch([record(ti * per + k)])
-                    lat[ti].append(time.perf_counter() - t0)
-
-            threads = [threading.Thread(target=run, args=(ti,))
-                       for ti in range(conc)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            sc.close()
-            flat = np.asarray([v for ts in lat for v in ts])
-            return float(np.percentile(flat, 50)) * 1e3
-
-        conc = max(spec["concurrency"])
-        off_p50 = race_pass(conc)
-        mark = racetrack.tracker().mark()
-        racetrack.arm(True)
-        try:
-            armed_p50 = race_pass(conc)
-            race_verdict = racetrack.tracker().verdict(mark)
-        finally:
-            racetrack.arm(None)
-        out["race_overhead"] = {
-            "concurrency": conc,
-            "off_p50_ms": round(off_p50, 3),
-            "armed_p50_ms": round(armed_p50, 3),
-            "armed_over_off": (round(armed_p50 / off_p50, 3)
-                               if off_p50 else None),
-            "verdict": race_verdict,
-        }
-
-        # ---- request tracing: per-stage tail breakdown + overhead ----
-        # Three closed-loop passes at the top concurrency: tracing OFF
-        # (sample=0, slowMs=0 — the zero-overhead reference), tracing at
-        # the DEFAULT knobs (the acceptance number: p99 must sit within
-        # noise of off — target < 1.05x, recorded not raised, since a
-        # CPU-harness ms-scale p99 swings more than 5% run to run), and
-        # sample=1.0 (every request traced) whose trace ring yields the
-        # per-stage p50/p99 breakdown. featurize share of p99 is the
-        # tracked number for the ROADMAP host-featurize target.
-        from shifu_tpu.obs import reqtrace
-        from shifu_tpu.utils import environment as _env
-
-        def traced_pass(conc, sample=None, slow_ms=None):
-            for key, v in (("shifu.trace.sample", sample),
-                           ("shifu.trace.slowMs", slow_ms)):
-                _env.set_property(key, "" if v is None else v)
-            reqtrace.reset()
-            reg3 = ModelRegistry(tmp)
-            sc = Scorer(reg3, AdmissionQueue(spec["queue_depth"]))
-            reg3.warm([1, conc])
-            per = spec["requests"] // conc
-            lat3 = [[] for _ in range(conc)]
-
-            def run3(ti):
-                for k in range(per):
-                    t0 = time.perf_counter()
-                    sc.score_batch([record(ti * per + k)])
-                    lat3[ti].append(time.perf_counter() - t0)
-
-            threads = [threading.Thread(target=run3, args=(ti,))
-                       for ti in range(conc)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            sc.close()
-            buf = reqtrace.buffer()
-            for key in ("shifu.trace.sample", "shifu.trace.slowMs"):
-                _env.set_property(key, "")
-            return (np.asarray([v for ts in lat3 for v in ts]), buf)
-
-        # best-of-3 per mode, passes INTERLEAVED off/default so slow
-        # host-load drift across the (long) scenario biases neither
-        # side: the compared gap is well under this harness's run-to-
-        # run p99 spread, and a sequential block per mode would
-        # attribute whatever the box was doing meanwhile to one mode
-        off_p99s, def_p99s = [], []
-        for _ in range(3):
-            off_p99s.append(float(np.percentile(
-                traced_pass(conc, sample="0", slow_ms="0")[0], 99)) * 1e3)
-            def_p99s.append(float(np.percentile(
-                traced_pass(conc)[0], 99)) * 1e3)  # default knobs
-        off_p99, def_p99 = min(off_p99s), min(def_p99s)
-        flat_all, buf = traced_pass(conc, sample="1.0", slow_ms="0")
-        out["stage_breakdown"] = _stage_breakdown(
-            buf.traces(), flat_all)
-        out["tracing_overhead"] = {
-            "concurrency": conc,
-            "off_p99_ms": round(off_p99, 3),
-            "default_p99_ms": round(def_p99, 3),
-            "default_over_off_p99": (round(def_p99 / off_p99, 3)
-                                     if off_p99 else None),
-            "target": "< 1.05 (acceptance: default-sampling tracing "
-                      "regresses p99 < 5% vs traced-off)",
-        }
-
-        # ---- wire formats: JSON vs columnar binary, top concurrency --
-        # The batched-scoring workload the wire protocol exists for:
-        # each request carries wire_rows records. Both formats pre-pay
-        # the CLIENT cost (payload bytes are built before the timed
-        # loop, via serve/wire.py's reference encoder for binary); the
-        # timed loop is the server's side of the wire — parse/decode
-        # the body, featurize, score. The JSON side posts the decimal-
-        # string records the rest of this bench posts (the measured
-        # baseline this PR migrates from); the binary side carries the
-        # same values as f64 columns (zero-copy views server-side) —
-        # each format's idiomatic encoding of the same logical rows.
-        # Every request is traced so each format reports its own
-        # featurize share of p99. GATED: binary
-        # featurize_share_of_p99 < 0.15 (the ROADMAP host-featurize
-        # acceptance number) and binary QPS >= JSON QPS.
-        from shifu_tpu.serve import wire as _wire
-
-        wire_rows = spec["wire_rows"]
-
-        def wire_pass(fmt, conc):
-            _env.set_property("shifu.trace.sample", "1.0")
-            _env.set_property("shifu.trace.slowMs", "0")
-            reqtrace.reset()
-            reg5 = ModelRegistry(tmp)
-            sc = Scorer(reg5, AdmissionQueue(spec["queue_depth"]))
-            reg5.warm([wire_rows, conc * wire_rows])
-            per = spec["requests"] // conc
-            payloads = []
-            for ti in range(conc):
-                row = []
-                for k in range(per):
-                    base = (ti * per + k) * wire_rows
-                    if fmt == "binary":
-                        recs = [{c: 0.1 * ((base + r) % 7) - 0.3
-                                 for c in cols}
-                                for r in range(wire_rows)]
-                        row.append(_wire.encode_records(recs, cols))
-                    else:
-                        recs = [record(base + r)
-                                for r in range(wire_rows)]
-                        row.append(json.dumps({"records": recs}))
-                payloads.append(row)
-            lat5 = [[] for _ in range(conc)]
-
-            def run5(ti):
-                for k in range(per):
-                    body = payloads[ti][k]
-                    t0 = time.perf_counter()
-                    if fmt == "binary":
-                        batch = _wire.decode(body)
-                    else:
-                        batch = json.loads(body)["records"]
-                    sc.score_batch(batch)
-                    lat5[ti].append(time.perf_counter() - t0)
-
-            threads = [threading.Thread(target=run5, args=(ti,))
-                       for ti in range(conc)]
-            t0 = time.perf_counter()
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            wall = time.perf_counter() - t0
-            sc.close()
-            buf5 = reqtrace.buffer()
-            for key in ("shifu.trace.sample", "shifu.trace.slowMs"):
-                _env.set_property(key, "")
-            flat5 = np.asarray([v for ts in lat5 for v in ts])
-            share = _stage_breakdown(buf5.traces(), flat5)[
-                "featurize_share_of_p99"]
-            return {
-                "requests": int(flat5.size),
-                "rows_per_request": wire_rows,
-                "p50_ms": round(float(np.percentile(flat5, 50)) * 1e3, 3),
-                "p99_ms": round(float(np.percentile(flat5, 99)) * 1e3, 3),
-                "qps": round(flat5.size / wall, 1),
-                "records_per_s": round(flat5.size * wire_rows / wall, 1),
-                "featurize_share_of_p99": share,
-                "payload_bytes": len(payloads[0][0]),
-            }
-
-        # interleaved best-of-3 per format (the tracing-overhead
-        # policy): host-load drift across the scenario must bias
-        # neither side of the QPS gate
-        json_best, bin_best = None, None
-        for _ in range(3):
-            jp = wire_pass("json", conc)
-            bp = wire_pass("binary", conc)
-            if json_best is None or jp["qps"] > json_best["qps"]:
-                json_best = jp
-            if bin_best is None or bp["qps"] > bin_best["qps"]:
-                bin_best = bp
-        wire_gates = {
-            "binary_featurize_share_lt_0.15":
-                (bin_best["featurize_share_of_p99"] or 1.0) < 0.15,
-            "binary_qps_ge_json": bin_best["qps"] >= json_best["qps"],
-        }
-        out["wire_format"] = {
-            "concurrency": conc,
-            "json": json_best,
-            "binary": bin_best,
-            "binary_over_json_qps": (
-                round(bin_best["qps"] / json_best["qps"], 3)
-                if json_best["qps"] else None),
-            "gates": wire_gates,
-            "note": (f"closed loop of {wire_rows}-row requests, payload "
-                     "pre-encoded per format (JSON: the decimal-string "
-                     "records of the measured baseline; binary: the "
-                     "same values as f64 columns through serve/wire.py)"
-                     "; the timed loop decodes the body (json.loads vs "
-                     "wire.decode's zero-copy views) and scores through "
-                     "the full admission -> micro-batcher -> fused "
-                     "path. featurize_share_of_p99 comes from per-"
-                     "request traces (sample=1.0) and covers columnar "
-                     "conversion + the staging-buffer fill + the single "
-                     "per-batch device_put"),
-        }
-        if not all(wire_gates.values()):
-            raise RuntimeError(
-                f"serve_latency wire_format gates failed: {wire_gates} "
-                f"(json {json_best} vs binary {bin_best})")
-
-        # ---- fleet observability plane: snapshotter + collector ------
-        # The same closed loop with the PR-17 plane armed at
-        # production-shaped cadences — the on-disk snapshotter ticking
-        # the process registry to chunk files every 250 ms AND a
-        # polling collector running the full /fleet scrape path
-        # (fleet_view: collect -> merge -> slo + stage summaries, a
-        # fleet of one folding its own live snapshot) at `shifu top`'s
-        # default 2 s interval — vs fully off. Both are GIL-sharing
-        # Python work, so their p99 cost is their duty cycle: the
-        # cadences are the knobs' intended operating point, not a
-        # stress setting. Interleaved best-of-3 per mode (the
-        # tracing_overhead policy). GATED: armed p99 <= 1.05x off.
-        from shifu_tpu import obs
-        from shifu_tpu.obs import fleetview, timeseries
-        from shifu_tpu.obs.metrics import (Histogram, _parse_key,
-                                           quantile_from_counts)
-
-        obs_root = os.path.join(tmp, "fleet-obs")
-
-        def fleet_obs_pass(conc, armed):
-            reg6 = ModelRegistry(tmp)
-            sc = Scorer(reg6, AdmissionQueue(spec["queue_depth"]))
-            reg6.warm([1, conc])
-            stop = threading.Event()
-            snap = poller = None
-            if armed:
-                snap = timeseries.MetricsSnapshotter(
-                    obs_root, "bench-proc", obs.registry,
-                    snapshot_ms=250, chunk_windows=8, retain_chunks=4)
-                snap.start()
-
-                def poll():
-                    while not stop.wait(2.0):
-                        fleetview.fleet_view(
-                            obs_root, self_id="bench-proc",
-                            self_snapshot=lambda:
-                                obs.registry().snapshot())
-
-                poller = threading.Thread(target=poll, daemon=True)
-                poller.start()
-            # enough requests that the pass spans several snapshot
-            # ticks and at least one collect cycle (the cost being
-            # measured must actually run inside the measured window)
-            per = max(150, spec["requests"] // conc)
-            lat6 = [[] for _ in range(conc)]
-
-            def run6(ti):
-                for k in range(per):
-                    t0 = time.perf_counter()
-                    sc.score_batch([record(ti * per + k)])
-                    lat6[ti].append(time.perf_counter() - t0)
-
-            threads = [threading.Thread(target=run6, args=(ti,))
-                       for ti in range(conc)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            if armed:
-                stop.set()
-                poller.join(timeout=5)
-                snap.stop()
-            sc.close()
-            flat6 = np.asarray([v for ts in lat6 for v in ts])
-            return float(np.percentile(flat6, 99)) * 1e3
-
-        armed_p99s, off_obs_p99s = [], []
-        for _ in range(3):
-            off_obs_p99s.append(fleet_obs_pass(conc, armed=False))
-            armed_p99s.append(fleet_obs_pass(conc, armed=True))
-        off_obs_p99, armed_obs_p99 = min(off_obs_p99s), min(armed_p99s)
-
-        # fold the armed pass's on-disk evidence back through the single
-        # Histogram.merge primitive: every per-stage serve histogram of
-        # the final reconstructed window merges into one all-stages
-        # distribution — the report's proof the SIGKILL-durable chunks
-        # carry the whole latency shape, not just counters
-        disk = timeseries.last_snapshot(obs_root, "bench-proc")
-        folded = None
-        if disk is not None:
-            all_stages = None
-            for key, h in disk["metrics"].get("histograms", {}).items():
-                if _parse_key(key)[0] != "serve.stage_seconds":
-                    continue
-                other = Histogram.from_dict(h)
-                if all_stages is None:
-                    all_stages = Histogram(other.buckets)
-                all_stages.merge(other)
-            if all_stages is not None:
-                d = all_stages.as_dict()
-                folded = {
-                    "stage_observations": d["count"],
-                    "all_stages_p99_ms": round(
-                        (quantile_from_counts(all_stages.buckets,
-                                              d["counts"], 0.99)
-                         or 0.0) * 1e3, 3),
-                    "windows_on_disk": len(
-                        timeseries.read_windows(obs_root, "bench-proc")),
-                }
-        ratio = ((armed_obs_p99 / off_obs_p99) if off_obs_p99 else None)
-        out["fleet_obs"] = {
-            "concurrency": conc,
-            "off_p99_ms": round(off_obs_p99, 3),
-            "armed_p99_ms": round(armed_obs_p99, 3),
-            "armed_over_off_p99": (round(ratio, 3) if ratio is not None
-                                   else None),
-            "snapshot_ms": 250,
-            "collector_poll_ms": 2000,
-            "disk_fold": folded,
-            "target": "<= 1.05 (acceptance: snapshotter + fleet "
-                      "collector armed regress p99 <= 5% vs off)",
-        }
-        if ratio is not None and ratio > 1.05:
-            raise RuntimeError(
-                f"serve_latency fleet_obs gate failed: armed p99 "
-                f"{armed_obs_p99:.3f} ms > 1.05x off "
-                f"{off_obs_p99:.3f} ms")
-
-        out["registry"] = registry.snapshot()
-        out["profile"] = _profile_delta(p0, _profile_totals(), 1,
-                                        sweep_elapsed)
-        return out
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-
-
-def bench_continuous_loop():
-    """The closed loop's three economics (shifu_tpu/loop/,
-    docs/CONTINUOUS.md), each self-relative:
-
-      warm_start   epochs-to-target-validation-error on a covariate-
-                   shifted stream, cold init vs warm-started from the
-                   parent model (the `shifu retrain` NN seam) — the
-                   ratio is the epochs an incremental run saves;
-      gbt_append   appending K trees on new chunks (init_trees, the GBT
-                   retrain seam) vs retraining P+K from scratch;
-      serve_drift  closed-loop serve p99 with the fused drift fold on vs
-                   off — the fold rides the scoring program, so the
-                   target is p99_on/p99_off <= 1.05."""
-    import jax
-
-    from shifu_tpu.models.nn import flatten_params
-    from shifu_tpu.train.nn_trainer import NNTrainConfig, train_nn
-
-    spec = CONTINUOUS
-    rng = np.random.default_rng(7)
-    n, d = spec["n"], spec["d"]
-    w_true = np.linspace(-1.0, 1.0, d).astype(np.float64)
-
-    def stream(shift):
-        x = rng.normal(shift, 1.0, size=(n, d)).astype(np.float32)
-        logits = x.astype(np.float64) @ w_true
-        y = (logits + rng.normal(0.0, 0.5, size=n) > shift * w_true.sum()
-             ).astype(np.float32)
-        return x, y
-
-    ones = np.ones(n, dtype=np.float32)
-
-    def run_curve(x, y, init_flat=None, seed=1):
-        hist = []
-        cfg = NNTrainConfig(
-            hidden_nodes=list(spec["hidden"]), num_epochs=spec["epochs"],
-            learning_rate=0.1, seed=seed, checkpoint_every=1,
-            progress_cb=lambda it, tr, va: hist.append((it, va)))
-        res = train_nn(jax.device_put(x), jax.device_put(y), ones, cfg,
-                       init_flat=init_flat, fetch_params=init_flat is None)
-        return res, hist
-
-    # parent model on the training distribution, then the same shifted
-    # stream twice: cold init vs warm-started from the parent
-    xa, ya = stream(0.0)
-    xb, yb = stream(spec["shift"])
-    t0 = time.perf_counter()
-    parent, _ = run_curve(xa, ya, seed=1)
-    flat, _shapes = flatten_params(parent.params)
-    cold_res, cold_hist = run_curve(xb, yb, seed=2)
-    warm_res, warm_hist = run_curve(xb, yb, init_flat=flat, seed=2)
-    target = max(cold_res.valid_error, warm_res.valid_error) * 1.02
-
-    def epochs_to(hist):
-        for it, va in hist:
-            if va <= target:
-                return it
-        return spec["epochs"]
-
-    cold_e, warm_e = epochs_to(cold_hist), epochs_to(warm_hist)
-    warm_start = {
-        "target_valid_error": round(target, 6),
-        "cold_epochs_to_target": cold_e,
-        "warm_epochs_to_target": warm_e,
-        "cold_over_warm_epochs": round(cold_e / max(warm_e, 1), 3),
-        "cold_first_epoch_valid": round(cold_hist[0][1], 6),
-        "warm_first_epoch_valid": round(warm_hist[0][1], 6),
-        "seconds": round(time.perf_counter() - t0, 2),
-    }
-
-    # ---- GBT: append K trees on new chunks vs retrain P+K from scratch
-    from shifu_tpu.train.tree_trainer import TreeTrainConfig, train_trees
-
-    g = spec["gbt"]
-    gn, gf, bins = g["n"], g["f"], g["bins"]
-    codes = rng.integers(0, bins, size=(gn, gf)).astype(np.int32)
-    y = (codes[:, 0].astype(np.int64) + codes[:, 1]
-         + rng.integers(0, 32, size=gn) > 48).astype(np.float32)
-    slots, is_cat = [bins + 1] * gf, [False] * gf
-    cols = [f"f{i}" for i in range(gf)]
-    codes_dev, y_dev = jax.device_put(codes), jax.device_put(y)
-    w_dev = jax.device_put(np.ones(gn, dtype=np.float32))
-    P, K = g["parent_trees"], g["append"]
-
-    def grow(tree_num, init=None):
-        cfg = TreeTrainConfig(algorithm="GBT", tree_num=tree_num,
-                              max_depth=g["depth"], learning_rate=0.1,
-                              valid_set_rate=0.1, seed=3)
-        t0 = time.perf_counter()
-        res = train_trees(codes_dev, y_dev, w_dev, slots, is_cat, cols,
-                          cfg, init_trees=init)
-        return res, time.perf_counter() - t0
-
-    parent_res, _parent_s = grow(P)
-    append_res, append_s = grow(P + K, init=list(parent_res.spec.trees))
-    scratch_res, scratch_s = grow(P + K)
-    gbt_append = {
-        "parent_trees": P,
-        "appended_trees": K,
-        "append_row_trees_per_s": round(gn * K / append_s, 1),
-        "append_seconds": round(append_s, 3),
-        "scratch_seconds": round(scratch_s, 3),
-        # appending K trees vs retraining P+K from scratch — the win an
-        # incremental `shifu retrain` buys on every drift cycle
-        "append_vs_scratch_speedup": round(scratch_s / append_s, 3),
-        "append_valid_error": round(append_res.valid_error, 6),
-        "scratch_valid_error": round(scratch_res.valid_error, 6),
-    }
-
-    # ---- serve p99: the fused drift fold on vs off on one model set
-    import shutil
-    import tempfile
-    import threading
-
-    from shifu_tpu.config.column_config import (
-        ColumnConfig,
-        ColumnType,
-    )
-    from shifu_tpu.loop.drift import DriftMonitor
-    from shifu_tpu.models.nn import NNModelSpec, init_params
-    from shifu_tpu.serve.queue import AdmissionQueue
-    from shifu_tpu.serve.registry import ModelRegistry
-    from shifu_tpu.serve.server import Scorer
-    from shifu_tpu.stats.binning import numeric_bin_index
-
-    sv = spec["serve"]
-    cols = [f"c{i}" for i in range(sv["cols"])]
-    tmp = tempfile.mkdtemp(prefix="bench-loop-")
-    try:
-        sizes = [sv["cols"]] + list(sv["hidden"]) + [1]
-        norm_specs = [{"name": c, "kind": "value", "outNames": [c],
-                       "mean": 0.0, "std": 1.0, "fill": 0.0,
-                       "zscore": True} for c in cols]
-        NNModelSpec(layer_sizes=sizes, activations=["tanh"],
-                    input_columns=cols, norm_specs=norm_specs,
-                    params=init_params(sizes, seed=0),
-                    ).save(os.path.join(tmp, "model0.nn"))
-        # drift baseline: training bins + counts per column, the exact
-        # ColumnConfig layout `stats` writes
-        train_vals = rng.normal(0.0, 1.0, size=(4096, sv["cols"]))
-        ccs = []
-        for i, c in enumerate(cols):
-            cc = ColumnConfig(column_num=i, column_name=c,
-                              column_type=ColumnType.N)
-            bounds = np.concatenate(
-                ([-np.inf], np.quantile(train_vals[:, i],
-                                        np.linspace(0.1, 0.9,
-                                                    sv["bins"] - 1))))
-            idx = numeric_bin_index(train_vals[:, i].astype(np.float32),
-                                    bounds.astype(np.float32))
-            counts = np.bincount(idx, minlength=len(bounds) + 1)
-            cc.column_binning.bin_boundary = [float(b) for b in bounds]
-            cc.column_binning.bin_count_pos = [int(v) for v in counts]
-            cc.column_binning.bin_count_neg = [0] * len(counts)
-            ccs.append(cc)
-
-        def record(i):
-            return {c: f"{0.2 * ((i + j) % 9) - 0.8:.4f}"
-                    for j, c in enumerate(cols)}
-
-        def p99(drift, reps=3):
-            import gc
-
-            registry = ModelRegistry(tmp, drift=drift)
-            scorer = Scorer(registry, AdmissionQueue(sv["queue_depth"]))
-            conc = sv["concurrency"]
-            # steady-state p99 is the measured quantity: pre-compile
-            # EVERY bucket the coalescer can produce (single-record
-            # requests batch to 1..concurrency rows), or the drift
-            # variant's larger compiles land in the timed region
-            registry.warm(range(1, conc + 1))
-            per_thread = sv["requests"] // conc
-            best99, best50 = [], []
-            for _rep in range(reps):
-                lat = [[] for _ in range(conc)]
-
-                def run(ti):
-                    for k in range(per_thread):
-                        t0 = time.perf_counter()
-                        scorer.score_batch([record(ti * per_thread + k)])
-                        lat[ti].append(time.perf_counter() - t0)
-
-                threads = [threading.Thread(target=run, args=(ti,))
-                           for ti in range(conc)]
-                # GC pauses land in p99 as multi-ms spikes that have
-                # nothing to do with the scoring path; collect before,
-                # hold during (best-of-reps strips what remains)
-                gc.collect()
-                gc.disable()
-                try:
-                    for t in threads:
-                        t.start()
-                    for t in threads:
-                        t.join()
-                finally:
-                    gc.enable()
-                flat = np.asarray([v for ts in lat for v in ts])
-                best99.append(float(np.percentile(flat, 99)) * 1e3)
-                best50.append(float(np.percentile(flat, 50)) * 1e3)
-            scorer.close()
-            return round(min(best99), 3), round(min(best50), 3)
-
-        off_p99, off_p50 = p99(None)
-        mon = DriftMonitor(ccs, threshold=0.2, min_rows=64)
-        on_p99, on_p50 = p99(mon)
-        psis = mon.psi_by_column()
-        serve_drift = {
-            "p50_ms_off": off_p50, "p50_ms_on": on_p50,
-            "p99_ms_off": off_p99, "p99_ms_on": on_p99,
-            # the acceptance target: the fused fold must cost <= 5% p99
-            "p99_on_over_off": round(on_p99 / off_p99, 4),
-            "drift_rows_folded": int(mon._rows),
-            "drift_columns": len(psis),
-            "drift_max_psi": round(max(psis.values()), 4) if psis else 0.0,
-        }
-        # warm() scores a few dummy rows through the fold too; the gate
-        # is that every real request's row was folded
-        assert mon._rows >= sv["requests"], mon._rows
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-
-    return {"warm_start": warm_start, "gbt_append": gbt_append,
-            "serve_drift": serve_drift}
-
-
-def _with_obs_metrics(fn, scenario="scenario", transfer_clean=False):
-    """Run one scenario inside a fresh obs scope and embed the registry
-    snapshot (compile counts, d2h sync counts, stage seconds, ...) in its
-    result — so BENCH_*.json trajectories can EXPLAIN a regression (e.g.
-    "jax.compiles doubled") instead of only reporting it.
-
-    Every scenario also runs under the runtime sanitizer harness
-    (analysis/sanitize.py): the recompile watchdog always, and — for
-    scenarios whose data is pre-placed in HBM (`transfer_clean`) — the
-    transfer guard, so an implicit host↔device transfer sneaking into a
-    steady-state hot path shows up as a verdict trip in BENCH_*.json.
-    A trip re-runs the scenario unguarded so timings still land; the
-    streamed scenarios keep the guard off (host→device streaming IS
-    their measured quantity)."""
-    from shifu_tpu import obs
-    from shifu_tpu.analysis import sanitize
-    from shifu_tpu.utils import environment
-
-    obs.install_jax_probes()
-    obs.reset()
-    modes = ["recompile"] + (["transfer"] if transfer_clean else [])
-    # benches compile warmup + on/off modes in one scope; default budget
-    # is therefore looser than the per-step one (still overridable)
-    san = sanitize.Sanitizer(
-        modes, budget=environment.get_int(
-            "shifu.sanitize.recompileBudget", 512))
-    try:
-        with sanitize.activate(san), san.armed(scenario):
-            res = fn()
-        verdict = san.verdict()
-    except Exception:
-        if not san.transfer_trips:
-            raise
-        # guard trip: the verdict records it; re-run WITHOUT the
-        # transfer guard so the bench still reports timings for the
-        # (now known-dirty) path. Fresh obs scope so the embedded
-        # metrics describe only the rerun, not the aborted first pass;
-        # the recompile watchdog stays armed and its rerun breaches
-        # merge into the reported verdict.
-        obs.reset()
-        rerun_san = sanitize.Sanitizer(
-            [m for m in san.modes if m != "transfer"], budget=san.budget)
-        with sanitize.activate(rerun_san), rerun_san.armed(scenario):
-            res = fn()
-        verdict = san.verdict()
-        rv = rerun_san.verdict()
-        verdict["recompile"]["breaches"] += rv["recompile"]["breaches"]
-        verdict["recompile"]["breachedCompileSeconds"] += (
-            rv["recompile"]["breachedCompileSeconds"])
-        verdict["events"] += rv["events"]
-        verdict["clean"] = False
-        verdict["transfer"]["note"] = (
-            "guard tripped; scenario re-run unguarded for timing")
-    res["sanitizer"] = verdict
-    if not transfer_clean:
-        res["sanitizer"]["transfer"]["note"] = (
-            "guard not armed: host->device streaming is this scenario's "
-            "measured quantity")
-    snap = obs.registry().snapshot()
-    res["metrics"] = {
-        "counters": {k: round(v, 1)
-                     for k, v in snap.get("counters", {}).items()},
-        "timers": {k: {"seconds": round(t["seconds"], 4),
-                       "calls": t["calls"]}
-                   for k, t in snap.get("timers", {}).items()},
-    }
-    return res
-
-
-def main() -> None:
-    remeasure = "--remeasure-baseline" in sys.argv
-    base = load_or_measure_baseline(remeasure)
-    t_start = time.perf_counter()
-
-    # the kernel-shaping sweep's children train on the default backend,
-    # and a chip belongs to one process at a time: they run BEFORE this
-    # parent initializes jax (the first scenario below does). Their
-    # profile.annotate survives obs.reset (process-global), so the
-    # gbt/gbt_wide/rf snapshots below carry the chosen best shaping
-    tree_sweep = bench_tree_sweep()
-    small = _with_obs_metrics(
-        lambda: bench_nn(SMALL, mixed_precision=True, reps=3),
-        "small", transfer_clean=True)
-    dense = _with_obs_metrics(
-        lambda: bench_nn(DENSE, mixed_precision=True, reps=2),
-        "dense", transfer_clean=True)
-    gbt = _with_obs_metrics(lambda: bench_gbt(reps=3),
-                            "gbt", transfer_clean=True)
-    gbt_wide = _with_obs_metrics(lambda: bench_gbt_wide(reps=2),
-                                 "gbt_wide", transfer_clean=True)
-    rf = _with_obs_metrics(lambda: bench_rf(reps=2),
-                           "rf", transfer_clean=True)
-    wdl = _with_obs_metrics(lambda: bench_wdl(reps=2),
-                            "wdl", transfer_clean=True)
-    streamed = _with_obs_metrics(lambda: bench_streamed_nn(reps=1),
-                                 "streamed_nn")
-    streamed_stats = _with_obs_metrics(
-        lambda: bench_streamed_stats(reps=3), "streamed_stats")
-    # subprocess sweep: sanitizer/obs wrappers stay in the children
-    sharded_stats = bench_sharded_stats()
-    serve_fleet = bench_serve_fleet()
-    failover = _with_obs_metrics(bench_failover, "failover")
-    model_zoo = _with_obs_metrics(bench_model_zoo, "model_zoo")
-    serve_latency = _with_obs_metrics(
-        bench_serve_latency, "serve_latency", transfer_clean=True)
-    ro = serve_latency.get("race_overhead") or {}
-    if "verdict" in ro:
-        # the armed race pass's tracker delta lands in the scenario's
-        # sanitizer snapshot exactly like transfer trips / nan traps
-        serve_latency["sanitizer"]["race"] = {
-            "armed": True, **ro.pop("verdict")}
-    continuous_loop = _with_obs_metrics(
-        bench_continuous_loop, "continuous_loop")
-    # subprocess child (forced 8 devices): sanitizer stays in the child
-    coresident_loop = bench_coresident_loop()
-
-    peak, chip = chip_peak_tflops()
-    nw = base["n_reference_workers"]
-
-    def section(res, unit_key, base_key):
-        denom = base[base_key] * nw
-        out = {
-            unit_key: round(res[unit_key], 1),
-            "vs_baseline": round(res[unit_key] / denom, 4),
-            "vs_one_numpy_worker": round(res[unit_key] / base[base_key], 2),
-            "spread": res["spread"],
-            "profile": res.get("profile"),
-            "metrics": res.get("metrics"),
-            "sanitizer": res.get("sanitizer"),
-        }
-        if "subtraction_speedup" in res:  # GBT/RF: hist-subtraction ratio
-            out["subtraction_speedup"] = round(
-                res["subtraction_speedup"], 3)
-            out["hist_counters"] = res["hist_counters"]
-        return out
-
-    print(json.dumps({
-        "metric": "nn_train_row_epochs_per_s",
-        "value": round(small["row_epochs_per_s"], 1),
-        "unit": "row-epochs/s",
-        "vs_baseline": round(
-            small["row_epochs_per_s"]
-            / (base["small_row_epochs_per_s"] * nw), 4),
-        "spread": small["spread"],
-        "profile": small.get("profile"),
-        "metrics": small.get("metrics"),
-        "sanitizer": small.get("sanitizer"),
-        "baseline_pinned": True,
-        "chip": chip,
-        "dense": {
-            "row_epochs_per_s": round(dense["row_epochs_per_s"], 1),
-            # profiler-derived (XLA cost analysis over the timed reps);
-            # hand_tflops is the corrected closed-form cross-check
-            "achieved_tflops": round(dense["tflops"], 2),
-            "hand_tflops": round(dense["hand_tflops"], 2),
-            "mfu": (round(dense["tflops"] / peak, 4) if peak else None),
-            "peak_tflops_bf16": peak,
-            "vs_baseline": round(
-                dense["row_epochs_per_s"]
-                / (base["dense_row_epochs_per_s"] * nw), 4),
-            "spread": dense["spread"],
-            "profile": dense.get("profile"),
-            "metrics": dense.get("metrics"),
-            "sanitizer": dense.get("sanitizer"),
-        },
-        "tree_sweep": tree_sweep,
-        "gbt": section(gbt, "row_trees_per_s", "gbt_row_trees_per_s"),
-        "gbt_wide": section(gbt_wide, "row_trees_per_s",
-                            "gbt_wide_row_trees_per_s"),
-        "rf": section(rf, "row_trees_per_s", "rf_row_trees_per_s"),
-        "wdl": section(wdl, "row_epochs_per_s", "wdl_row_epochs_per_s"),
-        "streamed_nn": {
-            **section(streamed, "row_epochs_per_s",
-                      "streamed_row_epochs_per_s"),
-            "note": ("host->device streaming IS the measured quantity "
-                     "(same data in-memory: see headline metric)"),
-        },
-        "streamed_stats": {
-            "rows_per_s": round(streamed_stats["rows_per_s"], 1),
-            "serial_rows_per_s": round(
-                streamed_stats["serial_rows_per_s"], 1),
-            "prefetch_speedup": round(
-                streamed_stats["prefetch_speedup"], 3),
-            "checkpoint_overhead": round(
-                streamed_stats["checkpoint_overhead"], 3),
-            "ckpt_rows_per_s": round(
-                streamed_stats["ckpt_rows_per_s"], 1),
-            "spread": streamed_stats["spread"],
-            "profile": streamed_stats.get("profile"),
-            "metrics": streamed_stats.get("metrics"),
-            "sanitizer": streamed_stats.get("sanitizer"),
-            "note": ("two-pass streaming stats rows/s through the "
-                     "overlapped ingest pipeline; prefetch_speedup = "
-                     "serial wall-clock / prefetched wall-clock on the "
-                     "identical chunk stream (results bit-identical)"),
-        },
-        "sharded_stats": sharded_stats,
-        "model_zoo": model_zoo,
-        "serve_latency": {
-            **{k: v for k, v in serve_latency.items()
-               if k.startswith("concurrency_") or k == "registry"},
-            "batching": serve_latency.get("batching"),
-            "replica_sweep": serve_fleet,
-            "failover": failover,
-            "race_overhead": serve_latency.get("race_overhead"),
-            "stage_breakdown": serve_latency.get("stage_breakdown"),
-            "tracing_overhead": serve_latency.get("tracing_overhead"),
-            "wire_format": serve_latency.get("wire_format"),
-            "fleet_obs": serve_latency.get("fleet_obs"),
-            "profile": serve_latency.get("profile"),
-            "metrics": serve_latency.get("metrics"),
-            "sanitizer": serve_latency.get("sanitizer"),
-            "note": ("closed-loop single-record requests through "
-                     "admission -> micro-batcher -> fused raw->score jit; "
-                     "registry.warmBuckets is the steady-state compile "
-                     "bound (transfer guard armed on the scoring seam); "
-                     "batching = continuous vs barrier (gated: "
-                     "continuous beats barrier p50 at low concurrency "
-                     "where barrier structurally pays maxWaitMs, and "
-                     "stays within 1.10x of barrier p99 at top "
-                     "concurrency); "
-                     "replica_sweep = forced-host fleet scaling "
-                     "(gates in its section; each replica point carries "
-                     "its per-stage p50/p99 trace breakdown); "
-                     "race_overhead = p50 with -Dshifu.sanitize=race "
-                     "lock tracking off vs armed (off is a plain "
-                     "threading.Lock; armed recorded, not gated); "
-                     "stage_breakdown = per-request per-stage p50/p99 "
-                     "from full-sample request traces, with "
-                     "featurize_share_of_p99 the ROADMAP host-featurize "
-                     "tracked number; tracing_overhead = p99 at default "
-                     "trace sampling vs tracing off (target < 1.05); "
-                     "fleet_obs = p99 with the on-disk metrics "
-                     "snapshotter + polling fleet collector armed vs "
-                     "off (gated <= 1.05)"),
-        },
-        "continuous_loop": {
-            "warm_start": continuous_loop["warm_start"],
-            "gbt_append": continuous_loop["gbt_append"],
-            "serve_drift": continuous_loop["serve_drift"],
-            "profile": continuous_loop.get("profile"),
-            "metrics": continuous_loop.get("metrics"),
-            "sanitizer": continuous_loop.get("sanitizer"),
-            "note": ("closed-loop economics, each self-relative: "
-                     "cold_over_warm_epochs = epochs-to-target saved by "
-                     "`shifu retrain` warm start on a shifted stream; "
-                     "append_vs_scratch_speedup = GBT appending K trees "
-                     "vs retraining P+K; p99_on_over_off = serve p99 "
-                     "cost of the fused drift fold (target <= 1.05)"),
-        },
-        "coresident_loop": coresident_loop,
-        "bench_seconds": round(time.perf_counter() - t_start, 1),
-    }))
-
-
-if __name__ == "__main__":
-    # parent and every --*-child: compiled programs go where
-    # JAX_COMPILATION_CACHE_DIR says, else to <checkout>/.jax_cache
-    from shifu_tpu.utils.platform import place_compile_cache
-
-    place_compile_cache()
-    if "--sharded-stats-child" in sys.argv:
-        _sharded_stats_child()
-    elif "--tree-sweep-child" in sys.argv:
-        _tree_sweep_child()
-    elif "--serve-fleet-child" in sys.argv:
-        _serve_fleet_child()
-    elif "--coresident-loop-child" in sys.argv:
-        _coresident_loop_child()
-    else:
-        main()

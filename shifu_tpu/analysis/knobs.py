@@ -74,8 +74,6 @@ KNOBS: List[Knob] = [
        "\"true\"/\"1\" forces shard-streamed training"),
     _K("shifu.train.memoryBudgetMB", "int", "1024",
        "normalized matrix budget before training streams from shards"),
-    _K("shifu.train.histCacheBudgetMB", "int", "4096",
-       "leaf-wise tree growth: retained-histogram cache budget"),
     _K("shifu.gridsearch.threshold", "int", "30",
        "max grid points trained in-process before bagging kicks in"),
     _K("shifu.rebin.maxNumBin", "int", "stats.maxNumBin",

@@ -43,7 +43,7 @@ pipeline. Four opt-in modes, combined freely:
 
 Verdicts: ``Sanitizer.verdict()`` returns a ``shifu.sanitize/1`` dict —
 BasicProcessor.run() embeds it in the run-ledger manifest (success AND
-failure), bench.py embeds it per scenario. Trip/breach counts also land
+failure). Trip/breach counts also land
 in the metrics registry (``sanitizer.*``), so `shifu runs` output and
 Prometheus exports see them too.
 """

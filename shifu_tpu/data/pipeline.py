@@ -227,9 +227,9 @@ class HostPlan:
 
     Round-robin on the global chunk index: `host_of(ci) = ci % H`, so
     with H hosts over K chunk files every process prefetches and folds
-    at most ceil(K/H) of them — the work-division bound the
-    host_affinity bench gates. Like ShardPlan the assignment is a pure
-    function of (ci, H): every process derives the identical partition
+    at most ceil(K/H) of them — the work-division bound
+    tests/test_sharded_lifecycle.py::TestHostPlan holds. Like ShardPlan
+    the assignment is a pure function of (ci, H): every process derives the identical partition
     with zero coordination, keyed only by its own host index
     (-Dshifu.lifecycle.hostIndex, or jax.process_index() on a real pod;
     the PR-14 lease id names the process, the index orders it).
@@ -299,7 +299,8 @@ class ShardPlan:
 
     Round-robin on the global chunk index: `shard_of(ci) = ci % S`, so
     with S shards over K chunks every shard folds at most ceil(K/S)
-    chunks — the work-division bound the sharded_stats bench gates. The
+    chunks — the work-division bound
+    tests/test_sharded_lifecycle.py::TestShardPlan holds. The
     assignment is a pure function of (ci, S): every pass, every resume,
     and every host in a real multi-host run derives the identical plan
     with zero coordination, and a shard can prefetch exactly its own

@@ -20,8 +20,7 @@ block on the result; async seams record dispatch time and are flagged
 `synced: false`). `snapshot()` joins the counts with the chip peak table
 (obs/costmodel.py) into achieved FLOP/s, achieved bandwidth, arithmetic
 intensity, MFU and a roofline verdict; BasicProcessor.run() embeds it in
-every run-ledger manifest and bench.py derives every scenario's MFU from
-it.
+every run-ledger manifest.
 
 Fallbacks keep the seams safe: tracer arguments (a wrapped program used
 inside another traced program), un-lowerable callables, or any AOT
@@ -257,25 +256,11 @@ class ProgramProfiler:
                     # signature): what a dispatch must read from HBM
                     # regardless of how the backend accounts internal
                     # traffic — the metric that shows a once-
-                    # materialized operand (e.g. the [n, T] code
-                    # one-hot) leaving a program's argument list
+                    # materialized operand leaving a program's
+                    # argument list
                     st["argBytes"] = max(st["argBytes"], entry.arg_bytes)
 
     # ---- views ----
-    def totals(self) -> Dict[str, float]:
-        """Cheap aggregate (bench scenarios diff this around timed runs)."""
-        with self._lock:
-            progs = [dict(p) for p in self._programs.values()]
-        out = {"flops": 0.0, "bytesAccessed": 0.0, "dispatches": 0,
-               "deviceSeconds": 0.0, "compileSeconds": 0.0}
-        for p in progs:
-            out["flops"] += p["flops"]
-            out["bytesAccessed"] += p["bytesAccessed"]
-            out["dispatches"] += p["dispatches"]
-            out["deviceSeconds"] += p["deviceSeconds"]
-            out["compileSeconds"] += p["compileSeconds"]
-        return out
-
     def snapshot(self, peaks=None) -> dict:
         """The manifest `profile` section: per-program table + totals,
         joined with the chip peak envelope into roofline terms."""

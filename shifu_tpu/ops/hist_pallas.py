@@ -100,7 +100,7 @@ _LANE = 128  # TPU lane width: a chunk's total width is a multiple of it
 # -Dshifu.pallas.wmax) so kernel-tuning rounds can sweep shapings
 # without code edits — per process because the built kernels are cached
 # (_build_call lru, tree_trainer's program cache): set the knobs at
-# launch, one process per shaping, the way the bench sweep children do.
+# launch, one process per shaping.
 # The chosen values land in the profiler snapshot (obs.profile
 # annotations, process-global so a later obs scope still reports them)
 # so every manifest records which shaping produced its numbers.
@@ -111,7 +111,7 @@ _W_MAX = 1024
 # Mosaic's scoped-VMEM stack (16 MiB on a v5e) grows with W^2 and with L.
 # Compiled for a described v5e: at W = 1024 only L <= 2 fits (L = 8 asks
 # 27.5 MiB); at W = 512 every level the grower fuses (L <= 32, f32 and
-# bf16 planes) compiles on the gbt / rf / gbt_wide bench layouts. So
+# bf16 planes) compiles on tests/test_chip_compile.py's layouts. So
 # fused-scan chunking clamps to 512 columns even when
 # -Dshifu.pallas.wmax asks for wider (hist-only chunks honor the raw
 # knob); tests/test_chip_compile.py holds the rule to the compiler.

@@ -65,7 +65,7 @@ def every_chunks_setting() -> int:
 
 def ckpt_stream_enabled() -> bool:
     """shifu.ckpt.stream — master switch for mid-stream checkpoints
-    (default on; the bench measures the on/off wall-clock ratio)."""
+    (default on)."""
     return environment.get_bool("shifu.ckpt.stream", True) \
         and every_chunks_setting() > 0
 

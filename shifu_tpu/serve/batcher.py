@@ -14,8 +14,8 @@ the fused registry program and closes each batch by one of two policies
       it `maxWaitMs` hoping for company, so p99 under load stops paying
       the coalesce deadline: the previous dispatch's device time IS the
       coalescing window.
-  barrier — the pre-fleet policy, kept for comparison benches and
-      deployments that want a minimum coalesce window:
+  barrier — the pre-fleet policy, kept for deployments that want a
+      minimum coalesce window:
 
       * row cap       shifu.serve.maxBatchRows (default 1024)
       * wait deadline shifu.serve.maxWaitMs    (default 2.0 ms after the

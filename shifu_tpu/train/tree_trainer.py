@@ -14,12 +14,7 @@ per-feature slot layout:
                the node-batch size L is sized from MaxStatsMemoryMB over
                the true T). Built by ONE scatter-add over the [n, F] code
                matrix; row-sharded inputs all-reduce (psum) the histogram
-               when run on a mesh. On a single device, the code one-hot
-               ("M", [n, T] bf16 — 0/1 is exact in bf16) is HOISTED
-               ACROSS THE FOREST: it is node- and label-independent, so
-               one build serves every level of every tree and each
-               level's histogram is one blocked dot (gated by
-               _M_BUDGET_BYTES; falls back to the rebuild path).
+               when run on a mesh.
     split scan ordered prefix sums per (node, feature segment): numeric
                segments keep code order, categorical segments sort by label
                mean (lexsort within static segment boundaries); gain by
@@ -41,7 +36,7 @@ per-feature slot layout:
                is on. Memory-gated by
                MaxStatsMemoryMB (fallback = full rebuild, counted);
                `tree.hist.built/derived/fallback_rebuilds` counters land
-               in run ledgers and bench snapshots.
+               in run ledgers.
 
 GBT parity (dt/DTWorker.java:1470-1486): tree 0 weight 1.0, later trees
 weight=learningRate; per-tree labels are -loss gradient. RF: per-tree
@@ -241,9 +236,7 @@ def _make_comps_of(n_classes: int):
 
 def _onehot_cols(code_b, pieces, slots_np, clip_np, blk: int):
     """One chunk's code one-hots as a list of [blk, *] bool columns in
-    flat-slot order (shared by the per-level rebuild path and the
-    forest-hoisted M builder — any change to the clip/piece semantics
-    lands in both)."""
+    flat-slot order."""
     import jax.numpy as jnp
 
     cols = []
@@ -418,111 +411,6 @@ def _make_hist_fn(L: int, lay: FeatureLayout, allow_matmul: bool = True,
         return hist.reshape(C, L, T)
 
     return hist_matmul
-
-
-# hoisted code one-hot ("M"): the [n, T] one-hot of the flat bin codes is
-# NODE-INDEPENDENT — one build serves every level of every tree in the
-# forest. Stored bf16 (0/1 is exact) in row blocks so each level's
-# histogram is one blocked dot instead of a rebuild+materialize of M.
-_M_BLK = 8192
-# the hoisted-M path keeps A = [_M_BLK, C*L] f32 per scan step; beyond
-# this lhs width the rebuild path's budget-derived blocking is safer
-_M_CL_CAP = 1024
-
-
-def _m_budget_bytes() -> int:
-    """Hoist the forest one-hot only while it fits this budget
-    (-Dshifu.train.histCacheBudgetMB, default 4096 — the one memory knob
-    here that is NOT MaxStatsMemoryMB, because M is a per-RUN cache, not
-    a per-level working set)."""
-    from shifu_tpu.utils import environment
-
-    return environment.get_int("shifu.train.histCacheBudgetMB", 4096) << 20
-
-
-def _get_m_builder(lay: FeatureLayout):
-    key = ("mbuild", lay.key)
-    prog = _PROGRAMS.get(key)
-    if prog is None:
-        prog = profile.wrap("tree.m_builder", _make_m_builder(lay))
-        _PROGRAMS[key] = prog
-    return prog
-
-
-def _make_m_builder(lay: FeatureLayout):
-    """jit fn(codes [n, F] i32) -> M [nb, _M_BLK, T] bf16 (rows padded)."""
-    import jax
-    import jax.numpy as jnp
-
-    chunks = _t_chunks(lay)
-    slots_np = lay.slots
-    clip_np = lay.clip_max
-
-    def build(codes):
-        n, F = codes.shape
-        n_pad = -(-n // _M_BLK) * _M_BLK
-        codes_p = jnp.pad(codes, ((0, n_pad - n), (0, 0)))
-
-        def block(_, i):
-            code_b = jax.lax.dynamic_slice_in_dim(codes_p, i * _M_BLK,
-                                                  _M_BLK, 0)
-            cols = []
-            for pieces in chunks:
-                cols.extend(_onehot_cols(code_b, pieces, slots_np,
-                                         clip_np, _M_BLK))
-            M_b = (cols[0] if len(cols) == 1
-                   else jnp.concatenate(cols, axis=1))
-            return None, M_b.astype(jnp.bfloat16)
-
-        _, M = jax.lax.scan(block, None, jnp.arange(n_pad // _M_BLK))
-        return M  # [nb, _M_BLK, T]
-
-    return jax.jit(build)
-
-
-def _make_hist_m_fn(L: int, lay: FeatureLayout, n_classes: int = 0):
-    """Histogram from the hoisted M: fn(M, labels, weights, node, active)
-    -> [C, L, T]. Per block: A = comps ⊗ one-hot(node) in f32, one
-    dot_general against the bf16 M block (XLA upconverts the exact 0/1
-    values in-register, so counts/sums match the rebuild path bit-for-bit
-    in summation structure)."""
-    import jax
-    import jax.numpy as jnp
-
-    C = n_classes if n_classes >= 3 else 3
-    T = lay.T
-    comps_of = _make_comps_of(n_classes)
-
-    def hist_m(M, labels, weights, node_slot, active):
-        n = labels.shape[0]
-        w = jnp.where(active, weights, 0.0)
-        nl = jnp.where(active, jnp.clip(node_slot, 0, L - 1), 0)
-        comps = jnp.stack(comps_of(w, labels), 1)  # [n, C]
-        n_pad = M.shape[0] * _M_BLK
-        comps_p = jnp.pad(comps, ((0, n_pad - n), (0, 0)))
-        nl_p = jnp.pad(nl, (0, n_pad - n))
-
-        def block(hist, i):
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * _M_BLK,
-                                                        _M_BLK, 0)
-            comps_b = sl(comps_p)
-            if L == 1:
-                A = comps_b
-            else:
-                oh_node = (sl(nl_p)[:, None]
-                           == jnp.arange(L)[None, :]).astype(jnp.float32)
-                A = (comps_b[:, :, None] * oh_node[:, None, :]).reshape(
-                    _M_BLK, C * L)
-            contrib = jax.lax.dot_general(
-                A, M[i], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [C*L, T]
-            return hist + contrib, None
-
-        hist0 = jnp.zeros((C * L, T), jnp.float32)
-        hist, _ = jax.lax.scan(block, hist0, jnp.arange(M.shape[0]))
-        return hist.reshape(C, L, T)
-
-    return hist_m
 
 
 def _make_leaf_fn(L: int, n_classes: int = 0):
@@ -988,7 +876,7 @@ def _node_batch_size(T: int, max_stats_memory_mb: int,
 # histogram-subtraction recurrence; the same reduction-reuse DrJAX frames
 # for MapReduce-style aggregations). Every level >= 1 therefore builds
 # only the SMALLER child of each split — half the node-histograms per
-# level, and for the matmul/hoisted-M lowerings a half-width [C, L/2, T]
+# level, and for the matmul lowering a half-width [C, L/2, T]
 # contraction — and reconstructs the full level by one fused elementwise
 # derive. RF histograms under unit/integer sample weights are integer
 # sums in f32 (exact under any order, counts < 2^24), so subtraction is
@@ -1286,8 +1174,8 @@ def _low_precision(cfg: "TreeTrainConfig") -> bool:
 
 def _get_codes8_program(lay: FeatureLayout):
     """Cached jit: [n, F] i32 codes -> int8 low-bandwidth planes for the
-    kernel's narrow chunks (hoisted once per forest, like the M cache —
-    codes are node/label/tree-independent)."""
+    kernel's narrow chunks (hoisted once per forest: codes are
+    node/label/tree-independent)."""
     key = ("codes8", lay.key)
     prog = _PROGRAMS.get(key)
     if prog is None:
@@ -1316,8 +1204,8 @@ def _interleave_children(left_small, built, derived):
 
 def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                       min_inst: int, min_gain: float, n_classes: int = 0,
-                      mesh=None, with_m: bool = False,
-                      sub_levels: tuple = (), acc64: bool = False,
+                      mesh=None, sub_levels: tuple = (),
+                      acc64: bool = False,
                       lowp: bool = False):
     """ONE jit program for a whole level-wise tree, levels UNROLLED at
     their exact widths: level d builds a [C, 2^d, T] histogram (≈3.5x less
@@ -1353,7 +1241,7 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
     p_on, p_interp, p_fused = _pallas_state(mesh)
     lowp = bool(lowp and p_on)
     key = ("tree", D, lay.key, impurity, min_inst, float(min_gain),
-           n_classes, _mesh_key(mesh), with_m, sub_levels, acc64,
+           n_classes, _mesh_key(mesh), sub_levels, acc64,
            p_on, p_interp, p_fused, lowp)
     prog = _PROGRAMS.get(key)
     if prog is not None:
@@ -1368,8 +1256,6 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
     # win, so deeper levels run the hist-mode kernel + the XLA scan
     fuse_at = [p_fused and 2**d <= _FUSED_SCAN_L_CAP for d in range(D)]
     fused_fns = [None] * D
-    hist_fns = None
-    hist_m_fns = None
     if p_on:
         from shifu_tpu.ops.hist_pallas import (make_fused_level_fn,
                                                make_pallas_hist_fn)
@@ -1391,9 +1277,6 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
             if f is not None else None
             for f in pallas_fns
         ]
-    elif with_m:
-        hist_m_fns = [_make_hist_m_fn(2**d, lay, n_classes)
-                      for d in range(D)]
     else:
         hist_fns = [_make_hist_fn(2**d, lay, n_classes=n_classes)
                     for d in range(D)]
@@ -1422,7 +1305,7 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
     acc_dt = jnp.float64 if acc64 else jnp.float32
     derive = _get_derive_program()
 
-    def tree_body(codes, labels, weights, feat_ok_t, M=None, codes8=None):
+    def tree_body(codes, labels, weights, feat_ok_t, codes8=None):
         n = codes.shape[0]
         node = jnp.zeros(n, jnp.int32)
         active = jnp.ones(n, bool)
@@ -1432,12 +1315,8 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
 
         def call_hist(L, idx, node_arg, act_arg):
             with phase(L, "hist"):
-                if with_m:
-                    h = hist_m_fns[idx](M, labels, weights, node_arg,
-                                        act_arg)
-                else:
-                    h = hist_fns[idx](codes, labels, weights, node_arg,
-                                      act_arg, off_c, clip_c, seg_c, pos_c)
+                h = hist_fns[idx](codes, labels, weights, node_arg,
+                                  act_arg, off_c, clip_c, seg_c, pos_c)
             if on_mesh:
                 # the level's all-reduce under a scope of its own: it waits
                 # for the slowest chip, which the histogram does not
@@ -2290,7 +2169,6 @@ def train_trees(
             batch_cap = _node_batch_size(lay.T, cfg.max_stats_memory_mb,
                                          cfg.n_classes)
             fused = (not leaf_wise) and 2**cfg.max_depth <= batch_cap
-            M_forest = None
             codes8_forest = None
             pallas_fused = False
             if fused:
@@ -2300,21 +2178,6 @@ def train_trees(
 
                     replicate_fn = lambda a: replicate(a, mesh)  # noqa: E731
                 _p_on, _p_int, pallas_fused = _pallas_state(mesh)
-                # hoist the code one-hot across the WHOLE forest when it fits:
-                # node-independent, so one bf16 [n, T] build replaces a rebuild +
-                # HBM materialization per level of every tree. The Pallas fused
-                # kernel supersedes it — M is exactly the [n, T] HBM plane the
-                # kernel exists to not materialize.
-                C_hist = cfg.n_classes if cfg.n_classes >= 3 else 3
-                n_pad_m = -(-n // _M_BLK) * _M_BLK
-                use_m = (mesh is None and not pallas_fused
-                         and n_pad_m * lay.T * 2 <= _m_budget_bytes()
-                         # deepest hist level is 2^(D-1) nodes; cap the A width
-                         and C_hist * 2 ** max(cfg.max_depth - 1, 0) <= _M_CL_CAP
-                         # resume-stable: depends on cfg only, never on start_k,
-                         # so a checkpoint-resumed run picks the SAME lowering as
-                         # the uninterrupted one (bit-equal resume contract)
-                         and cfg.tree_num * cfg.max_depth >= 2)
                 sub_levels, acc64 = _sub_plan(cfg, batch_cap)
                 sub_counts = _plan_counts(sub_levels[:cfg.max_depth],
                                           cfg.hist_subtraction)
@@ -2323,12 +2186,10 @@ def train_trees(
                 tree_prog = _get_tree_program(
                     cfg.max_depth, lay, cfg.impurity,
                     cfg.min_instances_per_node, cfg.min_info_gain,
-                    n_classes=cfg.n_classes, mesh=mesh, with_m=use_m,
+                    n_classes=cfg.n_classes, mesh=mesh,
                     sub_levels=sub_levels, acc64=acc64,
                     lowp=_low_precision(cfg),
                 )
-                if use_m:
-                    M_forest = _get_m_builder(lay)(codes_j)
                 if pallas_fused:
                     # int8 code planes hoisted once per forest (codes are
                     # tree/level-independent): 4x less kernel code-read bandwidth
@@ -2382,12 +2243,6 @@ def train_trees(
                         feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
                     feat_oks[k] = feat_ok
 
-        # NOTE (round 5, measured): building all K RF trees as ONE program
-        # with fat [blk, K*C*L] x [blk, T] contractions was tried and is
-        # SLOWER than the sequential hoisted-M path (8.2x vs 13.4x one numpy
-        # worker on the rf bench) — the K-times-larger A/one-hot
-        # materialization traffic outweighs the better MXU shape. See git
-        # history for the implementation.
         for k in range(start_k, cfg.tree_num):
             with span("train.tree", call=call, k=k):
                 feat_ok = feat_oks[k]
@@ -2417,10 +2272,7 @@ def train_trees(
                         fot = jnp.asarray(np.asarray(feat_ok, bool)[lay.seg_of_t])
                         if replicate_fn is not None:
                             fot = replicate_fn(fot)
-                    if M_forest is not None:
-                        feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
-                            codes_j, labels_k, w_k, fot, M_forest)
-                    elif pallas_fused:
+                    if pallas_fused:
                         feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
                             codes_j, codes8_forest, labels_k, w_k, fot)
                     else:

@@ -42,7 +42,7 @@ def checkout_cache_dir() -> str:
 
 def place_compile_cache() -> Optional[str]:
     """Turn on jax's persistent compilation cache for a process entry
-    point (bin/shifu, bench.py and its children — never at import of
+    point (bin/shifu, benchmarks/run.py — never at import of
     shifu_tpu, so tests keep jax's default of no cache).
 
     Where JAX_COMPILATION_CACHE_DIR is set, jax already reads it and this

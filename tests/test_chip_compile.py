@@ -20,8 +20,9 @@ import jax.numpy as jnp  # noqa: E402
 from shifu_tpu.ops import hist_pallas as hp  # noqa: E402
 from shifu_tpu.train import tree_trainer as tt  # noqa: E402
 
-# the three bench layouts (bench.py GBT / _rf_slots / _gbt_wide_slots) and
-# the benchmark's GBT cells' (benchmarks/configs/higgs_gbt*.json)
+# 30 numeric columns; 20 numeric + 10 categorical of 65 slots; 180 numeric
+# + 19 categorical of 65 slots + one of 2,001; and the benchmark's GBT
+# cells' layout (benchmarks/configs/higgs_gbt*.json)
 LAYOUTS = {
     "gbt": ([33] * 30, [False] * 30),
     "rf": ([33] * 20 + [65] * 10, [False] * 20 + [True] * 10),
@@ -184,7 +185,7 @@ def test_nn_train_step_compiles_at_small_width(one_chip):
     from shifu_tpu.models.nn import flatten_params, init_params
     from shifu_tpu.train import nn_trainer as nt
 
-    rows, d = 1_000_000, 30  # bench.py SMALL: 30 -> [50] -> 1
+    rows, d = 1_000_000, 30  # 1,000,000 x 30 numeric, 30 -> [50] -> 1
     cfg = nt.NNTrainConfig(hidden_nodes=[50], activations=["tanh"],
                            mixed_precision=True)
     flat0, shapes = flatten_params(

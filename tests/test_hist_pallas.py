@@ -502,27 +502,3 @@ def test_shaping_knobs_and_profiler_annotation():
         environment.set_property("shifu.pallas.blk", "")
         environment.set_property("shifu.pallas.wmax", "")
     assert blk_setting() == 512 and wmax_setting() == 1024
-
-
-def test_bench_baseline_guards(tmp_path, monkeypatch):
-    """bench.py refuses to silently clobber the calibrated pinned baseline
-    and rejects config drift (review findings, round 5)."""
-    import json
-    import sys
-
-    import bench
-
-    fake = tmp_path / "BASELINE_MEASURED.json"
-    monkeypatch.setattr(bench, "BASELINE_FILE", str(fake))
-    # calibrated file: remeasure refuses without --force-remeasure
-    json.dump({"calibrated": True, "configs": {}}, open(fake, "w"))
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--remeasure-baseline"])
-    with pytest.raises(SystemExit, match="calibrated"):
-        bench.load_or_measure_baseline(remeasure=True)
-    # config drift: plain load errors with guidance
-    with pytest.raises(SystemExit, match="different bench configs"):
-        bench.load_or_measure_baseline()
-    # missing file: clear instruction
-    fake.unlink()
-    with pytest.raises(SystemExit, match="must be checked in"):
-        bench.load_or_measure_baseline()

@@ -621,7 +621,7 @@ class TestDriftMonitor:
         reg.score_raw(_training_raw(model_set))
         assert mon.verdict()["rows"] > 0
 
-    def test_column_with_mismatched_counts_not_monitored(
+    def test_column_whose_counts_mismatch_is_not_monitored(
             self, column_configs):
         import copy
 

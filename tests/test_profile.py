@@ -2,7 +2,7 @@
 
 Covers the ISSUE-6 acceptance contract: costmodel units against a fake
 chip table (override knobs, roofline boundary), profiler-vs-hand-math
-FLOPs parity on the dense bench kernel (the real nn training program at a
+FLOPs parity on a dense MLP (the real nn training program at a
 reduced row count), the manifest `profile` section schema through
 BasicProcessor.run, regression gating (`shifu profile --diff` exits 1 on
 an injected 2x-FLOPs regression), `shifu runs --diff`, and a no-jax
@@ -193,20 +193,20 @@ class TestProgramProfiler:
 
 
 # ---------------------------------------------------------------------------
-# profiler vs hand math on the dense bench kernel
+# profiler vs hand math on a dense MLP
 # ---------------------------------------------------------------------------
 
 
 class TestDenseMfuParity:
     def test_xla_flops_match_corrected_hand_formula(self):
-        """The dense bench MFU now comes from the profiler; this pins it
-        against the corrected closed-form count (fwd 2/MAC + bwd 4/MAC
-        minus the never-computed first-layer input grad) on the REAL nn
-        training program at the dense layer shape, reduced row count."""
+        """The profiler's FLOP count against the benchmark's closed-form
+        count (fwd 2/MAC + bwd 4/MAC minus the never-computed first-layer
+        input grad) on the REAL nn training program at a dense layer
+        shape, 1024 -> 2048 x 2 -> 1, reduced row count."""
         import jax.numpy as jnp
 
         import jax
-        from bench import DENSE, _mlp_flops_per_row_epoch
+        from benchmarks.lib.work import mlp_flops_per_row_epoch
         from shifu_tpu import obs
         from shifu_tpu.obs import profile
         from shifu_tpu.train.nn_trainer import (
@@ -217,8 +217,8 @@ class TestDenseMfuParity:
         )
 
         obs.reset()
-        d, hidden = DENSE["d"], DENSE["hidden"]
-        n = 512  # flops scale linearly in rows; full n is bench-only
+        d, hidden = 1024, [2048, 2048]
+        n = 512  # flops scale linearly in rows
         cfg = NNTrainConfig(
             hidden_nodes=list(hidden), activations=["tanh"] * len(hidden),
             propagation="R", num_epochs=2, valid_set_rate=0.1, seed=1,
@@ -243,7 +243,7 @@ class TestDenseMfuParity:
                              sync=True)
         p = obs.profiler().snapshot()["programs"]["parity.dense"]
         assert p["costSource"] == "xla"
-        hand = _mlp_flops_per_row_epoch(d, list(hidden)) * n * epochs
+        hand = mlp_flops_per_row_epoch(d, hidden) * n * epochs
         assert p["flops"] == pytest.approx(hand, rel=0.05)
 
 
@@ -505,8 +505,8 @@ class TestCompileDurations:
 class TestDispatchSeamTracing:
     def test_bare_dispatch_installs_the_probes(self, tmp_path):
         """A process that goes straight to a dispatch seam (the trainers
-        called without BasicProcessor.run: bench.py, the benchmark's
-        drivers) still records which program compiled: the seam installs
+        called without BasicProcessor.run: the benchmark's drivers)
+        still records which program compiled: the seam installs
         the jax probes itself."""
         code = (
             "import jax, jax.numpy as jnp\n"

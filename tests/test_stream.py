@@ -263,7 +263,7 @@ class TestBoundedMemoryPipeline:
 
 
 class TestAdvisorFixes:
-    """Regression tests for the round-2 advisor findings (ADVICE.md)."""
+    """Regression tests for the round-2 advisor findings."""
 
     def _columnar(self, arrays: dict):
         from shifu_tpu.data.reader import ColumnarData

@@ -355,6 +355,39 @@ def test_tree_kernel_calls_is_silent_with_the_kernel_off(rows):
     assert tt._tree_kernel_calls(6, lay, (False,) + (True,) * 6) == 0
 
 
+@pytest.mark.parametrize("algorithm", ["GBT", "RF"])
+def test_off_the_chip_a_forest_grows_through_the_rebuild_path(
+        rows, monkeypatch, algorithm):
+    """One CPU device, kernel off: the whole-tree program builds every
+    level's histogram from the codes it is handed. No program holds a
+    forest-wide one-hot and the tree program takes no fifth argument."""
+    handed = []
+    real = tt._get_tree_program
+
+    def recording(*a, **kw):
+        prog = real(*a, **kw)
+
+        def call(*args):
+            handed.append(len(args))
+            return prog(*args)
+        return call
+
+    monkeypatch.setattr(tt, "_get_tree_program", recording)
+    codes, _x, y, w = rows
+    cfg = tt.TreeTrainConfig(algorithm=algorithm, tree_num=4, max_depth=4,
+                             valid_set_rate=0.2, seed=3)
+    obs.reset()
+    res = tt.train_trees(codes, y, w, [SLOTS] * F, [False] * F,
+                         ["f%d" % i for i in range(F)], cfg)
+    assert len(res.spec.trees) == 4
+    assert handed == [4] * 4  # codes, labels, weights, fot
+    # the grower and the error pass, and no program that builds anything
+    # once a forest
+    assert set(obs.profiler().snapshot()["programs"]) == {
+        "tree.whole_tree", "tree.errors"}
+    assert not [k for k in tt._PROGRAMS if k[0] == "mbuild"]
+
+
 def test_hist_program_counts_its_kernel_calls_at_each_dispatch(pallas_on):
     """The host-driven growers dispatch one hist program a level or a
     batch: each dispatch is the layout's hist-mode chunks."""

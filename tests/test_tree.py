@@ -256,37 +256,3 @@ class TestMeshParallelTrees:
                 np.testing.assert_allclose(t1.leaf_value, t8.leaf_value,
                                            atol=1e-4)
             assert abs(r1.valid_error - r8.valid_error) < 1e-4, alg
-
-
-def test_hoisted_m_matches_rebuild_path():
-    """The forest-hoisted code one-hot (bf16 M, one build per run) must
-    produce the same forest as the per-level rebuild path — counts are
-    exact either way; -Dshifu.train.histCacheBudgetMB=0 disables the
-    hoist."""
-    import numpy as np
-
-    from shifu_tpu.train.tree_trainer import TreeTrainConfig, train_trees
-    from shifu_tpu.utils import environment
-
-    rng = np.random.default_rng(9)
-    n, f, bins = 1500, 6, 8
-    codes = rng.integers(0, bins, size=(n, f)).astype(np.int32)
-    y = ((codes[:, 0] >= 4) | (codes[:, 1] <= 2)).astype(np.float32)
-    w = np.ones(n, np.float32)
-    cols = [f"c{i}" for i in range(f)]
-    cfg = TreeTrainConfig(algorithm="GBT", tree_num=4, max_depth=4,
-                          learning_rate=0.3, valid_set_rate=0.15, seed=6,
-                          min_instances_per_node=2)
-    hoisted = train_trees(codes, y, w, [bins] * f, [False] * f, cols, cfg)
-    environment.set_property("shifu.train.histCacheBudgetMB", "0")
-    try:
-        rebuilt = train_trees(codes, y, w, [bins] * f, [False] * f, cols,
-                              cfg)
-    finally:
-        environment.set_property("shifu.train.histCacheBudgetMB", "4096")
-    for th, tr in zip(hoisted.spec.trees, rebuilt.spec.trees):
-        np.testing.assert_array_equal(th.feature, tr.feature)
-        np.testing.assert_array_equal(th.left_mask, tr.left_mask)
-        np.testing.assert_allclose(th.leaf_value, tr.leaf_value, atol=1e-4)
-    assert hoisted.valid_error == pytest.approx(rebuilt.valid_error,
-                                                abs=1e-5)
