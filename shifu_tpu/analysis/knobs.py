@@ -83,7 +83,9 @@ KNOBS: List[Knob] = [
        "fused tree histogram kernel: auto (TPU on / CPU off) | on "
        "(forced; interpret mode off-TPU) | off (XLA lowering)"),
     _K("shifu.pallas.blk", "int", "512",
-       "pallas histogram kernel rows per grid step (ops/hist_pallas.py)"),
+       "pallas histogram kernel rows per grid step (ops/hist_pallas.py); "
+       "rounded up to whole 128-row lanes once the rows need a second "
+       "step"),
     _K("shifu.pallas.wmax", "int", "1024",
        "pallas histogram kernel max padded one-hot columns per VMEM "
        "chunk (fused-scan chunks clamp to 512)"),

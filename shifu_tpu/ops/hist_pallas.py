@@ -4,7 +4,7 @@ accumulate → split gain scan, with low-precision planes.
 The tree builder's hot op (dt/DTWorker.java:851 featureUpdate, fused by
 SURVEY §7.5 into "the histogram kernel") is
 
-    hist[c, l, t] = Σ_i comps[i, c] · (node[i] == l) · (code_t[i] == t)
+    hist[c, l, t] = Σ_i comps[c, i] · (node[i] == l) · (code_t[i] == t)
 
 followed immediately by the split gain scan over the [C, L, T] result.
 The XLA lowering in tree_trainer materializes the [blk, T] (or, hoisted,
@@ -15,19 +15,37 @@ and the scan. This kernel keeps BOTH in VMEM:
     grid (row blocks)  — per-chunk VMEM-resident [L, W] accumulator per
                          component, revisited across the grid (init at
                          block 0, += afterwards)
-    per block          — the chunk's code one-hot M is built by ONE
-                         broadcast-compare over a DENSELY PACKED column
-                         layout (below); a dot per component plane
-                         contracts the row axis on the MXU
+    per block          — the chunk's code one-hot M [blk, W] is built by
+                         ONE broadcast-compare over a DENSELY PACKED
+                         column layout (below); the component planes
+                         times the node one-hot, [C x L, blk] with the
+                         ROWS ALONG THE LANES (below), contract the row
+                         axis with M on the MXU
     last block         — the split scan runs in-kernel on the resident
                          planes (pairwise-rank formulation, below) and
                          emits per-column gain/rank/left-count planes,
                          so the histogram never has to be re-read from
                          HBM by a second scan dispatch
 
+Operands, all zero-padded to whole blocks of rows. The codes come as
+they lie, `[n_pad, nf]` int8 or int32, a row to a sublane. The two
+per-row operands the wrapper makes every level come with the ROWS ALONG
+THE LANES: component planes `[C, n_pad]` (bf16 or f32) and node ids
+`[1, n_pad]` (int32), in `(C, blk)` / `(1, blk)` blocks. As `[n, C]`
+and `[n, 1]` they would put 3 or 1 values on the 128-lane axis: XLA
+writes, the DMA moves and the step's vregs hold 128 lanes a row for them
+(2.8 GB written a level for 22 MB of node ids at 5.5 M rows), and the
+`[blk, L]` LHS has to be turned for the MXU. This way the node one-hot
+`[L, blk]` is a sublane broadcast of the id row, and `A [.., blk] x
+M [blk, W]` is the MXU's own orientation. While C x L (L in whole
+sublane tiles) fits the MXU's 128 rows, the C components share
+ONE stacked LHS, so M is pushed as weights once a step and not once a
+component; wider levels stream enough rows a component to pay for their
+own push and keep a dot each (static shapes alone decide). PERF.md,
+sections 5 and 6, has what each costs on the chip.
+
 Three changes over the round-5 kernel (which was slower than the XLA
-lowering and shipped dark behind an env var; what this kernel costs on
-the chip is in PERF.md, sections 5 and 6):
+lowering and shipped dark behind an env var):
 
 1. DENSELY PACKED COLUMN LAYOUT, NO PER-FEATURE STORES. The old kernel
    wrote each feature's one-hot segment at its raw flat-T offset with
@@ -73,9 +91,9 @@ the chip is in PERF.md, sections 5 and 6):
    its own scan.
 
 Numerics: counts and integer-weight moments are exact under any
-summation order (< 2^24), so RF forests are BIT-equal with the kernel
-on vs off; GBT float planes differ only by summation association
-(tolerance-tested), with bf16 comps adding one rounding at plane build.
+summation order (< 2^24): RF histograms are BIT-equal kernel on vs off,
+forests too off the chip (on it the scan's MXU matvecs round: PERF.md,
+section 7); GBT float planes differ by association and one bf16 rounding.
 
 Mode selection is the cataloged knob `-Dshifu.pallas.mode`:
   auto  (default) kernel on TPU backends, XLA elsewhere
@@ -116,10 +134,14 @@ _W_MAX = 1024
 # -Dshifu.pallas.wmax asks for wider (hist-only chunks honor the raw
 # knob); tests/test_chip_compile.py holds the rule to the compiler.
 _SCAN_W_CAP = 512
+# rows of the MXU's systolic array (128 x 128 on a v5e): the most the
+# accumulate step's stacked LHS may hold (`group` in _build_call)
+_MXU_ROWS = 128
 
 
 def blk_setting() -> int:
-    """shifu.pallas.blk — rows per grid step (default 512)."""
+    """shifu.pallas.blk — rows per grid step (default 512; `_block_rows`
+    rounds it up to whole lanes once the rows need a second step)."""
     from shifu_tpu.utils import environment
 
     return max(8, environment.get_int("shifu.pallas.blk", _BLK))
@@ -293,7 +315,7 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
                 interpret: bool):
     """One chunk's pallas_call builder, cached per static configuration.
 
-    Returns call(codes_chunk [n, nf], comps [n, C], node [n, 1],
+    Returns call(codes_chunk [n, nf], comps [C, n], node [1, n],
     featok [1, W]) -> (C hist planes [L, W], + when scan_key:
     gain [L, W], rank [L, W], lcnt [L, W], tot0 [L, C]).
 
@@ -314,6 +336,13 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
     name = kernel_name(do_scan)
     comp_dt = jnp.bfloat16 if lowp else jnp.float32
     m_dt = comp_dt
+    # the accumulate step's LHS: all C components stacked, each on L
+    # rounded up to whole sublane tiles of the planes' dtype, while that
+    # fits the MXU's rows; past it a component streams enough rows of its
+    # own to pay for its push of M, and each keeps its dot
+    sub = 16 if lowp else 8
+    tiled = -(-L // sub) * sub
+    group, rows = (C, tiled) if C * tiled <= _MXU_ROWS else (1, L)
     if do_scan:
         impurity, min_inst, min_gain, n_classes = scan_key
         use_entropy = impurity == "entropy"
@@ -361,23 +390,31 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
         # tail columns carry pos -1: clipped codes are >= 0, so M is 0
         m_ref[...] = (cb == pos_ref[...].astype(jnp.float32)).astype(m_dt)
 
-        comps = comps_ref[...]  # [blk, C]
-        if L > 1:
-            oh_node = (node_ref[...] == jax.lax.broadcasted_iota(
-                jnp.int32, (blk, L), 1)).astype(comp_dt)
+        # rows along the lanes: the node one-hot is a sublane broadcast
+        # of the [1, blk] ids, and A [.., blk] meets M [blk, W] in the
+        # MXU's own orientation, with nothing to transpose
+        comps = comps_ref[...]  # [C, blk]
         M = m_ref[...]
-        for c in range(C):
-            A_c = (comps[:, c:c + 1] if L == 1
-                   else comps[:, c:c + 1] * oh_node)  # [blk, L]
-            contrib = jax.lax.dot_general(
-                A_c, M, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [L, W]
 
-            @pl.when(i == 0)
-            def _init(out_ref=hist_refs[c]):
+        @pl.when(i == 0)
+        def _init():
+            for out_ref in hist_refs:
                 out_ref[...] = jnp.zeros_like(out_ref)
 
-            hist_refs[c][...] += contrib
+        # the components of one group share a stacked LHS, so M is pushed
+        # to the MXU as weights once a group and not once a component;
+        # a component's rows start on a tile boundary, and those past L
+        # match no node id
+        oh_node = (node_ref[...] == jax.lax.broadcasted_iota(
+            jnp.int32, (rows, blk), 0)).astype(comp_dt)
+        for c0 in range(0, C, group):
+            A = jnp.concatenate([comps[c:c + 1, :] * oh_node
+                                 for c in range(c0, c0 + group)], axis=0)
+            contrib = jax.lax.dot_general(
+                A, M, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [group * rows, W]
+            for k in range(group):
+                hist_refs[c0 + k][...] += contrib[k * rows:k * rows + L, :]
 
         if not do_scan:
             return
@@ -518,22 +555,21 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
                 preferred_element_type=jnp.float32) for c in range(C)]
             tot0_ref[...] = jnp.concatenate(tot_cols, axis=1)  # [L, C]
 
-    def call(codes_chunk, comps, node2d, featok):
+    def call(codes_chunk, comps, node_row, featok):
         import jax.numpy as jnp
 
-        n = codes_chunk.shape[0]
-        grid = n // blk
+        grid = codes_chunk.shape[0] // blk
         code_dt = jnp.int8 if code_i8 else jnp.int32
         in_specs = [
             pl.BlockSpec((blk, nf), lambda i: (i, 0)),
-            pl.BlockSpec((blk, C), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+            pl.BlockSpec((C, blk), lambda i: (0, i)),
+            pl.BlockSpec((1, blk), lambda i: (0, i)),
             pl.BlockSpec((1, W), lambda i: (0, 0)),
             pl.BlockSpec((1, W), lambda i: (0, 0)),
             pl.BlockSpec((1, W), lambda i: (0, 0)),
             pl.BlockSpec((1, W), lambda i: (0, 0)),
         ]
-        args = [codes_chunk.astype(code_dt), comps, node2d,
+        args = [codes_chunk.astype(code_dt), comps, node_row,
                 featok.astype(jnp.float32),
                 jnp.asarray(pos_np), jnp.asarray(clip_np),
                 jnp.asarray(featrel_np)]
@@ -582,29 +618,44 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
     return call
 
 
-def _comps_of(labels, weights, active, n_classes: int, dtype):
-    """[n, C] component planes (shared semantics with tree_trainer's
-    _make_comps_of): inactive rows zero out via the weight."""
+def _block_rows(n: int, blk: int) -> int:
+    """Rows a grid step takes of n: all of them where the `blk` knob
+    holds them (the block is then the whole array, whatever n is), else
+    the knob in whole lanes: a (1, blk) block of the row operands ends on
+    a 128-lane boundary."""
+    return n if n <= blk else _pad_lane(blk)
+
+
+def _pad_rows(arr, blk: int, axis: int):
+    """`arr` with zeros after its rows (along `axis`) up to whole blocks:
+    a padded row carries zero planes and adds nothing to node 0."""
     import jax.numpy as jnp
 
-    w = jnp.where(active, weights, 0.0)
-    if n_classes >= 3:
-        cls = jnp.clip(labels.astype(jnp.int32), 0, n_classes - 1)
-        cols = [w * (cls == c).astype(jnp.float32)
-                for c in range(n_classes)]
-    else:
-        cols = [w, w * labels, w * labels * labels]
-    return jnp.stack(cols, 1).astype(dtype)
+    pad = -arr.shape[axis] % blk
+    if not pad:
+        return arr
+    widths = [(0, 0)] * arr.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(arr, widths)
 
 
-def _pad_rows(arrs, blk):
+def _row_operands(labels, weights, active, node_slot, L: int,
+                  n_classes: int, dtype, blk: int):
+    """The kernel's two per-row operands, rows along the lanes and padded
+    to whole blocks: component planes [C, n_pad] (tree_trainer's, with
+    inactive rows zeroed out via the weight) and node ids [1, n_pad]. On
+    the sublanes ([n, C], [n, 1]) each row would take a 128-lane tile row
+    of its own, in HBM and in every block's DMA (PERF.md, section 6,
+    PR 31)."""
     import jax.numpy as jnp
 
-    n = arrs[0].shape[0]
-    n_pad = -(-n // blk) * blk
-    pad = n_pad - n
-    return [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-            for a in arrs]
+    from shifu_tpu.train.tree_trainer import _make_comps_of
+
+    planes = _make_comps_of(n_classes)(
+        jnp.where(active, weights, 0.0), labels)
+    comps = jnp.stack(planes, 0).astype(dtype)
+    nl = jnp.where(active, jnp.clip(node_slot, 0, L - 1), 0)
+    return _pad_rows(comps, blk, 1), _pad_rows(nl[None, :], blk, 1)
 
 
 def _annotate(lay, chunks, L, do_scan, lowp, i8_chunks, interpret):
@@ -612,6 +663,8 @@ def _annotate(lay, chunks, L, do_scan, lowp, i8_chunks, interpret):
 
     _profile.annotate(
         "ops.hist_pallas", kernel=kernel_name(do_scan), blk=blk_setting(),
+        # how the two per-row operands lie: rows along the lanes
+        rowLayout="planes[C,n] node[1,n]",
         wMax=wmax_setting(), chunks=len(chunks), L=int(L), T=int(lay.T),
         paddedT=int(sum(c.w for c in chunks)), fusedScan=bool(do_scan),
         bf16Planes=bool(lowp), int8Chunks=int(i8_chunks),
@@ -636,18 +689,16 @@ def make_pallas_hist_fn(L: int, lay, n_classes: int = 0,
     _annotate(lay, chunks, L, False, low_precision, 0, interpret)
 
     def hist_fn(codes, labels, weights, node_slot, active):
-        n, F = codes.shape
-        comps = _comps_of(labels, weights, active, n_classes, comp_dt)
-        nl = jnp.where(active, jnp.clip(node_slot, 0, L - 1), 0)
-        blk = min(blk_max, n)
-        codes_p, comps_p, nl_p = _pad_rows([codes, comps, nl], blk)
-        node2d = nl_p[:, None]
+        blk = _block_rows(codes.shape[0], blk_max)
+        comps_p, node_p = _row_operands(labels, weights, active, node_slot,
+                                        L, n_classes, comp_dt, blk)
+        codes_p = _pad_rows(codes, blk, 0)
         parts = []
         for ci, ch in enumerate(chunks):
             call = _build_call(lay.key, target, ci, L, C, blk, False,
                                low_precision, None, interpret)
             featok = jnp.ones((1, ch.w), jnp.float32)
-            outs = call(codes_p[:, ch.f_lo:ch.f_hi], comps_p, node2d,
+            outs = call(codes_p[:, ch.f_lo:ch.f_hi], comps_p, node_p,
                         featok)
             planes = jnp.stack(outs[:C])  # [C, L, W]
             parts.append(planes[:, :, jnp.asarray(ch.keep)])
@@ -730,16 +781,12 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
 
     def fused_fn(codes, codes8, labels, weights, node_slot, active,
                  feat_ok_t):
-        n, F = codes.shape
-        comps = _comps_of(labels, weights, active, n_classes, comp_dt)
-        nl = jnp.where(active, jnp.clip(node_slot, 0, L - 1), 0)
-        blk = min(blk_max, n)
-        pads = _pad_rows(
-            [codes, comps, nl] + ([codes8] if codes8 is not None else []),
-            blk)
-        codes_p, comps_p, nl_p = pads[:3]
-        codes8_p = pads[3] if codes8 is not None else None
-        node2d = nl_p[:, None]
+        blk = _block_rows(codes.shape[0], blk_max)
+        comps_p, node_p = _row_operands(labels, weights, active, node_slot,
+                                        L, n_classes, comp_dt, blk)
+        codes_p = _pad_rows(codes, blk, 0)
+        codes8_p = (_pad_rows(codes8, blk, 0) if codes8 is not None
+                    else None)
         fok_f = feat_ok_t.astype(jnp.float32)
 
         hist_parts, gain_parts, rank_parts, lcnt_parts = [], [], [], []
@@ -755,7 +802,7 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
             fok = (fok_f[jnp.asarray(t_clamp)]
                    * jnp.asarray((ch.scan_ok > 0)
                                  & (ch.pos >= 0), np.float32))[None, :]
-            outs = call(src[:, ch.f_lo:ch.f_hi], comps_p, node2d, fok)
+            outs = call(src[:, ch.f_lo:ch.f_hi], comps_p, node_p, fok)
             planes = jnp.stack(outs[:C])
             hist_parts.append(planes[:, :, jnp.asarray(ch.keep)])
             gain_parts.append(outs[C])

@@ -31,6 +31,7 @@ LAYOUTS = {
     "higgs": ([33] * 28, [False] * 28),
 }
 N_ROWS = 65_536
+CELL_ROWS = 5_500_000  # higgs_gbt.train_levelwise's, one chip
 L_MAX = tt._FUSED_SCAN_L_CAP  # the largest level the static rule fuses
 
 
@@ -61,11 +62,11 @@ def _shape(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def _row_args(one_chip, F):
+def _row_args(one_chip, F, rows=N_ROWS):
     s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
-    return (s((N_ROWS, F), jnp.int32), s((N_ROWS,), jnp.float32),
-            s((N_ROWS,), jnp.float32), s((N_ROWS,), jnp.int32),
-            s((N_ROWS,), jnp.bool_))
+    return (s((rows, F), jnp.int32), s((rows,), jnp.float32),
+            s((rows,), jnp.float32), s((rows,), jnp.int32),
+            s((rows,), jnp.bool_))
 
 
 def _compiled_kernels(compiled) -> int:
@@ -86,6 +87,17 @@ def test_hist_kernel_compiles(one_chip, layout, L, lowp):
     if layout == "higgs":
         assert [(ch.w, ch.f_hi - ch.f_lo) for ch in hp._chunks(lay)] == [
             (1024, 28)]
+
+
+def test_hist_kernel_compiles_at_rf_deepest_level(one_chip):
+    """RF's deepest level (depth 10: 512 nodes), NATIVE three-class
+    counts on f32 planes: the [512, blk] node one-hot at its largest,
+    past the stacked LHS (three dots) and past the fused scan."""
+    slots, is_cat = LAYOUTS["rf"]
+    lay = tt.make_layout(slots, is_cat)
+    fn = hp.make_pallas_hist_fn(512, lay, n_classes=3)
+    compiled = jax.jit(fn).lower(*_row_args(one_chip, len(slots))).compile()
+    assert _compiled_kernels(compiled) == len(hp._chunks(lay))
 
 
 def _distinct_kernels(lay):
@@ -120,8 +132,8 @@ def test_fused_kernels_compile_where_the_rule_admits(one_chip, layout,
         args.append((
             _shape(one_chip, (N_ROWS, nf),
                    jnp.int8 if narrow else jnp.int32),
-            _shape(one_chip, (N_ROWS, C), comp_dt),
-            _shape(one_chip, (N_ROWS, 1), jnp.int32),
+            _shape(one_chip, (C, N_ROWS), comp_dt),
+            _shape(one_chip, (1, N_ROWS), jnp.int32),
             _shape(one_chip, (1, w), jnp.float32)))
 
     def every_kind(*chunk_args):
@@ -148,6 +160,39 @@ def test_fused_level_entry_compiles(one_chip, layout):
         hp._chunks(lay, hp._SCAN_W_CAP))
     assert hp.wide_features(lay, hp._SCAN_W_CAP) == (
         [199] if layout == "gbt_wide" else [])
+
+
+# The kernel's two per-row operands ride with the rows along the lanes,
+# [C, n] planes and [1, n] node ids: a level's temporaries are then the
+# code operand's (128 B a row for each int8 chunk, 512 B for the int32
+# matrix) and little else. With the rows on the sublanes ([n, 3], [n, 1])
+# every row took a 128-lane tile row in each: 1,024 and 1,280 B a row
+# here (5.63 and 7.04 GB; described-chip compile, PR 31). At N_ROWS the
+# operands sit in VMEM and the reading is 0, so this one asks at the
+# cell's rows (shapes only: nothing is allocated). The hist-mode entry is
+# asked at the cell's rows in whole blocks, as each chip of the mesh
+# holds them: at 5,500,000 the wrapper's pad of the int32 codes stands
+# beside their row-major copy for a moment and that alone reads 1,024.
+@pytest.mark.parametrize("entry,L,rows,cap", [
+    ("fused", 1, CELL_ROWS, 320),
+    ("hist", L_MAX, -(-CELL_ROWS // 512) * 512, 560)])
+def test_level_temporaries_a_row_at_the_cells_rows(one_chip, entry, L, rows,
+                                                   cap):
+    slots, is_cat = LAYOUTS["higgs"]
+    lay = tt.make_layout(slots, is_cat)
+    codes, labels, weights, node, active = _row_args(
+        one_chip, len(slots), rows)
+    if entry == "fused":
+        fn = hp.make_fused_level_fn(L, lay, "variance", 5, 0.0,
+                                    low_precision=True)
+        args = (codes, _shape(one_chip, codes.shape, jnp.int8), labels,
+                weights, node, active, _shape(one_chip, (lay.T,), jnp.bool_))
+    else:
+        fn = hp.make_pallas_hist_fn(L, lay, low_precision=True)
+        args = (codes, labels, weights, node, active)
+    compiled = jax.jit(fn).lower(*args).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < cap * rows, temp / rows
 
 
 @pytest.mark.parametrize("meshed,want", [(False, 12), (True, 6)],

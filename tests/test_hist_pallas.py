@@ -18,6 +18,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from shifu_tpu.ops.hist_pallas import (  # noqa: E402
+    _block_rows,
     _chunks,
     make_codes8_fn,
     make_fused_level_fn,
@@ -134,6 +135,78 @@ def test_bf16_plane_parity_bounds():
 
 
 # ---------------------------------------------------------------------------
+# rows along the lanes: [C, n] planes and [1, n] node ids, at every level
+# width a grower builds
+# ---------------------------------------------------------------------------
+
+_LANES_SLOTS = [9] * 6 + [33, 65]
+_LANES_CAT = [False] * 6 + [True] * 2
+
+
+def _lanes_case(L, n_classes, n=1100, seed=17):
+    """Integer weights and integer labels: every plane value is a small
+    integer, exact in bf16 as in f32, so kernel and reference must agree
+    BIT for bit at either precision. n = 1,100 is 2 blocks of 512 and a
+    tail of 76 rows in a third: the 436 padded rows must add nothing.
+    Inactive rows carry node ids the level does not have."""
+    rng = np.random.default_rng(seed + L)
+    codes = np.stack([rng.integers(0, s, size=n) for s in _LANES_SLOTS],
+                     1).astype(np.int32)
+    w = rng.integers(1, 4, size=n).astype(np.float32)
+    y = (rng.integers(0, n_classes, size=n) if n_classes
+         else codes[:, 0] >= 4).astype(np.float32)
+    active = rng.random(n) < 0.9
+    node = np.where(active, rng.integers(0, L, size=n),
+                    rng.integers(-3, L + 3, size=n)).astype(np.int32)
+    return codes, y, w, node, active
+
+
+@pytest.mark.parametrize("n_classes", [0, 3], ids=["moments", "classes3"])
+@pytest.mark.parametrize("lowp", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("L", [1, 4, 32, 64, 512])
+def test_hist_kernel_parity_rows_along_lanes(L, lowp, n_classes):
+    lay = make_layout(_LANES_SLOTS, _LANES_CAT)
+    codes, y, w, node, active = _lanes_case(L, n_classes)
+    assert len(y) % 512 and _block_rows(len(y), 512) == 512
+    h_ref = _ref_hist(L, lay, codes, y, w, node, active,
+                      n_classes=n_classes)
+    h_pl = _pallas_hist(L, lay, codes, y, w, node, active,
+                        n_classes=n_classes, low_precision=lowp)
+    assert h_pl.shape == (3, L, lay.T)
+    np.testing.assert_array_equal(h_ref, h_pl)
+    # every active row lands once in feature 0's columns, no padded row does
+    weight = h_pl.sum(0) if n_classes else h_pl[0]
+    assert weight[:, :_LANES_SLOTS[0]].sum() == w[active].sum()
+
+
+@pytest.mark.parametrize("n_classes", [0, 3], ids=["moments", "classes3"])
+@pytest.mark.parametrize("lowp", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("L", [1, 4, 32])
+def test_fused_level_parity_rows_along_lanes(L, lowp, n_classes):
+    codes, y, w, _node, _active = _lanes_case(L, n_classes)
+    h_ref, hist, ref, out = _run_scan_pair(
+        _LANES_SLOTS, _LANES_CAT, codes, y, w, L=L,
+        impurity="gini" if n_classes else "variance", n_classes=n_classes,
+        low_precision=lowp)
+    np.testing.assert_array_equal(np.asarray(h_ref), np.asarray(hist))
+    # the class impurities divide and square in another order in the
+    # kernel: their gains agree to rounding, every choice exactly
+    _assert_scan_equal(ref, out, exact_floats=not n_classes)
+
+
+@pytest.mark.parametrize("n,blk,want", [
+    (90, 512, 90),      # all the rows in one step: the block is the array
+    (512, 512, 512),
+    (700, 128, 128),
+    (700, 100, 128),    # the knob in whole lanes once there is a second step
+    (700, 8, 128),
+    (5_500_000, 512, 512),
+])
+def test_block_rows_end_on_a_lane_boundary(n, blk, want):
+    assert _block_rows(n, blk) == want
+
+
+# ---------------------------------------------------------------------------
 # densely packed chunk layout
 # ---------------------------------------------------------------------------
 
@@ -204,7 +277,7 @@ def test_codes8_planes():
 
 
 def _run_scan_pair(slots, is_cat, codes, y, w, L, impurity, n_classes=0,
-                   min_inst=2, seed=7, wmax=None):
+                   min_inst=2, seed=7, wmax=None, low_precision=False):
     rng = np.random.default_rng(seed)
     n = len(y)
     lay = make_layout(slots, is_cat)
@@ -228,7 +301,7 @@ def _run_scan_pair(slots, is_cat, codes, y, w, L, impurity, n_classes=0,
                    int(lay.slots[0]))
         fused = jax.jit(make_fused_level_fn(
             L, lay, impurity, min_inst, 0.0, n_classes=n_classes,
-            interpret=True))
+            interpret=True, low_precision=low_precision))
         hist, out = fused(jnp.asarray(codes), None, jnp.asarray(y),
                           jnp.asarray(w), jnp.asarray(node),
                           jnp.asarray(active), fot)
@@ -496,6 +569,7 @@ def test_shaping_knobs_and_profiler_annotation():
         np.testing.assert_allclose(h_ref, h_pl, rtol=2e-5, atol=1e-4)
         ann = obs.profiler().snapshot()["annotations"]["ops.hist_pallas"]
         assert ann["blk"] == 128 and ann["wMax"] == 256
+        assert ann["rowLayout"] == "planes[C,n] node[1,n]"
         assert ann["chunks"] == len(_chunks(lay))
         assert ann["mode"] in ("auto", "on", "off")
     finally:
