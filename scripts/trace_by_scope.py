@@ -1,7 +1,8 @@
 """Device time by named scope, from a kept xplane file
 (`BENCH_KEEP_TRACE=<dir>`): each operation's own time inside the window of
 the `bench.call` spans, grouped by the scope its `tf_op` carries
-(`tree.L4/hist`, `tree.L4/psum`, `nn.bwd`, ...), one chip at a time.
+(`tree.L4/hist`, `tree.L4/psum`, `nn.bwd`, `transpose(jvp(wdl.embed))`, ...),
+one chip at a time.
 
     python scripts/trace_by_scope.py <file.xplane.pb> [--depth 2] [--top 12]
 
@@ -22,6 +23,11 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.lib import xplane  # noqa: E402
+
+# a part of an op's name stack that is one of the program's own scopes, bare
+# (`tree.L4`, `nn.bwd`, `wdl.update`) or as jax wraps it under a `grad`
+# (`jvp(wdl.embed)`, `transpose(jvp(wdl.embed))`)
+SCOPE = re.compile(r"(?:^|\()(?:tree|nn|wdl)\.")
 
 
 def _stat_value(plane, stat):
@@ -78,8 +84,8 @@ def by_scope(path: str, depth: int = 2, top: int = 12) -> list:
         for k, ns in own.items():
             parts = [p for p in meta[plane][k][1].split("/") if p]
             # jit(...)/ wrappers come first: start at the program's own scope
-            at = next((i for i, p in enumerate(parts)
-                       if p.startswith(("tree.", "nn."))), None)
+            at = next((i for i, p in enumerate(parts) if SCOPE.search(p)),
+                      None)
             key = "/".join(parts[at:at + depth]) if at is not None else "-"
             scopes[key] = scopes.get(key, 0.0) + ns * 1e-9
             m = re.match(r"tree\.L\d+/(\w+)", key)

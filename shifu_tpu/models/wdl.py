@@ -112,28 +112,38 @@ def unflatten_wdl(flat, template: WDLParams) -> WDLParams:
 
 def wdl_forward(p: WDLParams, dense, codes, activations: List[str],
                 logits_only: bool = False):
-    """dense [n, Dn], codes [n, Dc] -> [n] probability (or raw logit)."""
+    """dense [n, Dn], codes [n, Dc] -> [n] probability (or raw logit).
+
+    The three named scopes are op metadata only (the `tf_op` a profiler
+    trace shows): `wdl.embed` the per-field table lookups, `wdl.deep` the
+    tower, `wdl.wide` the per-field weight lookups and the dense dot. Under
+    `jax.grad` their halves read `jvp(wdl.embed)` and
+    `transpose(jvp(wdl.embed))`, the second being the scatter-adds."""
+    import jax
     import jax.numpy as jnp
 
     from shifu_tpu.models.nn import activation_fn
 
     pieces = [dense]
-    for f, table in enumerate(p.embed):
-        tb = jnp.asarray(table)  # params may be host numpy (loaded spec)
-        idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
-        pieces.append(tb[idx])
-    h = jnp.concatenate(pieces, axis=1)
-    n_hidden = len(p.dense_layers) - 1
-    for i in range(n_hidden):
-        act = activation_fn(activations[i % len(activations)] if activations else "relu")
-        h = act(h @ p.dense_layers[i]["W"] + p.dense_layers[i]["b"])
-    deep_logit = (h @ p.dense_layers[-1]["W"] + p.dense_layers[-1]["b"])[:, 0]
+    with jax.named_scope("wdl.embed"):
+        for f, table in enumerate(p.embed):
+            tb = jnp.asarray(table)  # params may be host numpy (loaded spec)
+            idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
+            pieces.append(tb[idx])
+    with jax.named_scope("wdl.deep"):
+        h = jnp.concatenate(pieces, axis=1)
+        n_hidden = len(p.dense_layers) - 1
+        for i in range(n_hidden):
+            act = activation_fn(activations[i % len(activations)] if activations else "relu")
+            h = act(h @ p.dense_layers[i]["W"] + p.dense_layers[i]["b"])
+        deep_logit = (h @ p.dense_layers[-1]["W"] + p.dense_layers[-1]["b"])[:, 0]
 
-    wide_logit = dense @ jnp.asarray(p.wide_dense)
-    for f, table in enumerate(p.wide):
-        tb = jnp.asarray(table)
-        idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
-        wide_logit = wide_logit + tb[idx]
+    with jax.named_scope("wdl.wide"):
+        wide_logit = dense @ jnp.asarray(p.wide_dense)
+        for f, table in enumerate(p.wide):
+            tb = jnp.asarray(table)
+            idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
+            wide_logit = wide_logit + tb[idx]
 
     logit = deep_logit + wide_logit + jnp.asarray(p.bias)[0]
     if logits_only:

@@ -419,6 +419,18 @@ def fn_memory(name: str, fn: Callable) -> List[Dict[str, float]]:
     return out
 
 
+def compiled_texts(name: str) -> List[str]:
+    """The compiled module's text of every executable the cost cache holds
+    for seam `name`, one a signature. A device trace names a fusion
+    (`%fusion.668`) and not what it holds; these are the very executables
+    the seam dispatches, so their text says. Empty with the profiler off,
+    after an eviction, or where the ahead-of-time compile fell back to
+    plain jit."""
+    with _cost_lock:
+        entries = [e for k, e in _cost_cache.items() if k[0] == name]
+    return [e.compiled.as_text() for e in entries if e.compiled is not None]
+
+
 def dispatch(name: str, fn: Callable, *args, sync: bool = True,
              static_argnums: Tuple[int, ...] = (),
              static_argnames: Tuple[str, ...] = (), **kwargs):

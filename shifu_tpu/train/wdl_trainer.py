@@ -10,6 +10,7 @@ log loss (the reference's WDL trains sigmoid + cross-entropy).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -24,7 +25,7 @@ from shifu_tpu.models.wdl import (
     wdl_forward,
     wdl_shapes,
 )
-from shifu_tpu.obs import profile
+from shifu_tpu.obs import profile, registry, span
 from shifu_tpu.resilience.checkpoint import atomic_save_npy
 from shifu_tpu.train.updaters import make_updater
 from shifu_tpu.utils.log import get_logger
@@ -123,25 +124,31 @@ def _get_program(cfg: WDLTrainConfig, template: WDLParams, mesh=None):
                 for e in p.embed
             ]
         prob = wdl_forward(p, dense, codes, cfg.activations)
-        eps = 1e-7
-        pc = jnp.clip(prob, eps, 1 - eps)
-        ll = -(t * jnp.log(pc) + (1 - t) * jnp.log(1 - pc))
-        return jnp.sum(sig * ll), prob
+        with jax.named_scope("wdl.loss"):
+            eps = 1e-7
+            pc = jnp.clip(prob, eps, 1 - eps)
+            ll = -(t * jnp.log(pc) + (1 - t) * jnp.log(1 - pc))
+            return jnp.sum(sig * ll), prob
 
     grad_fn = jax.grad(loss_fn, has_aux=True)
 
+    # Named scopes (`wdl.embed`, `wdl.wide`, `wdl.deep` in wdl_forward,
+    # `wdl.loss`, `wdl.update` here) are op metadata only and not in the
+    # compile cache's key.
     def one_iter(carry, dense, codes, t, sig_tr, sig_va, nts, lr):
         (flat, opt, it, best_val, best_flat, bad, halt, tr_e, va_e) = carry
         g_neg, prob = grad_fn(flat, dense, codes, t, sig_tr)
         g = -g_neg
-        sq = (t - prob) ** 2
-        tr = jnp.sum(sig_tr * sq) / jnp.maximum(jnp.sum(sig_tr), 1.0)
-        va = jnp.sum(sig_va * sq) / jnp.maximum(jnp.sum(sig_va), 1.0)
-        new_flat, new_opt = apply_update(opt, flat, g, lr, it + 1, nts)
-        improved = va < best_val
-        best_val2 = jnp.where(improved, va, best_val)
-        best_flat2 = jnp.where(improved, flat, best_flat)
-        bad2 = jnp.where(improved, 0, bad + 1)
+        with jax.named_scope("wdl.loss"):
+            sq = (t - prob) ** 2
+            tr = jnp.sum(sig_tr * sq) / jnp.maximum(jnp.sum(sig_tr), 1.0)
+            va = jnp.sum(sig_va * sq) / jnp.maximum(jnp.sum(sig_va), 1.0)
+        with jax.named_scope("wdl.update"):
+            new_flat, new_opt = apply_update(opt, flat, g, lr, it + 1, nts)
+            improved = va < best_val
+            best_val2 = jnp.where(improved, va, best_val)
+            best_flat2 = jnp.where(improved, flat, best_flat)
+            bad2 = jnp.where(improved, 0, bad + 1)
         halt2 = (bad2 >= window) if window > 0 else jnp.zeros((), bool)
         return (new_flat, new_opt, it + 1, best_val2, best_flat2, bad2,
                 halt2, tr, va)
@@ -187,102 +194,119 @@ def train_wdl(
     import jax
     import jax.numpy as jnp
 
+    # spans, counters and their names mirror train_nn's (one reader idiom
+    # serves both); the body stays in this frame, as there
     n = dense.shape[0]
-    template = init_wdl_params(
-        dense.shape[1], vocab_sizes, cfg.embed_dim, cfg.hidden, seed=cfg.seed
-    )
-    flat0 = flatten_wdl(template)
-    if init_flat is not None and init_flat.size == flat0.size:
-        flat0 = init_flat.astype(np.float32)
+    n_cat = len(vocab_sizes)
+    call = int(registry().counter("train.calls", engine="wdl").inc())
+    with span("train.wdl.call", call=call, rows=int(n), fields=n_cat,
+              epochs=int(cfg.num_epochs)):
+        with span("train.wdl.prologue", call=call):
+            template = init_wdl_params(
+                dense.shape[1], vocab_sizes, cfg.embed_dim, cfg.hidden,
+                seed=cfg.seed
+            )
+            flat0 = flatten_wdl(template)
+            if init_flat is not None and init_flat.size == flat0.size:
+                flat0 = init_flat.astype(np.float32)
 
-    d = dense.astype(np.float32) if not isinstance(dense, jax.Array) else dense
-    c = codes.astype(jnp.int32) if isinstance(codes, jax.Array) else codes.astype(np.int32)
-    t = tags.astype(np.float32) if not isinstance(tags, jax.Array) else tags
-    if mesh is None:
-        # deterministic draw rides the NN trainer's device cache — repeat
-        # runs transfer zero sampling bytes (remote TPU links)
-        from shifu_tpu.train.nn_trainer import _device_split_and_sample
+            d = dense.astype(np.float32) if not isinstance(dense, jax.Array) else dense
+            c = codes.astype(jnp.int32) if isinstance(codes, jax.Array) else codes.astype(np.int32)
+            t = tags.astype(np.float32) if not isinstance(tags, jax.Array) else tags
+            if mesh is None:
+                # deterministic draw rides the NN trainer's device cache —
+                # repeat runs transfer zero sampling bytes (remote TPU links)
+                from shifu_tpu.train.nn_trainer import _device_split_and_sample
 
-        sig_d, valid_d, nts = _device_split_and_sample(n, cfg)
-        w_d = (weights if isinstance(weights, jax.Array)
-               else jnp.asarray(np.asarray(weights, np.float32)))
-        sig_tr = sig_d * w_d
-        sig_va = valid_d * w_d
-    else:
-        from shifu_tpu.train.nn_trainer import split_and_sample
+                sig_d, valid_d, nts = _device_split_and_sample(n, cfg)
+                w_d = (weights if isinstance(weights, jax.Array)
+                       else jnp.asarray(np.asarray(weights, np.float32)))
+                sig_tr = sig_d * w_d
+                sig_va = valid_d * w_d
+            else:
+                from shifu_tpu.parallel.mesh import (
+                    pad_rows,
+                    row_shard_count,
+                    shard_rows,
+                )
+                from shifu_tpu.train.nn_trainer import split_and_sample
 
-        sig, valid = split_and_sample(n, cfg)
-        sig_tr = (sig * np.asarray(weights)).astype(np.float32)
-        sig_va = (valid.astype(np.float32)
-                  * np.asarray(weights)).astype(np.float32)
-        nts = float(max(sig.sum(), 1.0))
-    if mesh is not None:
-        from shifu_tpu.parallel.mesh import pad_rows, shard_rows
+                sig, valid = split_and_sample(n, cfg)
+                sig_tr = (sig * np.asarray(weights)).astype(np.float32)
+                sig_va = (valid.astype(np.float32)
+                          * np.asarray(weights)).astype(np.float32)
+                nts = float(max(sig.sum(), 1.0))
+                n_data = row_shard_count(mesh)
+                (d, c, t, sig_tr, sig_va), _ = pad_rows(
+                    [d, c, t, sig_tr, sig_va], n_data)
+                d = shard_rows(d, mesh)
+                c = shard_rows(c, mesh)
+                t = shard_rows(t, mesh)
+                sig_tr = shard_rows(sig_tr, mesh)
+                sig_va = shard_rows(sig_va, mesh)
 
-        from shifu_tpu.parallel.mesh import row_shard_count
+            program, init_state = _get_program(cfg, template, mesh=mesh)
+            opt0 = init_state(flat0.size)
+            flat_j = jnp.asarray(flat0)
+            if mesh is not None:
+                from shifu_tpu.parallel.mesh import replicate
 
-        n_data = row_shard_count(mesh)
-        (d, c, t, sig_tr, sig_va), _ = pad_rows([d, c, t, sig_tr, sig_va], n_data)
-        d = shard_rows(d, mesh)
-        c = shard_rows(c, mesh)
-        t = shard_rows(t, mesh)
-        sig_tr = shard_rows(sig_tr, mesh)
-        sig_va = shard_rows(sig_va, mesh)
+                flat_j = replicate(flat_j, mesh)
+                opt0 = replicate(opt0, mesh)
 
-    program, init_state = _get_program(cfg, template, mesh=mesh)
-    opt0 = init_state(flat0.size)
-    flat_j = jnp.asarray(flat0)
-    if mesh is not None:
-        from shifu_tpu.parallel.mesh import replicate
+            carry = (
+                flat_j, opt0, jnp.int32(0), jnp.float32(np.inf), flat_j,
+                jnp.int32(0), jnp.zeros((), bool), jnp.float32(0.0),
+                jnp.float32(0.0),
+            )
+            nts_j = jnp.float32(nts)
+            lr_j = jnp.float32(cfg.learning_rate)
 
-        flat_j = replicate(flat_j, mesh)
-        opt0 = replicate(opt0, mesh)
+        def run_until(cr, limit):
+            with span("train.wdl.program", call=call, limit=int(limit)):
+                return profile.dispatch(
+                    "wdl.train_program", program, cr, jnp.int32(limit), d, c,
+                    t, sig_tr, sig_va, nts_j, lr_j, sync=True)
 
-    carry = (
-        flat_j, opt0, jnp.int32(0), jnp.float32(np.inf), flat_j,
-        jnp.int32(0), jnp.zeros((), bool), jnp.float32(0.0), jnp.float32(0.0),
-    )
+        if cfg.checkpoint_every and cfg.checkpoint_every > 0:
+            it = 0
+            while it < cfg.num_epochs:
+                limit = min(it + cfg.checkpoint_every, cfg.num_epochs)
+                with profile.scaled(limit - it):
+                    carry = run_until(carry, limit)
+                it = int(carry[2])
+                if cfg.progress_cb:
+                    cfg.progress_cb(it, float(carry[7]), float(carry[8]))
+                if cfg.checkpoint_path:
+                    atomic_save_npy(cfg.checkpoint_path, np.asarray(carry[0]))
+                if bool(carry[6]) or it >= cfg.num_epochs:
+                    break
+            result = carry
+        else:
+            with profile.scaled(cfg.num_epochs):
+                result = run_until(carry, cfg.num_epochs)
+        (flat_f, _, it_f, best_val, best_flat, _, _, tr_e, va_e) = result
 
-    def run_until(cr, limit):
-        return profile.dispatch(
-            "wdl.train_program", program, cr, jnp.int32(limit), d, c, t,
-            sig_tr, sig_va, jnp.float32(nts),
-            jnp.float32(cfg.learning_rate), sync=True)
-
-    if cfg.checkpoint_every and cfg.checkpoint_every > 0:
-        it = 0
-        while it < cfg.num_epochs:
-            limit = min(it + cfg.checkpoint_every, cfg.num_epochs)
-            with profile.scaled(limit - it):
-                carry = run_until(carry, limit)
-            it = int(carry[2])
-            if cfg.progress_cb:
-                cfg.progress_cb(it, float(carry[7]), float(carry[8]))
-            if cfg.checkpoint_path:
-                atomic_save_npy(cfg.checkpoint_path, np.asarray(carry[0]))
-            if bool(carry[6]) or it >= cfg.num_epochs:
-                break
-        result = carry
-    else:
-        with profile.scaled(cfg.num_epochs):
-            result = run_until(carry, cfg.num_epochs)
-    (flat_f, _, it_f, best_val, best_flat, _, _, tr_e, va_e) = result
-    import math as _math
-
-    # one host round-trip for all scalars (serial casts pay an RTT each on
-    # remote TPU links)
-    it_h, bv, tr_h, va_h = map(
-        lambda a: a.item(), jax.device_get((it_f, best_val, tr_e, va_e)))
-    use_best = cfg.valid_set_rate > 0 and _math.isfinite(bv)
-    chosen = np.asarray(best_flat if use_best else flat_f)
-    params = _to_host_params(chosen, template)
-    final_valid = float(bv) if use_best else float(va_h)
-    log.info("wdl train done: %d iterations, train_err %.6f valid_err %.6f",
-             int(it_h), float(tr_h), final_valid)
-    return WDLTrainResult(
-        params=params, train_error=float(tr_h), valid_error=final_valid,
-        iterations=int(it_h),
-    )
+        # one host round-trip for all scalars (serial casts pay an RTT each
+        # on remote TPU links), then the chosen weights
+        with span("train.wdl.pull", call=call) as pulled:
+            scalars = jax.device_get((it_f, best_val, tr_e, va_e))
+            it_h, bv, tr_h, va_h = (a.item() for a in scalars)
+            use_best = cfg.valid_set_rate > 0 and math.isfinite(bv)
+            chosen = np.asarray(best_flat if use_best else flat_f)
+            pulled["bytes"] = sum(a.nbytes for a in scalars) + chosen.nbytes
+        params = _to_host_params(chosen, template)
+        final_valid = float(bv) if use_best else float(va_h)
+        reg = registry()
+        reg.gauge("train.train_error").set(float(tr_h))
+        reg.gauge("train.valid_error").set(final_valid)
+        reg.counter("train.iterations").inc(int(it_h))
+        log.info("wdl train done: %d iterations, train_err %.6f valid_err %.6f",
+                 int(it_h), float(tr_h), final_valid)
+        return WDLTrainResult(
+            params=params, train_error=float(tr_h), valid_error=final_valid,
+            iterations=int(it_h),
+        )
 
 
 def train_wdl_bagged(
@@ -435,15 +459,13 @@ def train_wdl_bagged(
             out = run_until(carry, base_cfg.num_epochs)
     (flat_f, _, it_f, best_val, best_flat, _, _, tr_e, va_e) = out
 
-    import math as _math
-
     results = []
     flat_f_np = np.asarray(flat_f)
     best_flat_np = np.asarray(best_flat)
     for i in range(M):
         bv = float(np.asarray(best_val)[i])
         use_best = (member_sigs is None and base_cfg.valid_set_rate > 0
-                    and _math.isfinite(bv))
+                    and math.isfinite(bv))
         chosen = best_flat_np[i] if use_best else flat_f_np[i]
         results.append(WDLTrainResult(
             params=_to_host_params(chosen, template),

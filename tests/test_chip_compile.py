@@ -250,6 +250,52 @@ def test_nn_train_step_compiles_at_small_width(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
+def test_wdl_program_compiles_at_the_criteo_cells_size(one_chip):
+    """The wide-and-deep program of `criteo_wdl.train_fullbatch`
+    (benchmarks/configs/criteo_wdl.json: 2,865,039 rows x 13 dense + 26
+    codes, tables of min(c, 10000) + 1 rows): it fits one v5e, its
+    temporaries stay under 2.2 KB a row (1,844 B in PR 32: 5.28 GB), and
+    XLA lowers the 52 lookups a row to 45 `gather` (the 7 wide tables of 3
+    to 27 rows become selects) and their transposes to 52 `scatter`. A PR
+    that changes how the lookups are lowered changes these counts, and
+    says so here."""
+    import json
+    import os
+    import re
+
+    from shifu_tpu.models.wdl import flatten_wdl, init_wdl_params
+    from shifu_tpu.train import wdl_trainer as wt
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "criteo_wdl.json")) as f:
+        c = json.load(f)
+    rows, vocab = c["rows"], c["vocab_sizes"]
+    assert (rows, sum(vocab)) == (2_865_039, 119_915)
+    tpl = init_wdl_params(c["dense_columns"], vocab, c["embed_outputs"],
+                          c["hidden_nodes"])
+    n_flat = flatten_wdl(tpl).size
+    assert n_flat == c["parameters"]
+    key_before = set(wt._PROGRAMS)
+    program, _init = wt._get_program(wt.WDLTrainConfig(), tpl)
+    for k in set(wt._PROGRAMS) - key_before:
+        del wt._PROGRAMS[k]
+    s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    flat, row = s((n_flat,), jnp.float32), s((rows,), jnp.float32)
+    f32, i32 = s((), jnp.float32), s((), jnp.int32)
+    carry = (flat, {"m": flat, "v": flat}, i32, f32, flat, i32,
+             s((), jnp.bool_), f32, f32)
+    compiled = program.lower(
+        carry, i32, s((rows, c["dense_columns"]), jnp.float32),
+        s((rows, len(vocab)), jnp.int32), row, row, row, f32, f32).compile()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < 2200 * rows, \
+        mem.temp_size_in_bytes / rows
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 * 2**30
+    text = compiled.as_text()
+    assert len(re.findall(r" gather\(", text)) == 45
+    assert len(re.findall(r" scatter\(", text)) == 52
+
+
 def test_levels_beyond_the_rule_go_to_the_hist_kernel(monkeypatch):
     """The static rule, as the grower applies it: levels with
     2**d <= _FUSED_SCAN_L_CAP build the fused kernel (chunked to

@@ -1,5 +1,5 @@
-"""The trainers' own measurement: the spans and counts `train_trees` and
-`train_nn` leave in the tracer's ring, and the names their compiled programs
+"""The trainers' own measurement: the spans and counts `train_trees`,
+`train_nn` and `train_wdl` leave in the tracer's ring, and the names their compiled programs
 carry (a named kernel, one scope a level and a phase).
 
 The span tables are the ones in docs/OBSERVABILITY.md ("Span tracing"); the
@@ -18,6 +18,7 @@ import jax.numpy as jnp  # noqa: E402
 from shifu_tpu import obs  # noqa: E402
 from shifu_tpu.train import nn_trainer as nt  # noqa: E402
 from shifu_tpu.train import tree_trainer as tt  # noqa: E402
+from shifu_tpu.train import wdl_trainer as wt  # noqa: E402
 from shifu_tpu.utils import environment  # noqa: E402
 
 N, F, SLOTS = 2000, 6, 9
@@ -191,6 +192,70 @@ def test_checkpointed_train_nn_has_one_program_span_a_segment(rows,
     _got, evs = _train_events()
     assert [e["args"]["limit"] for e in evs
             if e["name"] == "train.nn.program"] == [2, 3]
+
+
+WDL_VOCAB = [5, 9, 3]
+
+
+def _wdl(rows, **cfg):
+    codes, x, y, w = rows
+    conf = wt.WDLTrainConfig(hidden=[8], activations=["relu"], embed_dim=2,
+                             seed=1, **cfg)
+    cat = np.minimum(codes[:, :3], np.array(WDL_VOCAB) - 1)
+    return wt.train_wdl(x, cat, y, w, WDL_VOCAB, conf)
+
+
+def test_train_wdl_leaves_the_tables_spans(rows):
+    _wdl(rows, num_epochs=2)
+    obs.reset()
+    res = _wdl(rows, num_epochs=2)
+    _wdl(rows, num_epochs=3)
+    assert res.iterations == 2
+    got, evs = _train_events()
+    WCALL = "train.wdl.call"
+    one = [("train.wdl.prologue", WCALL, None),
+           ("train.wdl.program", WCALL, None),
+           ("train.wdl.pull", WCALL, None),
+           (WCALL, "", None)]
+    assert got == one + one
+    assert [e["args"]["call"] for e in evs] == [1] * 4 + [2] * 4
+    assert evs[3]["args"] == {"call": 1, "rows": N, "fields": 3, "epochs": 2}
+    assert evs[1]["args"]["limit"] == 2 and evs[5]["args"]["limit"] == 3
+    n_flat = sum(WDL_VOCAB) * 3 + F + (F + 3 * 2) * 8 + 8 + 8 + 1 + 1
+    # two f32, one i32 and one f32 scalar, and the chosen weights
+    assert evs[2]["args"]["bytes"] == 16 + 4 * n_flat
+    reg = obs.registry()
+    assert reg.counter("train.calls", engine="wdl").value == 2
+    assert reg.counter("train.calls", engine="nn").value == 0
+    assert reg.counter("train.iterations").value == 5
+
+
+@pytest.mark.parametrize("epochs", [1, 4])
+def test_train_wdl_counts_its_epochs_and_sets_the_errors(rows, epochs):
+    """As `train_nn` does: `train.iterations` counts the epochs run, the two
+    gauges hold what the result says; no counter is kept from a formula
+    (the lookups an epoch makes are read off the compiled program)."""
+    _wdl(rows, num_epochs=epochs)
+    obs.reset()
+    res = _wdl(rows, num_epochs=epochs)
+    reg = obs.registry()
+    assert reg.counter("train.iterations").value == epochs
+    assert reg.gauge("train.train_error").value == res.train_error
+    assert reg.gauge("train.valid_error").value == res.valid_error
+    _wdl(rows, num_epochs=epochs)
+    assert reg.counter("train.iterations").value == 2 * epochs
+    assert not [k for k in reg.snapshot()["counters"] if k.startswith("wdl.")]
+
+
+def test_checkpointed_train_wdl_has_one_program_span_a_segment(rows,
+                                                               tmp_path):
+    obs.reset()
+    _wdl(rows, num_epochs=3, checkpoint_every=2,
+         checkpoint_path=str(tmp_path / "ck.npy"))
+    _got, evs = _train_events()
+    assert [e["args"]["limit"] for e in evs
+            if e["name"] == "train.wdl.program"] == [2, 3]
+    assert obs.registry().counter("train.iterations").value == 3
 
 
 # ---- names inside the compiled programs ----
@@ -437,3 +502,45 @@ def test_nn_program_carries_its_scopes():
             if e.primitive.name == "dot_general"]
     assert sum("nn.bwd/jvp(nn.fwd)" in s for s in dots) == 2
     assert sum("transpose(jvp(nn.fwd))" in s for s in dots) >= 2
+
+
+def test_wdl_program_carries_its_scopes():
+    from shifu_tpu.models.wdl import flatten_wdl, init_wdl_params
+
+    cfg = wt.WDLTrainConfig(hidden=[7], activations=["relu"], embed_dim=2)
+    tpl = init_wdl_params(F, WDL_VOCAB, 2, [7])
+    key_before = set(wt._PROGRAMS)
+    program, init_state = wt._get_program(cfg, tpl)
+    for k in set(wt._PROGRAMS) - key_before:
+        del wt._PROGRAMS[k]
+    flat = jnp.asarray(flatten_wdl(tpl))
+    carry = (flat, init_state(flat.size), jnp.int32(0), jnp.float32(np.inf),
+             flat, jnp.int32(0), jnp.zeros((), dtype=bool), jnp.float32(0.0),
+             jnp.float32(0.0))
+    row = jnp.ones(64)
+    jp = jax.make_jaxpr(program)(carry, jnp.int32(2), jnp.ones((64, F)),
+                                 jnp.zeros((64, 3), jnp.int32), row, row,
+                                 row, jnp.float32(1.0), jnp.float32(0.005))
+    eqns = _eqns(jp.jaxpr, [])
+    stacks = {str(e.source_info.name_stack) for e in eqns}
+    for scope in ("jvp(wdl.embed)", "transpose(jvp(wdl.embed))",
+                  "jvp(wdl.wide)", "transpose(jvp(wdl.wide))",
+                  "jvp(wdl.deep)", "transpose(jvp(wdl.deep))",
+                  "wdl.loss", "wdl.update"):
+        assert any(scope in s for s in stacks), (scope, sorted(stacks))
+    # every lookup and every transpose of one sits under its own scope: one
+    # gather and one scatter-add a field, embedding and wide
+    by = lambda prim, scope: sum(  # noqa: E731
+        e.primitive.name == prim and scope in str(e.source_info.name_stack)
+        for e in eqns)
+    assert by("gather", "jvp(wdl.embed)") == 3
+    assert by("gather", "jvp(wdl.wide)") == 3
+    assert by("scatter-add", "transpose(jvp(wdl.embed))") == 3
+    assert by("scatter-add", "transpose(jvp(wdl.wide))") == 3
+    dots = [str(e.source_info.name_stack) for e in eqns
+            if e.primitive.name == "dot_general"]
+    # the tower's two matmuls forward, and a weight and an input gradient
+    # of each behind them
+    assert sum("jvp(wdl.deep)" in s and "transpose" not in s
+               for s in dots) == 2
+    assert sum("transpose(jvp(wdl.deep))" in s for s in dots) == 4
