@@ -24,6 +24,7 @@ from typing import List, Tuple
 
 from shifu_tpu.coresident.plan import StagePlan
 from shifu_tpu.models.nn import activation_fn
+from shifu_tpu.models.wdl import wdl_plane
 from shifu_tpu.train.nn_trainer import NNTrainConfig
 
 _PROGRAMS: dict = {}
@@ -171,7 +172,8 @@ def _wdl_unflatten_group(flat_k, sizes_shapes):
 
 
 def make_wdl_stage_programs(cfg, plan: StagePlan):
-    """WDL pipeline bodies. Stage 0 owns the embedding gather + wide
+    """WDL pipeline bodies. Stage 0 owns the one lookup a field
+    (`models/wdl.wdl_plane`, as `wdl_forward` does it) + wide
     tower (its logit is data-only, so it is computed once and carried
     beside the deep activation as one extra f32 column); mid stages
     apply their dense layers; the head owns the output layer, bias and
@@ -221,16 +223,14 @@ def make_wdl_stage_programs(cfg, plan: StagePlan):
             wide = parts[n_cat: 2 * n_cat]
             wide_dense = parts[2 * n_cat]
             layers = parts[head_arrays:]
-            pieces = [dense]
-            for f in range(n_cat):
-                idx = jnp.clip(codes[:, f], 0, embed[f].shape[0] - 1)
-                pieces.append(embed[f][idx])
-            h = jnp.concatenate(pieces, axis=1)
-            wl = dense @ wide_dense
-            for f in range(n_cat):
-                idx = jnp.clip(codes[:, f], 0, wide[f].shape[0] - 1)
-                wl = wl + wide[f][idx]
-            h = deep_group(layers, h, stage.layer_lo, stage.layer_hi)
+            # the forward's own lookup and wide sum, op for op: K stages
+            # reproduce one stage bit for bit
+            h, first_w, is_wide = wdl_plane(embed, wide, layers[0], dense,
+                                            codes)
+            wl = dense @ wide_dense + jnp.dot(
+                h, is_wide, precision=jax.lax.Precision.HIGHEST)
+            h = deep_group([first_w] + layers[1:], h, stage.layer_lo,
+                           stage.layer_hi)
             return h.astype(jnp.float32), wl.astype(jnp.float32)
 
         @jax.jit

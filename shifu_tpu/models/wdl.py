@@ -110,40 +110,79 @@ def unflatten_wdl(flat, template: WDLParams) -> WDLParams:
     )
 
 
+def wdl_plane(embed, wide, first_w, dense, codes):
+    """One lookup a categorical field: the field's embedding row and its wide
+    weight sit side by side in a `[vocab_f, E + 1]` table put together here
+    from `embed[f]` and `wide[f]` (a pass over the table's rows, not the
+    data's), and one gather reads both; under `jax.grad` its transpose is one
+    scatter-add a field into that table, which the assembly's transpose
+    splits into the gradients of `embed[f]` and `wide[f]`. The gathered
+    planes are never cut apart (a column cut from a `[n, E + 1]` plane is a
+    pass over the rows of its own, as dear as the lookup it saves): they are
+    concatenated whole.
+
+    Returns the plane `h` [n, Dn + Dc x (E + 1)]; the tower's first weight
+    `first_w` [Dn + Dc x E, H] given a zero row at every wide column, so
+    `h @ w` is the first layer on the dense columns and the embeddings alone;
+    and the 0/1 selector of the wide columns: `jnp.dot(h, is_wide)` at
+    `HIGHEST` is the fields' wide sum, the wide weight f32 from table to
+    logit and back."""
+    import jax.numpy as jnp
+
+    first_w = jnp.asarray(first_w)
+    pieces, at = [dense], dense.shape[1]
+    w_rows = [first_w[:at]]
+    no_row = jnp.zeros((1, first_w.shape[1]), first_w.dtype)
+    is_wide = [0.0] * at
+    for f, (emb, wd) in enumerate(zip(embed, wide, strict=True)):
+        # params may be host numpy (loaded spec)
+        tb = jnp.concatenate([jnp.asarray(emb), jnp.asarray(wd)[:, None]],
+                             axis=1)
+        idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
+        pieces.append(tb[idx])
+        e = emb.shape[1]
+        w_rows += [first_w[at:at + e], no_row]
+        is_wide += [0.0] * e + [1.0]
+        at += e
+    h = jnp.concatenate(pieces, axis=1)
+    return h, jnp.concatenate(w_rows, axis=0), jnp.asarray(is_wide, h.dtype)
+
+
 def wdl_forward(p: WDLParams, dense, codes, activations: List[str],
                 logits_only: bool = False):
     """dense [n, Dn], codes [n, Dc] -> [n] probability (or raw logit).
 
+    One lookup a categorical field for its embedding row and its wide weight
+    together (`wdl_plane`); the tower reads the whole plane through its first
+    weight with zero rows at the wide columns, and the wide sum is a dot with
+    the 0/1 selector of those columns at `HIGHEST`.
+
     The three named scopes are op metadata only (the `tf_op` a profiler
-    trace shows): `wdl.embed` the per-field table lookups, `wdl.deep` the
-    tower, `wdl.wide` the per-field weight lookups and the dense dot. Under
-    `jax.grad` their halves read `jvp(wdl.embed)` and
-    `transpose(jvp(wdl.embed))`, the second being the scatter-adds."""
+    trace shows): `wdl.embed` the per-field lookups and the plane, `wdl.deep`
+    the tower, `wdl.wide` the selector dot and the dense dot. Under
+    `jax.grad` their halves read `jvp(wdl.embed)`, the gathers, and
+    `transpose(jvp(wdl.embed))`, one scatter-add a field into its
+    `[vocab_f, E + 1]` table."""
     import jax
     import jax.numpy as jnp
 
     from shifu_tpu.models.nn import activation_fn
 
-    pieces = [dense]
     with jax.named_scope("wdl.embed"):
-        for f, table in enumerate(p.embed):
-            tb = jnp.asarray(table)  # params may be host numpy (loaded spec)
-            idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
-            pieces.append(tb[idx])
+        h, first_w, is_wide = wdl_plane(
+            p.embed, p.wide, p.dense_layers[0]["W"], dense, codes)
     with jax.named_scope("wdl.deep"):
-        h = jnp.concatenate(pieces, axis=1)
+        weights = [first_w] + [layer["W"] for layer in p.dense_layers[1:]]
+        x = h
         n_hidden = len(p.dense_layers) - 1
         for i in range(n_hidden):
             act = activation_fn(activations[i % len(activations)] if activations else "relu")
-            h = act(h @ p.dense_layers[i]["W"] + p.dense_layers[i]["b"])
-        deep_logit = (h @ p.dense_layers[-1]["W"] + p.dense_layers[-1]["b"])[:, 0]
+            x = act(x @ weights[i] + p.dense_layers[i]["b"])
+        deep_logit = (x @ weights[-1] + p.dense_layers[-1]["b"])[:, 0]
 
     with jax.named_scope("wdl.wide"):
-        wide_logit = dense @ jnp.asarray(p.wide_dense)
-        for f, table in enumerate(p.wide):
-            tb = jnp.asarray(table)
-            idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
-            wide_logit = wide_logit + tb[idx]
+        wide_logit = dense @ jnp.asarray(p.wide_dense) + jnp.dot(
+            h, is_wide, precision=jax.lax.Precision.HIGHEST)
 
     logit = deep_logit + wide_logit + jnp.asarray(p.bias)[0]
     if logits_only:

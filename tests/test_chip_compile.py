@@ -253,12 +253,16 @@ def test_nn_train_step_compiles_at_small_width(one_chip):
 def test_wdl_program_compiles_at_the_criteo_cells_size(one_chip):
     """The wide-and-deep program of `criteo_wdl.train_fullbatch`
     (benchmarks/configs/criteo_wdl.json: 2,865,039 rows x 13 dense + 26
-    codes, tables of min(c, 10000) + 1 rows): it fits one v5e, its
-    temporaries stay under 2.2 KB a row (1,844 B in PR 32: 5.28 GB), and
-    XLA lowers the 52 lookups a row to 45 `gather` (the 7 wide tables of 3
-    to 27 rows become selects) and their transposes to 52 `scatter`. A PR
-    that changes how the lookups are lowered changes these counts, and
-    says so here."""
+    codes, tables of min(c, 10000) + 1 rows): it fits one v5e with the
+    check's reference beside it (temporaries and arguments under 70 % of
+    the 15.75 GiB a v5e hands out), its temporaries stay under 3.0 KB a row
+    (2,774 B since PR 33: 7.95 GB, the 26 gathered `f32[n, 9]` planes and
+    their concatenation; 1,844 B in PR 32), and XLA lowers the 52 lookups
+    a row to 26 `gather`, one a field for its embedding row and its wide
+    weight together, and their transposes to 26 `scatter` (PR 32's two
+    lookups a field were 45 `gather`, the 7 wide tables of 3 to 27 rows
+    selects, and 52 `scatter`). A PR that changes how the lookups are
+    lowered changes these counts, and says so here."""
     import json
     import os
     import re
@@ -288,12 +292,13 @@ def test_wdl_program_compiles_at_the_criteo_cells_size(one_chip):
         carry, i32, s((rows, c["dense_columns"]), jnp.float32),
         s((rows, len(vocab)), jnp.int32), row, row, row, f32, f32).compile()
     mem = compiled.memory_analysis()
-    assert 0 < mem.temp_size_in_bytes < 2200 * rows, \
+    assert 0 < mem.temp_size_in_bytes < 3000 * rows, \
         mem.temp_size_in_bytes / rows
-    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 * 2**30
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            < 0.70 * 15.75 * 2**30)
     text = compiled.as_text()
-    assert len(re.findall(r" gather\(", text)) == 45
-    assert len(re.findall(r" scatter\(", text)) == 52
+    assert len(re.findall(r" gather\(", text)) == 26
+    assert len(re.findall(r" scatter\(", text)) == 26
 
 
 def test_levels_beyond_the_rule_go_to_the_hist_kernel(monkeypatch):

@@ -528,17 +528,20 @@ def test_wdl_program_carries_its_scopes():
                   "jvp(wdl.deep)", "transpose(jvp(wdl.deep))",
                   "wdl.loss", "wdl.update"):
         assert any(scope in s for s in stacks), (scope, sorted(stacks))
-    # every lookup and every transpose of one sits under its own scope: one
-    # gather and one scatter-add a field, embedding and wide
+    # one lookup a field, for its embedding row and its wide weight together
+    # (PR 33), and one scatter-add that is its transpose, both under
+    # `wdl.embed`; `wdl.wide` keeps the dense dot and the selector dot
     by = lambda prim, scope: sum(  # noqa: E731
         e.primitive.name == prim and scope in str(e.source_info.name_stack)
         for e in eqns)
     assert by("gather", "jvp(wdl.embed)") == 3
-    assert by("gather", "jvp(wdl.wide)") == 3
     assert by("scatter-add", "transpose(jvp(wdl.embed))") == 3
-    assert by("scatter-add", "transpose(jvp(wdl.wide))") == 3
+    assert sum(e.primitive.name in ("gather", "scatter-add")
+               for e in eqns) == 6
     dots = [str(e.source_info.name_stack) for e in eqns
             if e.primitive.name == "dot_general"]
+    assert sum("jvp(wdl.wide)" in s and "transpose" not in s
+               for s in dots) == 2
     # the tower's two matmuls forward, and a weight and an input gradient
     # of each behind them
     assert sum("jvp(wdl.deep)" in s and "transpose" not in s

@@ -11,7 +11,10 @@ from shifu_tpu.models.wdl import (
     flatten_wdl,
     init_wdl_params,
     unflatten_wdl,
+    unflatten_wdl_from_shapes,
+    wdl_arrays,
     wdl_forward,
+    wdl_shapes,
 )
 from shifu_tpu.train.wdl_trainer import WDLTrainConfig, train_wdl
 
@@ -59,6 +62,113 @@ class TestForward:
         assert out[1] > out[0] > out[2]
 
 
+def _two_lookup_forward(p, dense, codes, activations, logits_only=False):
+    """The forward written out with two lookups a field, `embed[idx]` for
+    the tower and `wide[idx]` for the wide sum, in plain `jax.numpy`: what
+    `wdl_forward`'s one lookup a field (PR 33) has to equal, and the only
+    copy of that loop the repo keeps."""
+    import jax.numpy as jnp
+
+    from shifu_tpu.models.nn import activation_fn
+
+    pieces, wide_logit = [dense], dense @ jnp.asarray(p.wide_dense)
+    for f in range(len(p.embed)):
+        embed, wide = jnp.asarray(p.embed[f]), jnp.asarray(p.wide[f])
+        idx = jnp.clip(codes[:, f], 0, embed.shape[0] - 1)
+        pieces.append(embed[idx])
+        wide_logit = wide_logit + wide[idx]
+    h = jnp.concatenate(pieces, axis=1)
+    for i, layer in enumerate(p.dense_layers[:-1]):
+        act = activation_fn(activations[i % len(activations)])
+        h = act(h @ layer["W"] + layer["b"])
+    last = p.dense_layers[-1]
+    logit = (h @ last["W"] + last["b"])[:, 0] + wide_logit + jnp.asarray(p.bias)[0]
+    return logit if logits_only else 1.0 / (1.0 + jnp.exp(-logit))
+
+
+def _trainer_loss(forward, shapes, n_cat, activations):
+    """`wdl_trainer._get_program`'s `loss_fn` over a given forward."""
+    import jax.numpy as jnp
+
+    def loss(flat, dense, codes, t, sig):
+        p = unflatten_wdl_from_shapes(flat, shapes, n_cat)
+        pc = jnp.clip(forward(p, dense, codes, activations), 1e-7, 1 - 1e-7)
+        return jnp.sum(sig * -(t * jnp.log(pc) + (1 - t) * jnp.log(1 - pc)))
+
+    return loss
+
+
+def _parity_case(embed_dim, hidden, seed=11, n=257, dn=3):
+    """Tables of one row, of three and of seven; codes below 0 and past the
+    last row in every field, so the clip is on the path."""
+    vocab = [1, 3, 7]
+    rng = np.random.default_rng(seed)
+    p = init_wdl_params(dn, vocab, embed_dim, hidden, seed=seed)
+    flat = flatten_wdl(p)
+    flat = (flat + rng.normal(0, 0.3, flat.size)).astype(np.float32)
+    dense = rng.normal(size=(n, dn)).astype(np.float32)
+    codes = np.stack([rng.integers(-2, v + 3, n) for v in vocab],
+                     axis=1).astype(np.int32)
+    t = (rng.random(n) < 0.4).astype(np.float32)
+    sig = rng.integers(0, 3, n).astype(np.float32)
+    return unflatten_wdl(flat, p), flat, wdl_shapes(p), dense, codes, t, sig
+
+
+class TestOneLookupAField:
+    """`wdl_forward` reads a field's embedding row and its wide weight with
+    one gather (PR 33): value and every gradient leaf against the two-lookup
+    forward above."""
+
+    @pytest.mark.parametrize("embed_dim,hidden,acts", [
+        (1, [5], ["relu"]), (2, [6, 4], ["tanh", "relu"]), (8, [], ["relu"]),
+        (8, [16], ["relu"])])
+    @pytest.mark.parametrize("tables", ["host", "device"])
+    def test_forward_and_every_gradient_leaf(self, embed_dim, hidden, acts,
+                                             tables):
+        import jax
+        import jax.numpy as jnp
+
+        p, flat, shapes, dense, codes, t, sig = _parity_case(embed_dim, hidden)
+        assert codes.min() < 0 and (codes.max(axis=0) > [0, 2, 6]).all()
+        if tables == "device":
+            p = unflatten_wdl_from_shapes(jnp.asarray(flat), shapes, 3)
+        for logits_only in (True, False):
+            got = jax.jit(lambda d, c: wdl_forward(p, d, c, acts, logits_only))(
+                dense, codes)
+            want = _two_lookup_forward(p, dense, codes, acts, logits_only)
+            np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+        grads = [jax.jit(jax.grad(_trainer_loss(fwd, shapes, 3, acts)))(
+            jnp.asarray(flat), dense, codes, t, sig)
+            for fwd in (wdl_forward, _two_lookup_forward)]
+        leaves = [unflatten_wdl_from_shapes(np.asarray(g), shapes, 3)
+                  for g in grads]
+        for i, (a, b) in enumerate(zip(*map(wdl_arrays, leaves))):
+            assert a.shape == b.shape
+            assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b), i
+            assert np.linalg.norm(b) > 0, i
+
+    @pytest.mark.parametrize("embed_dim", [1, 2, 8])
+    def test_under_vmap_as_the_bagged_trainer_runs_it(self, embed_dim):
+        import jax
+        import jax.numpy as jnp
+
+        p, flat, shapes, dense, codes, t, sig = _parity_case(embed_dim, [6])
+        rng = np.random.default_rng(5)
+        flats = jnp.asarray(np.stack(
+            [flat, flat + rng.normal(0, 0.1, flat.size).astype(np.float32)]))
+        sigs = jnp.asarray(np.stack([sig, sig[::-1]]))
+        # train_wdl_bagged: members along the parameters and the draw, the
+        # rows shared
+        ga, gb = [jax.jit(jax.vmap(
+            jax.grad(_trainer_loss(fwd, shapes, 3, ["relu"])),
+            in_axes=(0, None, None, None, 0)))(flats, dense, codes, t, sigs)
+            for fwd in (wdl_forward, _two_lookup_forward)]
+        ends = np.cumsum([int(np.prod(shp)) for shp in shapes])[:-1]
+        for shp, a, b in zip(shapes, np.split(np.asarray(ga), ends, axis=1),
+                             np.split(np.asarray(gb), ends, axis=1)):
+            assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b), shp
+
+
 class TestTrain:
     def test_learns_both_towers(self):
         dense, codes, t, w, vocab = _make_data()
@@ -68,7 +178,11 @@ class TestTrain:
         res = train_wdl(dense, codes, t, w, vocab, cfg)
         assert res.valid_error < 0.12
 
-    def test_mesh_matches_single(self):
+    @pytest.mark.parametrize("model_axis", [1, 2])
+    def test_mesh_matches_single(self, model_axis):
+        """Rows over `data`; with a `model` axis the embedding tables are
+        held to `P(None, "model")` before the forward puts a field's table
+        together from them."""
         from shifu_tpu.parallel.mesh import data_mesh
 
         dense, codes, t, w, vocab = _make_data(n=260)
@@ -76,7 +190,8 @@ class TestTrain:
                              learning_rate=0.05, num_epochs=15,
                              valid_set_rate=0.25, seed=3)
         r1 = train_wdl(dense, codes, t, w, vocab, cfg)
-        r2 = train_wdl(dense, codes, t, w, vocab, cfg, mesh=data_mesh())
+        r2 = train_wdl(dense, codes, t, w, vocab, cfg,
+                       mesh=data_mesh(model_axis=model_axis))
         np.testing.assert_allclose(
             flatten_wdl(r1.params), flatten_wdl(r2.params), rtol=3e-3, atol=3e-4
         )
@@ -108,6 +223,45 @@ class TestSpec:
         s1 = spec.independent().compute_parts(dense[:20], codes[:20])
         s2 = loaded.independent().compute_parts(dense[:20], codes[:20])
         np.testing.assert_allclose(s1, s2, atol=1e-6)
+
+    def test_the_file_is_what_it_was_before_the_one_lookup(self, tmp_path):
+        """PR 33 changed how the forward reads the tables, not what is
+        stored: a `.wdl` saved from fixed parameters has the bytes PR 32's
+        `save` wrote for them (the hash is from that commit's code), laid
+        out as header then embed, wide, wide_dense, W and b a layer, bias;
+        loaded again it scores to the two-lookup forward's values."""
+        import hashlib
+        import json
+        import struct
+
+        tpl = init_wdl_params(2, [1, 3], 2, [3])
+        n = flatten_wdl(tpl).size
+        flat = (((np.arange(n) * 37) % 101 - 50) / 64).astype(np.float32)
+        spec = WDLModelSpec(
+            hidden=[3], activations=["tanh"], embed_dim=2,
+            dense_columns=["n0", "n1"], cat_columns=["c0", "c1"],
+            vocab_sizes=[1, 3], categories=[[], ["a", "b"]],
+            params=unflatten_wdl(flat, tpl), train_error=0.25,
+            valid_error=0.5)
+        path = str(tmp_path / "m.wdl")
+        spec.save(path)
+        with open(path, "rb") as fh:
+            data = fh.read()
+        assert hashlib.sha256(data).hexdigest() == (
+            "0746b757dec62a7f0b1e80060714ca54aaf31c1965db0381a16a6f9c8ba5a572")
+        (hlen,) = struct.unpack("<I", data[4:8])
+        assert data[:4] == b"STWD" and data[8 + hlen:] == flat.tobytes()
+        assert json.loads(data[8:8 + hlen])["shapes"] == [
+            [1, 2], [3, 2], [1], [3], [2], [6, 3], [3], [3, 1], [1], [1]]
+        p = spec.params
+        assert np.array_equal(p.embed[1], flat[2:8].reshape(3, 2))
+        assert np.array_equal(p.wide[1], flat[9:12])
+        rng = np.random.default_rng(2)
+        dense = rng.normal(size=(40, 2)).astype(np.float32)
+        codes = rng.integers(-1, 5, (40, 2)).astype(np.int32)
+        got = WDLModelSpec.load(path).independent().compute_parts(dense, codes)
+        want = _two_lookup_forward(p, dense, codes, ["tanh"])
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
 
 
 class TestProcessor:
