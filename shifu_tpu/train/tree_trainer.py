@@ -2205,54 +2205,55 @@ def train_trees(
                 if replicate_fn is not None:
                     fot_all_features = replicate_fn(fot_all_features)
 
-            # ---- per-tree RNG draws, PREPASSED: each tree's stream is keyed by
-            # (seed, tree index) — resume at tree k replays identically — so the
-            # draws are known up front. RF bag counts ship as ONE [K, n] uint16
-            # transfer instead of a [n] f32 per tree (remote TPU links price every
-            # host->device byte); values are exact (Poisson counts nowhere near
-            # 65535). feat_ok stays host-side (tiny, drives layout masks). ----
-            draw_ks = list(range(start_k, cfg.tree_num))
-            feat_oks: Dict[int, np.ndarray] = {}
-            bags_j = None
-            if cfg.algorithm == "RF" and draw_ks:
-                bag_rows = []
-                for k in draw_ks:
+            # ---- per-tree RNG draws: each tree's stream is keyed by (seed, tree
+            # index) — resume at tree k replays identically. feat_ok stays
+            # host-side (tiny, drives layout masks). A forest's bag (a Poisson
+            # or Bernoulli count for every row, ~0.2 s of numpy a tree at
+            # 5.5 M rows) is drawn a tree AHEAD: the first here, tree k + 1's
+            # in the loop once tree k is dispatched, so the host draws while
+            # the device grows (PERF.md section 6, PR 34: all drawn here they
+            # idled the chip 2 s a 10-tree call). A bag crosses as uint16
+            # (exact: counts nowhere near 65535; half the bytes of f32). ----
+            is_rf = cfg.algorithm == "RF"
+
+            def draw_feat_ok(rng_k):
+                feat_ok = np.zeros(F, dtype=bool)
+                if k_sub >= F:
+                    feat_ok[:] = True
+                else:
+                    feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
+                return feat_ok
+
+            def draw_bag(k):
+                """Tree k's bag, on the device, and its column subset."""
+                with span("train.trees.bag", call=call, k=k,
+                          rows=n_orig) as bag_sp:
                     rng_k = np.random.default_rng([cfg.seed, k])
                     if cfg.bagging_with_replacement:
-                        bag = rng_k.poisson(cfg.bagging_sample_rate, size=n_orig)
+                        bag = rng_k.poisson(cfg.bagging_sample_rate,
+                                            size=n_orig)
                     else:
                         bag = rng_k.random(n_orig) < cfg.bagging_sample_rate
-                    bag_rows.append(np.pad(bag.astype(np.uint16), (0, n - n_orig)))
-                    feat_ok = np.zeros(F, dtype=bool)
-                    if k_sub >= F:
-                        feat_ok[:] = True
-                    else:
-                        feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
-                    feat_oks[k] = feat_ok
-                if mesh is None:
-                    bags_j = jnp.asarray(np.stack(bag_rows))  # [K, n] u16, one put
-                else:
-                    bags_j = [row_put(b.astype(np.float32)) for b in bag_rows]
-            else:
-                for k in draw_ks:
-                    rng_k = np.random.default_rng([cfg.seed, k])
-                    feat_ok = np.zeros(F, dtype=bool)
-                    if k_sub >= F:
-                        feat_ok[:] = True
-                    else:
-                        feat_ok[rng_k.choice(F, size=k_sub, replace=False)] = True
-                    feat_oks[k] = feat_ok
+                    bag = np.pad(bag.astype(np.uint16 if mesh is None
+                                            else np.float32), (0, n - n_orig))
+                    feat_ok = draw_feat_ok(rng_k)
+                    bag_sp["bytes"] = int(bag.nbytes)
+                    return row_put(bag), feat_ok
+
+            feat_oks: Dict[int, np.ndarray] = {} if is_rf else {
+                k: draw_feat_ok(np.random.default_rng([cfg.seed, k]))
+                for k in range(start_k, cfg.tree_num)}
+            ahead = (draw_bag(start_k)
+                     if is_rf and start_k < cfg.tree_num else None)
 
         for k in range(start_k, cfg.tree_num):
             with span("train.tree", call=call, k=k):
-                feat_ok = feat_oks[k]
-                if cfg.algorithm == "RF":
-                    if mesh is None:
-                        w_k = base_w_j * bags_j[k - start_k].astype(jnp.float32)
-                    else:
-                        w_k = base_w_j * bags_j[k - start_k]
+                if is_rf:
+                    bag_j, feat_ok = ahead
+                    w_k = base_w_j * bag_j.astype(jnp.float32)
                     labels_k = y_j
                 else:  # GBT: fit the negative loss gradient
+                    feat_ok = feat_oks[k]
                     w_k = base_w_j
                     if log_loss:
                         labels_k = y_j - 1.0 / (1.0 + jnp.exp(-pred))
@@ -2331,6 +2332,10 @@ def train_trees(
                     pred = tree_pred if k == 0 else (pred * n_prev + tree_pred) / (k + 1)
                     score = jnp.clip(pred, 0.0, 1.0)
                     t_e, v_e = errors_of(score)
+                if is_rf and k + 1 < cfg.tree_num:
+                    # this tree is dispatched: draw the next one's bag before
+                    # anything below waits for the device
+                    ahead = draw_bag(k + 1)
                 if not need_sync:
                     err_pairs.append((t_e, v_e))
                     valid_errors.append(None)  # filled after the final sync

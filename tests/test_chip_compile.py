@@ -226,6 +226,66 @@ def test_whole_tree_program_kernel_count_at_higgs(one_chip, monkeypatch,
     assert tt._tree_kernel_calls(D, lay, sub_levels) == want
 
 
+def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
+    """The whole-tree program of `higgs_rf.train_depth10`
+    (benchmarks/configs/higgs_rf.json: 5,500,000 rows x 28 x 33 slots, depth
+    10, f32 planes, subtraction at every level) for the described v5e: 17
+    Mosaic calls, 14 `tree_fused_level` (2 chunks x levels 0 to 6, built
+    halves of 1 to 32 nodes) and 3 `tree_hist` (levels 7 to 9, built halves
+    of 64 to 256 nodes, one chunk); temporaries under 1,130 B a row (1,061:
+    5.84 GB, of which the int32 code matrix's row-major `copy` and its `pad`
+    for the hist-mode entry are 2.8 GB each) and, with the arguments, under
+    45 % of the 15.75 GiB a v5e hands out (40 %). About 45 s on the CPU."""
+    import json
+    import os
+    import re
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "higgs_rf.json")) as f:
+        c = json.load(f)
+    rows, D = c["rows"], c["max_depth"]
+    assert (rows, D, c["features"], c["slots_per_feature"]) == (
+        5_500_000, 10, 28, 33)
+    lay = tt.make_layout([c["slots_per_feature"]] * c["features"],
+                         [False] * c["features"])
+    cfg = tt.TreeTrainConfig(
+        algorithm="RF", tree_num=c["trees_per_call"], max_depth=D,
+        max_stats_memory_mb=c["max_stats_memory_mb"],
+        hist_subtraction=c["hist_subtraction"])
+    batch_cap = tt._node_batch_size(lay.T, cfg.max_stats_memory_mb)
+    assert 2 ** D <= batch_cap  # the whole-tree program, not build_tree
+    sub_levels, acc64 = tt._sub_plan(cfg, batch_cap)
+    assert all(sub_levels[1:D]) and not tt._low_precision(cfg)
+    monkeypatch.setattr(tt, "_pallas_state",
+                        lambda mesh=None: (True, False, True))
+    key_before = set(tt._PROGRAMS)
+    try:
+        prog = tt._get_tree_program(
+            D, lay, c["impurity"], c["min_instances_per_node"],
+            c["min_info_gain"], sub_levels=sub_levels, acc64=acc64,
+            lowp=False)
+    finally:
+        for k in set(tt._PROGRAMS) - key_before:
+            del tt._PROGRAMS[k]  # built under a steered state: never reuse
+    codes, labels, weights, _node, _active = _row_args(
+        one_chip, c["features"], rows)
+    compiled = prog.fn.lower(
+        codes, _shape(one_chip, codes.shape, jnp.int8), labels, weights,
+        _shape(one_chip, (lay.T,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    # each call is an instruction named after its kernel
+    names = re.findall(r"%(tree_fused_level|tree_hist)[\w.]* = ", text)
+    assert (names.count("tree_fused_level"), names.count("tree_hist")) == (
+        14, 3), names
+    assert _compiled_kernels(compiled) == 17
+    assert tt._tree_kernel_calls(D, lay, sub_levels) == 17
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < 1130 * rows, \
+        mem.temp_size_in_bytes / rows
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            < 0.45 * 15.75 * 2**30)
+
+
 def test_nn_train_step_compiles_at_small_width(one_chip):
     from shifu_tpu.models.nn import flatten_params, init_params
     from shifu_tpu.train import nn_trainer as nt

@@ -153,6 +153,103 @@ def test_progress_cb_time_is_the_callers(rows):
     assert len(cb) == 3 and min(cb) >= 0.018
 
 
+def _forest(rows, progress_cb=None, algorithm="RF", trees=3, **cfg):
+    codes, _x, y, w = rows
+    conf = tt.TreeTrainConfig(algorithm=algorithm, tree_num=trees,
+                              max_depth=3, valid_set_rate=0.2, seed=3, **cfg)
+    return tt.train_trees(codes, y, w, [SLOTS] * F, [False] * F,
+                          ["f%d" % i for i in range(F)], conf,
+                          progress_cb=progress_cb)
+
+
+@pytest.mark.parametrize("algorithm,trees", [("RF", 3), ("RF", 1),
+                                             ("GBT", 3)])
+def test_a_forests_bag_draws_have_a_span_of_their_own(rows, algorithm,
+                                                      trees):
+    """`train.trees.bag`: one tree's bag and column draws and the bag's put,
+    with what crossed to the device (a uint16 a row). The first tree's is in
+    the prologue; tree k + 1's is inside tree k's `train.tree`, after the
+    tree is dispatched, so the host draws while the device grows. Only a
+    forest enters it: a GBT call's spans are what they were."""
+    obs.reset()
+    _forest(rows, algorithm=algorithm, trees=trees,
+            feature_subset_strategy="TWOTHIRDS")
+    got, evs = _train_events()
+    bags = [e for e in evs if e["name"] == "train.trees.bag"]
+    if algorithm == "GBT":
+        assert not bags and got[0] == ("train.trees.prologue", CALL, None)
+        return
+    assert [e["args"] for e in bags] == [
+        {"call": 1, "k": k, "rows": N, "bytes": 2 * N,
+         "parent": (CALL + "/train.trees.prologue" if k == 0
+                    else TREE)} for k in range(trees)]
+    # tree k + 1's bag ends inside tree k's span, before the tree's end
+    names = [g[0] for g in got]
+    assert names[:2] == ["train.trees.bag", "train.trees.prologue"]
+    for k in range(1, trees):
+        assert got.index(("train.trees.bag", TREE, k)) \
+            < got.index(("train.tree", CALL, k - 1))
+    # the other spans are a GBT call's, in a GBT call's order
+    rest = [g for g in got if g[0] != "train.trees.bag"]
+    assert rest[0] == ("train.trees.prologue", CALL, None)
+    assert [g for g in rest if g[0] == "train.tree"] == [
+        ("train.tree", CALL, k) for k in range(trees)]
+
+
+def test_the_next_bag_is_drawn_before_the_loop_waits_for_the_tree(rows):
+    """With a consumer the loop blocks a tree (`train.tree.wait`): the next
+    tree's bag must be drawn before the first of them, or the device idles
+    through the draw."""
+    _forest(rows, progress_cb=lambda *a: None)
+    obs.reset()
+    _forest(rows, progress_cb=lambda *a: None)
+    _got, evs = _train_events()
+    for k in range(2):
+        (bag,) = [e for e in evs if e["name"] == "train.trees.bag"
+                  and e["args"]["k"] == k + 1]
+        waits = [e for e in evs if e["name"] == "train.tree.wait"
+                 and e["args"]["k"] == k]
+        assert waits and all(bag["ts"] + bag["dur"] <= w["ts"] + 1.0
+                             for w in waits)
+
+
+def _bag_ks():
+    return [e["args"]["k"] for e in obs.tracer().events
+            if e["name"] == "train.trees.bag"]
+
+
+def test_a_forest_that_stops_early_draws_one_bag_past_its_last_tree(rows):
+    """Bags are drawn one tree ahead, so a stop after tree k has drawn tree
+    k + 1's and no other; the forest is the first trees of the
+    uninterrupted one."""
+    obs.reset()
+    res = _forest(rows, trees=40, early_stop_rounds=1,
+                  bagging_sample_rate=0.05)
+    grown = len(res.spec.trees)
+    assert 2 <= grown < 40
+    assert _bag_ks() == list(range(grown + 1))
+    whole = _forest(rows, trees=grown, bagging_sample_rate=0.05)
+    for a, b in zip(res.spec.trees, whole.spec.trees):
+        assert np.array_equal(a.feature, b.feature)
+        assert np.array_equal(a.leaf_value, b.leaf_value)
+
+
+def test_a_callback_that_raises_leaves_no_bag_being_drawn(rows):
+    """`progress_cb` raising at the second tree unwinds `train_trees` with
+    the bags of trees 0, 1 and 2 drawn (one ahead) and nothing left behind
+    that draws or puts another."""
+    def cb(k, _t, _v):
+        if k == 2:
+            raise RuntimeError("stop here")
+
+    obs.reset()
+    with pytest.raises(RuntimeError, match="stop here"):
+        _forest(rows, progress_cb=cb, trees=6)
+    assert _bag_ks() == [0, 1, 2]
+    time.sleep(0.2)
+    assert _bag_ks() == [0, 1, 2]
+
+
 def test_train_nn_leaves_the_tables_spans(rows):
     _codes, x, y, w = rows
     cfg = nt.NNTrainConfig(hidden_nodes=[8], activations=["tanh"],
