@@ -9,7 +9,13 @@ in the level. Each form is jitted alone, run 5 times after a warm-up call
 and timed with `block_until_ready`; the least of the five is printed, in
 ms, one JSON line a shape, and all lines go to chiprun_out/route_bench.json.
 `--explore` also times the parts (the code select, the mask lookup) in the
-forms that were tried; PERF.md section 6 (PR 27) has the readings."""
+forms that were tried; PERF.md section 6 (PR 27) has the readings.
+`--built` times, instead, the forms of the next level's build-row mask
+(histogram subtraction) at the cell's rows and L = 16 to 512: none (the
+routing pass alone), `ride` (the bit in the feature's table entry: what
+`route_rows` does since PR 35), `select` (a `_lookup` of its own after the
+pass) and `gather` (`built_lsb[node >> 1]`, the form up to PR 34), each one
+jitted program; PERF.md section 6 (PR 35) has the readings."""
 
 from __future__ import annotations
 
@@ -156,6 +162,49 @@ def explore(codes, d, F, s_max, L):
     return out
 
 
+def built_forms(n=5_500_000, F=28, s_max=33, levels=(16, 64, 128, 256, 512)):
+    """The build-row mask of the next level, in the forms tried."""
+    import jax
+    import jax.numpy as jnp
+
+    from shifu_tpu.train import tree_trainer as tt
+
+    def after(lookup):
+        def form(*a):
+            resting, node, active, _ = tt.route_rows(*a[:-1])
+            lsb = lookup(jnp.where(a[-1], 0, 1), node >> 1)
+            return resting, node, active, active & ((node & 1) == lsb)
+        return form
+
+    forms = {
+        "none": lambda *a: tt.route_rows(*a[:-1])[:3],
+        "ride": tt.route_rows,
+        "select": after(tt._lookup),
+        "gather": after(lambda table, idx: table[idx]),
+    }
+    lines = []
+    for L in levels:
+        d = {k: jnp.asarray(v) for k, v in
+             make_inputs(n, F, s_max, L).items()}
+        codes = jax.random.randint(jax.random.PRNGKey(1), (n, F), -1,
+                                   s_max + 2, jnp.int32)
+        left_small = jax.random.bernoulli(jax.random.PRNGKey(2), 0.5, (L,))
+        args = (codes, d["node"], d["active"], d["resting"], d["feature"],
+                d["is_split"], d["left_mask"], jnp.int32(L - 1), d["clip"],
+                left_small)
+        line, ref = {"n": n, "F": F, "s_max": s_max, "L": L}, None
+        for name, fn in forms.items():
+            line[name + "_ms"], o = best_ms(jax.jit(fn), args)
+            if name != "none":
+                ref = o if ref is None else ref
+                assert all(bool(jnp.array_equal(x, y))
+                           for x, y in zip(o, ref)), name
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+        del codes, d, args, ref, o
+    return lines
+
+
 def main():
     import jax
     import jax.numpy as jnp
@@ -164,6 +213,13 @@ def main():
     from tests.test_route_rows import route_gather
 
     dev = jax.devices()[0]
+    if "--built" in sys.argv:
+        lines = built_forms()
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/route_bench_built.json", "w") as fh:
+            json.dump({"device": dev.device_kind, "lines": lines}, fh,
+                      indent=1)
+        return
     lines = []
     for n, F, s_max, L in SHAPES:
         d = {k: jnp.asarray(v) for k, v in

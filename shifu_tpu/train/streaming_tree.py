@@ -55,7 +55,6 @@ from shifu_tpu.train.tree_trainer import (
     _scan_batched,
     _sub_acc64,
     _sub_plan,
-    _sub_row_masks,
     make_layout,
     subset_count,
 )
@@ -123,7 +122,7 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
     sub_on = cfg.hist_subtraction
     n_built = n_derived = n_fallback = 0
     pending = None
-    prev = None  # retained parent level (hist_acc, is_split, lcnt, ncnt)
+    prev = None  # retained parent level (hist_acc, is_split, left_small)
     for depth in range(D + 1):
         L = 2**depth
         base = L - 1
@@ -133,8 +132,7 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
             # shards accumulate only the SMALLER child of each parent as
             # a half-width histogram; siblings derive after the merge
             Lh = L // 2
-            p_hist, p_split, p_lcnt, p_ncnt = prev
-            left_small = p_lcnt <= p_ncnt - p_lcnt
+            p_hist, p_split, left_small = prev
             ranges = [(0, Lh)]
         else:
             ranges = [(b0, min(batch_cap, L - b0))
@@ -143,11 +141,14 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
         for wk, codes_host in _iter_codes(feed, work):
             codes_s = row_put(pad_to_mesh(codes_host))
             if pending is not None:
-                pbf, psplit, pmask, pbase = pending
-                wk["resting"], wk["node"], wk["active"] = (
+                # with use_sub the pass also says which of the shard's
+                # rows went to the child this level builds
+                pbf, psplit, pmask, pbase, p_small = pending
+                wk["resting"], wk["node"], wk["active"], build_row = (
                     _get_update_program()(
                         codes_s, wk["node"], wk["active"], wk["resting"],
-                        pbf, psplit, pmask, jnp.int32(pbase), la.clip))
+                        pbf, psplit, pmask, jnp.int32(pbase), la.clip,
+                        p_small))
             for bi, (b0, Lb) in enumerate(ranges):
                 # -Dshifu.pallas.mode routes this through the hist-mode
                 # Pallas kernel (inside shard_map on a mesh): per-shard
@@ -159,8 +160,7 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
                                            low_precision=_low_precision(
                                                cfg))
                 if use_sub:
-                    nd, in_batch = _sub_row_masks(wk["node"], wk["active"],
-                                                  left_small)
+                    nd, in_batch = wk["node"] >> 1, build_row
                 else:
                     nd = wk["node"] - b0
                     in_batch = (wk["active"] & (wk["node"] >= b0)
@@ -196,16 +196,16 @@ def _grow_levelwise_streamed(feed, work, la, lay, cfg, D, row_put,
                 wk["resting"] = jnp.where(
                     wk["active"], base + wk["node"], wk["resting"])
             break
+        prev = next_small = None
         if retain_next:
             if hist_acc is None:  # full-rebuild level kept whole (the
                 # next level's gate bounds this one to a single batch)
                 full = (hist_parts[0] if len(hist_parts) == 1
                         else jnp.concatenate(hist_parts, axis=1))
                 hist_acc = full.astype(acc_dt) if acc64 else full
-            prev = (hist_acc, is_split, lc, nc)
-        else:
-            prev = None
-        pending = (bf, is_split, lm, base)
+            next_small = lc <= nc - lc
+            prev = (hist_acc, is_split, next_small)
+        pending = (bf, is_split, lm, base, next_small)
         feat_levels.append(jnp.where(is_split, bf, -1))
         mask_levels.append(lm)
         leaf_levels.append(lv)

@@ -780,7 +780,11 @@ def _make_cls_scan(L: int, T: int, s_max: int, impurity: str, min_inst: int,
 # compare-select over the table's axis, which the TPU's vector unit streams
 # (one v5e, 5.5 M rows: 1.0 ms at 64 entries, 10 ms at 1,024, 40 ms at
 # 4,096); past it a 1-D gather, which costs the same 27-43 ms whatever the
-# table. PERF.md section 6 (PR 27) has the readings.
+# table. PERF.md section 6 (PR 27) has the readings. What a row needs of
+# its node beyond those tables rides an entry it reads anyway: the next
+# level's build-row mask (histogram subtraction) is one bit of the feature's
+# entry and comes out of `route_rows`, where a gather of its own costs 45 ms
+# at 128 entries (section 6, PR 35).
 _ROUTE_SELECT_CAP = 4096
 
 
@@ -807,7 +811,7 @@ def _lookup(table, idx):
 
 
 def route_rows(codes, node, active, resting, feature, is_split, left_mask,
-               base, clip_f):
+               base, clip_f, left_small=None):
     """Move a level's rows one level down: rows of a node that does not
     split settle at base + node, the others go to child 2i or 2i + 1
     (level-wise numbering within the next level) as the level's
@@ -817,10 +821,17 @@ def route_rows(codes, node, active, resting, feature, is_split, left_mask,
     No per-row gather from a 2-D table (a TPU v5e runs those at 16-20 ns a
     row whatever the table): the row's code is a select over the static
     feature axis that streams `codes` once, its node's feature, clip and
-    mask word a `_lookup` each, the mask packed 32 slots a word. Returns
-    (resting, node, active) for the next level. Traced inside the
-    whole-tree program (every level) and alone as `tree.row_update` (the
-    node-batched and streamed growers)."""
+    mask word a `_lookup` each, the mask packed 32 slots a word.
+
+    Returns (resting, node, active, built) for the next level. `built` is
+    None unless the next level derives siblings by subtraction and the
+    caller hands in this level's `left_small` [L] (the left child is the
+    smaller one, so the one that is built): then it is the next level's
+    build-row mask, the rows that went to the side their parent builds.
+    The bit rides the feature's table entry, so it costs no lookup of its
+    own; the half-width histogram's node ids are the caller's `node >> 1`.
+    Traced inside the whole-tree program (every level) and alone as
+    `tree.row_update` (the node-batched and streamed growers)."""
     import jax.numpy as jnp
 
     L, s_max = left_mask.shape
@@ -832,8 +843,10 @@ def route_rows(codes, node, active, resting, feature, is_split, left_mask,
                                                       dtype=jnp.uint32)
 
     nl = jnp.clip(node, 0, L - 1)
-    f = _lookup(jnp.where(is_split, feature, -1), nl)  # -1: no split
-    split_row = f >= 0
+    entry = feature if left_small is None else 2 * feature + left_small
+    e = _lookup(jnp.where(is_split, entry, -1), nl)  # -1: no split
+    split_row = e >= 0
+    f = e if left_small is None else e >> 1
     resting = jnp.where(active & ~split_row, base + nl, resting)
     code = jnp.where(f[:, None] == jnp.arange(F, dtype=jnp.int32),
                      codes, 0).sum(axis=1)
@@ -841,9 +854,11 @@ def route_rows(codes, node, active, resting, feature, is_split, left_mask,
     word = _lookup(words, nl * W + (c >> 5))
     goes_left = ((word >> (c & 31).astype(jnp.uint32)) & 1) > 0
     still = split_row & active
+    built = (None if left_small is None
+             else still & (goes_left == ((e & 1) > 0)))
     return (resting,
             jnp.where(still, jnp.where(goes_left, 2 * nl, 2 * nl + 1), 0),
-            still)
+            still, built)
 
 
 def _get_update_program():
@@ -960,16 +975,6 @@ def _get_derive_program():
     prog = profile.wrap("tree.hist_derive", derive)
     _PROGRAMS[key] = prog
     return prog
-
-
-def _sub_row_masks(node, active, left_small):
-    """Per-row restriction to the built (smaller) children: row's node is
-    built iff its low bit matches its parent's chosen side. Returns
-    (parent-slot node ids, build-row mask) for the half-width histogram."""
-    import jax.numpy as jnp
-
-    built_lsb = jnp.where(left_small, 0, 1)
-    return node >> 1, active & ((node & 1) == built_lsb[node >> 1])
 
 
 def _plan_counts(sub_levels: tuple, enabled: bool) -> Tuple[int, int, int]:
@@ -1311,7 +1316,9 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         active = jnp.ones(n, bool)
         resting = jnp.zeros(n, jnp.int32)
         feats_l, masks_l, leaves_l = [], [], []
-        prev = None  # retained parent level (hist_acc, is_split, lcnt, ncnt)
+        # retained parent level: (hist_acc, is_split, left_small, the build
+        # mask of its children's rows)
+        prev = None
 
         def call_hist(L, idx, node_arg, act_arg):
             with phase(L, "hist"):
@@ -1348,14 +1355,11 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                 # the SMALLER child in-kernel (hist + its scan in one
                 # pass), derive the sibling as parent − built and scan it
                 # with the XLA reference, then interleave per parent
-                p_hist, p_split, p_lcnt, p_ncnt = prev
+                p_hist, p_split, left_small, build_row = prev
                 with phase(L, "hist"):
-                    left_small = p_lcnt <= p_ncnt - p_lcnt
-                    nhalf, build_row = _sub_row_masks(node, active,
-                                                      left_small)
                     built, scan_b = fused_fns[d - 1](
-                        codes, codes8, labels, weights, nhalf, build_row,
-                        feat_ok_t)
+                        codes, codes8, labels, weights, node >> 1,
+                        build_row, feat_ok_t)
                 with phase(L, "derive"):
                     b_acc = built.astype(p_hist.dtype)
                     derived = jnp.where(p_split[None, :, None],
@@ -1382,11 +1386,9 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                      lc) = scan_t
                     hist_acc = hist.astype(acc_dt) if acc64 else hist
             elif prev is not None:  # sub_levels[d]: derive from the parent
-                p_hist, p_split, p_lcnt, p_ncnt = prev
+                p_hist, p_split, left_small, build_row = prev
                 with phase(L, "hist"):
-                    left_small = p_lcnt <= p_ncnt - p_lcnt
-                    nhalf, build_row = _sub_row_masks(node, active,
-                                                      left_small)
+                    nhalf = node >> 1
                 built = call_hist(L, d - 1, nhalf, build_row)
                 with phase(L, "derive"):
                     hist, hist_acc = derive(p_hist, built, p_split,
@@ -1400,12 +1402,14 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                 with phase(L, "scan"):
                     (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = xla_scan(d, hist)
-            prev = ((hist_acc, is_split, lc, nc)
-                    if d + 1 < D and sub_levels[d + 1] else None)
+            retain = d + 1 < D and sub_levels[d + 1]
             with phase(L, "route"):
-                resting, node, active = route_rows(
+                left_small = lc <= nc - lc if retain else None
+                resting, node, active, build_row = route_rows(
                     codes, node, active, resting, bf, is_split, lm,
-                    L - 1, clip_c)
+                    L - 1, clip_c, left_small)
+            prev = ((hist_acc, is_split, left_small, build_row)
+                    if retain else None)
             feats_l.append(jnp.where(is_split, bf, -1))
             masks_l.append(lm)
             leaves_l.append(lv)
@@ -1554,7 +1558,9 @@ def build_tree(
     lowp = _low_precision(cfg)
     n_built = n_derived = n_fallback = 0
     feat_levels, mask_levels, leaf_levels = [], [], []
-    prev = None  # retained parent level (hist_acc, is_split, lcnt, ncnt)
+    # retained parent level: (hist_acc, is_split, left_small, the build mask
+    # of its children's rows)
+    prev = None
     for depth in range(D + 1):
         L = 2**depth
         base = L - 1
@@ -1564,13 +1570,11 @@ def build_tree(
         retain_next = (not final) and sub_on and sub_levels[depth + 1]
         if prev is not None:  # sub_levels[depth]: half-width build + derive
             Lh = L // 2
-            p_hist, p_split, p_lcnt, p_ncnt = prev
-            left_small = p_lcnt <= p_ncnt - p_lcnt
-            nhalf, build_row = _sub_row_masks(node_local, active, left_small)
+            p_hist, p_split, left_small, build_row = prev
             hist_p = _get_hist_program(Lh, lay, allow_matmul=mesh is None,
                                        n_classes=cfg.n_classes,
                                        low_precision=lowp)
-            built = hist_p(codes, labels, weights, nhalf, build_row,
+            built = hist_p(codes, labels, weights, node_local >> 1, build_row,
                            la.off, la.clip, la.seg_t, la.pos_t)
             hist_f32, hist_acc = derive(p_hist, built, p_split, left_small)
             parts = [(hist_f32, L, 0)]
@@ -1617,10 +1621,12 @@ def build_tree(
             mask_levels.append(jnp.zeros((L, lay.s_max), bool))
             resting = jnp.where(active, base + node_local, resting)
             break
-        prev = (hist_acc, is_split, lc, nc) if retain_next else None
-        resting, node_local, active = _get_update_program()(
+        left_small = lc <= nc - lc if retain_next else None
+        resting, node_local, active, build_row = _get_update_program()(
             codes, node_local, active, resting, bf, is_split, lm,
-            jnp.int32(base), la.clip)
+            jnp.int32(base), la.clip, left_small)
+        prev = ((hist_acc, is_split, left_small, build_row)
+                if retain_next else None)
         feat_levels.append(jnp.where(is_split, bf, -1))
         mask_levels.append(lm)
         leaf_levels.append(lv)

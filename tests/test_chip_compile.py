@@ -232,10 +232,13 @@ def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
     10, f32 planes, subtraction at every level) for the described v5e: 17
     Mosaic calls, 14 `tree_fused_level` (2 chunks x levels 0 to 6, built
     halves of 1 to 32 nodes) and 3 `tree_hist` (levels 7 to 9, built halves
-    of 64 to 256 nodes, one chunk); temporaries under 1,130 B a row (1,061:
-    5.84 GB, of which the int32 code matrix's row-major `copy` and its `pad`
-    for the hist-mode entry are 2.8 GB each) and, with the arguments, under
-    45 % of the 15.75 GiB a v5e hands out (40 %). About 45 s on the CPU."""
+    of 64 to 256 nodes, one chunk); no `gather` that hands out a value a row
+    (PR 34's program held two, `built_lsb[node >> 1]` at 128 and 256 parents:
+    the build mask comes out of `route_rows` since PR 35); temporaries under
+    1,130 B a row (1,050; 1,061 in PR 34: 5.84 GB, of which the int32 code
+    matrix's row-major `copy` and its `pad` for the hist-mode entry are
+    2.8 GB each) and, with the arguments, under 45 % of the 15.75 GiB a v5e
+    hands out (40 %). About 45 s on the CPU."""
     import json
     import os
     import re
@@ -279,6 +282,8 @@ def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
         14, 3), names
     assert _compiled_kernels(compiled) == 17
     assert tt._tree_kernel_calls(D, lay, sub_levels) == 17
+    assert re.findall(r"= \w+\[%d\][^\n=]* gather\(" % rows, text) == []
+    assert len(re.findall(r" gather\(", text)) > 100  # the pattern's opcode
     mem = compiled.memory_analysis()
     assert 0 < mem.temp_size_in_bytes < 1130 * rows, \
         mem.temp_size_in_bytes / rows

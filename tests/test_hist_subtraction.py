@@ -217,3 +217,86 @@ def test_streamed_levelwise_counters_and_rf_bit_parity(tmp_path):
     off = train_trees_streamed(out, [bins] * f, [False] * f, cols,
                                _cfg_off(cfg))
     _assert_forests_bit_equal(on, off)
+
+
+@pytest.mark.parametrize("depth", [5, 9])
+def test_three_growers_agree_with_and_without_subtraction(monkeypatch,
+                                                          depth):
+    """RF planes (a Poisson bag's whole numbers x 0/1 labels) are exact, so
+    the whole-tree program, the node-batched `build_tree` (stats budget
+    steered down: it subtracts while 2 L nodes fit and rebuilds below) and
+    the streamed grower (three shards; it derives the leaf level too) give
+    one DenseTree and one `resting`, with the build mask `route_rows` hands
+    on (PR 35) as with every node rebuilt. Depth 9 has parents of 128 and
+    256 nodes, the forest cell's widest."""
+    import jax.numpy as jnp
+
+    from shifu_tpu.train import streaming_tree as st
+    from shifu_tpu.train import tree_trainer as tt
+
+    rng = np.random.default_rng(depth)
+    n, F, bins = 6000, 6, 16
+    codes = rng.integers(0, bins, size=(n, F)).astype(np.int32)
+    y = ((codes[:, 0] + codes[:, 1] + codes[:, 2] % 5
+          + rng.integers(0, 10, n)) > bins + 4).astype(np.float32)
+    w = rng.poisson(1.0, size=n).astype(np.float32)
+    slots, is_cat = [bins] * F, [False] * F
+    feat_ok = np.array([True, True, True, False, True, True])
+    cfg = TreeTrainConfig(algorithm="RF", tree_num=1, max_depth=depth,
+                          min_instances_per_node=1, seed=1)
+    dev = (jnp.asarray(codes), jnp.asarray(y), jnp.asarray(w))
+
+    def grow(c):
+        tree, resting = tt.build_tree(*dev, slots, is_cat, c, feat_ok)
+        return tree, np.asarray(resting)
+
+    lay = make_layout(slots, is_cat)
+    assert 2 ** depth <= _node_batch_size(lay.T, cfg.max_stats_memory_mb)
+    grown = {"whole_tree": grow(cfg), "rebuilt": grow(_cfg_off(cfg))}
+
+    # the streamed grower, three shards of unequal length
+    cuts = [0, 1500, 4100, n]
+    shards = [slice(a, b) for a, b in zip(cuts, cuts[1:])]
+
+    class Feed:
+        def codes(self, s):
+            return codes[shards[s]]
+
+    work = [{"labels": jnp.asarray(y[sl]), "w": jnp.asarray(w[sl]),
+             "node": jnp.zeros(sl.stop - sl.start, jnp.int32),
+             "active": jnp.ones(sl.stop - sl.start, bool),
+             "resting": jnp.zeros(sl.stop - sl.start, jnp.int32)}
+            for sl in shards]
+    obs.reset()
+    tree = st._grow_levelwise_streamed(
+        Feed(), work, tt._device_layout(lay, feat_ok), lay, cfg, depth,
+        jnp.asarray, lambda a: a, None)
+    assert _hist_counters()["derived"] == 2 ** depth - 1
+    grown["streamed"] = (tree, np.concatenate(
+        [np.asarray(wk["resting"]) for wk in work]))
+
+    # the node-batched grower: one node short of the whole-tree program
+    cap = 2 ** depth - 1
+    monkeypatch.setattr(tt, "_node_batch_size", lambda *a, **k: cap)
+    plan, _acc64 = tt._sub_plan(cfg, cap)
+    assert any(plan) and not all(plan[1:])
+    obs.reset()
+    grown["node_batched"] = grow(cfg)
+    c = _hist_counters()
+    assert c["derived"] >= 1 and c["fallback_rebuilds"] >= 1
+
+    want_tree, want_resting = grown["rebuilt"]
+    # parents of 2^(D-1) nodes split; at depth 9 some nodes above them do
+    # not, so rows rest before the leaf level too
+    leaves = 2 ** depth - 1
+    assert (want_tree.feature[leaves // 2:leaves] >= 0).any()
+    assert want_resting.max() >= leaves
+    assert depth < 9 or want_resting.min() < leaves
+    for name, (got_tree, got_resting) in grown.items():
+        np.testing.assert_array_equal(got_tree.feature, want_tree.feature,
+                                      name)
+        np.testing.assert_array_equal(got_tree.left_mask,
+                                      want_tree.left_mask, name)
+        np.testing.assert_array_equal(got_tree.leaf_value,
+                                      want_tree.leaf_value, name)
+        np.testing.assert_array_equal(got_resting, want_resting, name)

@@ -1,8 +1,10 @@
 """Row routing (`tree_trainer.route_rows`): the dense form against the
 per-row gather formulation, which is kept here as the plain reference; the
 identity between a scan's `left_mask` and its `rank_flat` that the dense
-form rests on; and the whole-tree program's route scopes, which must hold no
-gather from `codes` or from a level's [L, T] table."""
+form rests on; the build-row mask it hands the next level (histogram
+subtraction) against the two-line gather it replaced in PR 35; and the
+whole-tree program's scopes, which must hold no gather from `codes` or from
+a level's [L, T] table under route, and none a row under hist or route."""
 
 import re
 
@@ -138,6 +140,73 @@ def test_route_rows_past_the_select_cap_is_the_gather_form(monkeypatch, cols,
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
+def built_gather(node, active, left_small):
+    """The reference: `_sub_row_masks` as the growers ran it up to PR 34,
+    on the rows as `route_rows` left them: a row is built iff its node's
+    low bit is its parent's built side, the side a per-row gather names."""
+    built_lsb = jnp.where(left_small, 0, 1)
+    return node >> 1, active & ((node & 1) == built_lsb[node >> 1])
+
+
+BUILT_CASES = [
+    # id, columns, L, share of inactive rows, _ROUTE_SELECT_CAP (None: as is)
+    ("L1", NUMERIC, 1, 0.2, None),
+    ("L4", MIXED, 4, 0.2, None),
+    ("L64", MIXED, 64, 0.2, None),
+    ("L256", NUMERIC, 256, 0.2, None),
+    ("L512", MIXED, 512, 0.2, None),
+    ("L64_all_rows_active", NUMERIC, 64, 0.0, None),
+    ("L64_no_row_active", NUMERIC, 64, 1.0, None),
+    ("L64_words_past_the_cap", MIXED, 64, 0.2, 64),
+    ("L64_nodes_past_the_cap", MIXED, 64, 0.2, 16),
+    ("L256_nodes_past_the_cap", NUMERIC, 256, 0.2, 128),
+]
+
+
+@pytest.mark.parametrize("cols,L,inactive,cap",
+                         [c[1:] for c in BUILT_CASES],
+                         ids=[c[0] for c in BUILT_CASES])
+def test_route_rows_hands_on_the_build_mask_bit_for_bit(monkeypatch, cols, L,
+                                                        inactive, cap):
+    """`route_rows(..., left_small)`'s fourth value is the old form's mask
+    on the rows it moved, and the first three are what it returns without
+    `left_small`. Nodes 1, 4, 7, ... do not split; nodes 0, 5, 10, ... are
+    ties (lc == nc - lc, so the left child is the built one), nodes 2, 7,
+    12, ... have the smaller child on the right by one row."""
+    lay, la, (bf, _br, _rank, _lv, is_split, _g, lm, nc, lc) = _level(
+        cols, L, seed=L + 2)
+    codes, node, active, resting = _rows(lay, L, 4000, seed=L + 2,
+                                         inactive=inactive)
+    i = jnp.arange(L)
+    nc = jnp.where(i % 5 == 0, 2 * lc, jnp.where(i % 5 == 2, 2 * lc - 1, nc))
+    left_small = lc <= nc - lc
+    ls = np.asarray(left_small)
+    assert (np.asarray(lc) == np.asarray(nc - lc)).any() and ls.any()
+    assert L < 4 or not ls.all()
+    if cap is not None:
+        monkeypatch.setattr(tt, "_ROUTE_SELECT_CAP", cap)
+        assert not tt.route_is_dense(L, lay.s_max)
+    base = jnp.int32(L - 1)
+    plain = jax.jit(tt.route_rows)(codes, node, active, resting, bf,
+                                   is_split, lm, base, la.clip)
+    got = jax.jit(tt.route_rows)(codes, node, active, resting, bf, is_split,
+                                 lm, base, la.clip, left_small)
+    assert plain[3] is None
+    for name, w, g in zip(("resting", "node", "active"), plain, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    nhalf, want = built_gather(got[1], got[2], left_small)
+    assert got[3].dtype == want.dtype and got[3].shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got[1] >> 1), np.asarray(nhalf))
+    if inactive < 1.0 and L >= 4:
+        # rows are built on both sides, and some go to the derived child
+        b, nd, act = (np.asarray(a) for a in (got[3], got[1], got[2]))
+        assert (nd[b] % 2 == 0).any() and (nd[b] % 2 == 1).any()
+        assert (act & ~b).any()
+    else:
+        assert np.asarray(got[3]).any() == (inactive < 1.0)
+
+
 def test_the_dense_rule_is_on_static_shapes_alone():
     # 33 slots are 2 mask words a node, 256 slots 8: 4,096 words is the cap
     assert tt._ROUTE_SELECT_CAP == 4096
@@ -232,13 +301,15 @@ def _scoped_eqns(jaxpr, outer=""):
                     yield from _scoped_eqns(inner, stack)
 
 
-def _route_gathers(jaxpr):
-    """(scope, operand shape) of every gather under a tree.L*/route scope."""
+def _gathers(jaxpr, phases="route"):
+    """(scope, operand shape, result shape) of every gather under a
+    tree.L*/<phase> scope."""
     found = []
     for stack, e in _scoped_eqns(jaxpr):
-        scope = re.match(r"tree\.L\d+/route", stack)
+        scope = re.match(r"tree\.L\d+/(%s)" % phases, stack)
         if e.primitive.name == "gather" and scope:
-            found.append((scope.group(0), tuple(e.invars[0].aval.shape)))
+            found.append((scope.group(0), tuple(e.invars[0].aval.shape),
+                          tuple(e.outvars[0].aval.shape)))
     return found
 
 
@@ -258,7 +329,7 @@ def test_whole_tree_program_routes_without_a_2d_gather():
     for d in range(D):
         assert any(s.startswith("tree.L%d/route" % 2**d) for s in stacks)
     two_d = {(n, F)} | {(2**d, lay.T) for d in range(D)}
-    assert [g for g in _route_gathers(jp.jaxpr) if g[1] in two_d] == []
+    assert [g for g in _gathers(jp.jaxpr) if g[1] in two_d] == []
 
     # the reference form, traced the same way, is caught by this reader
     la = tt._device_layout(lay, np.ones(F, bool))
@@ -272,5 +343,52 @@ def test_whole_tree_program_routes_without_a_2d_gather():
     jp_old = jax.make_jaxpr(old)(zi(n, F), zi(n), jnp.ones(n, bool), zi(n),
                                  zi(4), zi(4), zi(4, lay.T),
                                  jnp.ones(4, bool))
-    assert sorted(g[1] for g in _route_gathers(jp_old.jaxpr)
+    assert sorted(g[1] for g in _gathers(jp_old.jaxpr)
                   if g[1] in two_d) == [(4, lay.T), (n, F)]
+
+
+def _row_gathers(jaxpr, n):
+    """(scope, result shape) of every gather that hands out a value a row
+    (a result whose leading axis is the rows') under a tree.L*/hist or
+    tree.L*/route scope."""
+    return [(scope, out) for scope, _operand, out
+            in _gathers(jaxpr, "hist|route") if out[:1] == (n,)]
+
+
+def test_forest_tree_program_has_no_gather_a_row(monkeypatch):
+    """At the forest cell's columns, slots, depth and subtraction plan
+    (benchmarks/configs/higgs_rf.json; a small n that is no level's width),
+    with the kernels the chip runs (interpreted here: fused with subtraction
+    up to 64 nodes, hist mode from 128): nothing under a level's hist or
+    route scope is a gather with a value a row, so the build mask's
+    `built_lsb[node >> 1]` (PR 34: 45 and 47 ms a tree at 128 and 256
+    parents) cannot come back unnoticed."""
+    n, F, slots, D = 600, 28, 33, 10
+    lay = tt.make_layout([slots] * F, [False] * F)
+    monkeypatch.setattr(tt, "_pallas_state",
+                        lambda mesh=None: (True, True, True))
+    key_before = set(tt._PROGRAMS)
+    try:
+        prog = tt._get_tree_program(D, lay, "variance", 5, 0.0,
+                                    sub_levels=(False,) + (True,) * (D - 1))
+    finally:
+        for k in set(tt._PROGRAMS) - key_before:
+            del tt._PROGRAMS[k]  # built under a steered state: never reuse
+    jp = jax.make_jaxpr(prog.fn)(
+        jnp.zeros((n, F), jnp.int32), jnp.zeros((n, F), jnp.int8),
+        jnp.zeros(n), jnp.ones(n), jnp.ones(lay.T, bool))
+    stacks = {stack for stack, _e in _scoped_eqns(jp.jaxpr)}
+    for d in range(D):
+        for what in ("hist", "route"):
+            assert any(s.startswith("tree.L%d/%s" % (2**d, what))
+                       for s in stacks), (d, what)
+    assert _row_gathers(jp.jaxpr, n) == []
+
+    # the form it replaced, traced under such a scope, is caught
+    def old(node, active, left_small):
+        with jax.named_scope("tree.L256/hist"):
+            return built_gather(node, active, left_small)
+
+    jp_old = jax.make_jaxpr(old)(jnp.zeros(n, jnp.int32), jnp.ones(n, bool),
+                                 jnp.ones(128, bool))
+    assert _row_gathers(jp_old.jaxpr, n) == [("tree.L256/hist", (n,))]
