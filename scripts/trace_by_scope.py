@@ -8,9 +8,23 @@ one chip at a time.
 
 Prints one JSON line a chip: busy and window seconds, seconds by scope (cut
 to `--depth` parts; the level folded out of `tree.L<n>/<phase>` under
-`by_phase`), and the `--top` largest operations. Needs tensorflow's copy of
+`by_phase`; `tree.codes` read wherever it stands in the stack, so
+`tree.L1/hist/tree.codes/pad` is `tree.codes`' and not the level's), and the
+`--top` largest operations. Needs tensorflow's copy of
 the xplane protobuf (`jax.profiler.ProfileData` hands out no `tf_op`), which
-the benchmark itself does not. PERF.md section 5 is read with it."""
+the benchmark itself does not.
+
+Since PR 36 the benchmark reads the same split without this script and
+without a kept file: `benchmarks/lib/scopes.py` joins the traced window's
+events to `obs.profile.scope_table()` by instruction name, and ten per-layer
+metrics report it in every traced run (`tree_route_ms_per_tree`, ...,
+`nn_bwd_ms_per_epoch`). What this script is still for: a kept file read
+after the run (an xprof session of `-Dshifu.profile=xla` too), every chip
+of a mesh and not the first alone, the split by level (`by_scope` keeps
+`tree.L128/route` apart where the metrics fold the level out), the largest
+operations with their `tf_op`, and a check of the join itself: on one
+traced run the two agree to the digit wherever `scope_table` has the
+window's executables (PERF.md section 6, PR 36)."""
 
 from __future__ import annotations
 
@@ -28,6 +42,7 @@ from benchmarks.lib import xplane  # noqa: E402
 # (`tree.L4`, `nn.bwd`, `wdl.update`) or as jax wraps it under a `grad`
 # (`jvp(wdl.embed)`, `transpose(jvp(wdl.embed))`)
 SCOPE = re.compile(r"(?:^|\()(?:tree|nn|wdl)\.")
+CODES = "tree.codes"
 
 
 def _stat_value(plane, stat):
@@ -87,6 +102,10 @@ def by_scope(path: str, depth: int = 2, top: int = 12) -> list:
             at = next((i for i, p in enumerate(parts) if SCOPE.search(p)),
                       None)
             key = "/".join(parts[at:at + depth]) if at is not None else "-"
+            if CODES in parts:
+                # written inside a level's `hist` in the whole-tree
+                # program: the code operand's, not that level's
+                key = CODES
             scopes[key] = scopes.get(key, 0.0) + ns * 1e-9
             m = re.match(r"tree\.L\d+/(\w+)", key)
             ph = (m.group(1) if m else "psum" if key == "tree.leaf/psum"

@@ -72,11 +72,12 @@ class _CostEntry:
     executable with the OLD closure's constants baked in."""
 
     __slots__ = ("fn", "compiled", "flops", "bytes_accessed", "peak_hbm",
-                 "arg_bytes", "compile_seconds", "source")
+                 "arg_bytes", "compile_seconds", "source", "scope_map")
 
     def __init__(self, fn: Optional[Callable] = None) -> None:
         self.fn = fn
         self.compiled = None
+        self.scope_map: Optional[Dict[str, Tuple[str, str]]] = None
         self.flops: Optional[float] = None
         self.bytes_accessed: Optional[float] = None
         self.peak_hbm: Optional[float] = None
@@ -429,6 +430,75 @@ def compiled_texts(name: str) -> List[str]:
     with _cost_lock:
         entries = [e for k, e in _cost_cache.items() if k[0] == name]
     return [e.compiled.as_text() for e in entries if e.compiled is not None]
+
+
+def result_shape(text: str, at: int, stop: Optional[int] = None) -> str:
+    """The result shape an instruction's text carries at `at`, just after
+    its `%name = `, without layouts and index comments: `f32[64,16]`,
+    `(f32[1,512], s32[])`. A device trace's event names print it the same
+    way, so a reader of both compares the two through this."""
+    import re
+
+    stop = len(text) if stop is None else stop
+    if text.startswith("(", at):  # a tuple: to its closing parenthesis
+        depth = 0
+        for end in range(at, stop):
+            depth += {"(": 1, ")": -1}.get(text[end], 0)
+            if not depth:
+                break
+        end += 1
+    else:
+        end = text.find(" ", at, stop)
+    return re.sub(r"\{[^{}]*\}|/\*[^*]*\*/", "", text[at:end])
+
+
+def _instruction_scopes(hlo_text: str) -> Dict[str, Tuple[str, str]]:
+    """{instruction name: (result shape, op_name)} of a compiled module's
+    text. The op_name is the instruction's own metadata ('' where it has
+    none: a parameter, or what the compiler made itself); a fusion's line
+    carries its root's. An instruction may run over several lines (a
+    Mosaic call prints its `kernel_metadata` on lines of their own), so
+    each runs from its `%name = ` to the next one's."""
+    import re
+
+    head = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = ", re.M)
+    scope = re.compile(r'metadata=\{op_name="((?:[^"\\]|\\.)*)"')
+    out: Dict[str, Tuple[str, str]] = {}
+    heads = list(head.finditer(hlo_text))
+    for m, nxt in zip(heads, heads[1:] + [None]):
+        stop = len(hlo_text) if nxt is None else nxt.start()
+        op = scope.search(hlo_text, m.end(), stop)
+        out[m.group(1)] = (result_shape(hlo_text, m.end(), stop),
+                           op.group(1) if op else "")
+    return out
+
+
+def scope_table() -> List[Tuple[str, Dict[str, Tuple[str, str]]]]:
+    """Which named scope each instruction of the kept executables belongs
+    to: one `(seam, {instruction name: (result shape, op_name)})` for
+    every executable the cost cache holds, whatever its seam. A device
+    trace names an event by its instruction
+    (`%fusion.168 = s32[5500000]{0} fusion(...)`); the `op_name` of that
+    instruction in the compiled module carries the program's
+    `jax.named_scope`s (`jit(f)/tree.L4/route/...`), so the two joined by
+    name give device time by scope with no trace file kept. The shape
+    (layouts cut) tells apart a name that two executables both use.
+
+    A map is built from `compiled.as_text()` the first time it is asked
+    for and kept on the cache entry (the text itself, megabytes for a
+    program that holds Mosaic kernels, is not); a dispatch pays nothing.
+    Empty where `compiled_texts` is: profiler off, entry evicted, or the
+    ahead-of-time compile fell back to plain jit."""
+    with _cost_lock:
+        entries = [(k[0], e) for k, e in _cost_cache.items()]
+    out = []
+    for seam, e in entries:
+        if e.compiled is None:
+            continue
+        if e.scope_map is None:
+            e.scope_map = _instruction_scopes(e.compiled.as_text())
+        out.append((seam, e.scope_map))
+    return out
 
 
 def dispatch(name: str, fn: Callable, *args, sync: bool = True,

@@ -569,7 +569,9 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
             pl.BlockSpec((1, W), lambda i: (0, 0)),
             pl.BlockSpec((1, W), lambda i: (0, 0)),
         ]
-        args = [codes_chunk.astype(code_dt), comps, node_row,
+        with jax.named_scope("tree.codes"):
+            codes_chunk = codes_chunk.astype(code_dt)
+        args = [codes_chunk, comps, node_row,
                 featok.astype(jnp.float32),
                 jnp.asarray(pos_np), jnp.asarray(clip_np),
                 jnp.asarray(featrel_np)]
@@ -679,6 +681,7 @@ def make_pallas_hist_fn(L: int, lay, n_classes: int = 0,
     contract (the hist-subtraction built-child, budget-batched,
     leaf-wise and streamed/shard_map call sites). `interpret=True` runs
     the kernels in pallas interpret mode (CPU tests)."""
+    import jax
     import jax.numpy as jnp
 
     C = n_classes if n_classes >= 3 else 3
@@ -692,14 +695,16 @@ def make_pallas_hist_fn(L: int, lay, n_classes: int = 0,
         blk = _block_rows(codes.shape[0], blk_max)
         comps_p, node_p = _row_operands(labels, weights, active, node_slot,
                                         L, n_classes, comp_dt, blk)
-        codes_p = _pad_rows(codes, blk, 0)
+        with jax.named_scope("tree.codes"):
+            codes_p = _pad_rows(codes, blk, 0)
         parts = []
         for ci, ch in enumerate(chunks):
             call = _build_call(lay.key, target, ci, L, C, blk, False,
                                low_precision, None, interpret)
             featok = jnp.ones((1, ch.w), jnp.float32)
-            outs = call(codes_p[:, ch.f_lo:ch.f_hi], comps_p, node_p,
-                        featok)
+            with jax.named_scope("tree.codes"):
+                codes_c = codes_p[:, ch.f_lo:ch.f_hi]
+            outs = call(codes_c, comps_p, node_p, featok)
             planes = jnp.stack(outs[:C])  # [C, L, W]
             parts.append(planes[:, :, jnp.asarray(ch.keep)])
         return (parts[0] if len(parts) == 1
@@ -712,13 +717,15 @@ def make_codes8_fn(lay):
     """jit-able (codes [n, F] i32) -> [n, F] int8 low-bandwidth code
     planes: exact for every feature with <= 128 slots (the int8-eligible
     chunks); wide features keep reading the i32 matrix."""
+    import jax
     import jax.numpy as jnp
 
     cap = np.minimum(lay.clip_max, _LANE - 1).astype(np.int32)
 
     def build(codes):
-        return jnp.clip(codes, 0, jnp.asarray(cap)[None, :]).astype(
-            jnp.int8)
+        with jax.named_scope("tree.codes"):
+            return jnp.clip(codes, 0, jnp.asarray(cap)[None, :]).astype(
+                jnp.int8)
 
     return build
 
@@ -736,6 +743,7 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
     tree_trainer's per-level hist+scan pair. `codes8` may be None (i32
     codes everywhere); when given, int8-eligible chunks read it instead
     of the i32 matrix."""
+    import jax
     import jax.numpy as jnp
 
     C = n_classes if n_classes >= 3 else 3
@@ -784,9 +792,10 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
         blk = _block_rows(codes.shape[0], blk_max)
         comps_p, node_p = _row_operands(labels, weights, active, node_slot,
                                         L, n_classes, comp_dt, blk)
-        codes_p = _pad_rows(codes, blk, 0)
-        codes8_p = (_pad_rows(codes8, blk, 0) if codes8 is not None
-                    else None)
+        with jax.named_scope("tree.codes"):
+            codes_p = _pad_rows(codes, blk, 0)
+            codes8_p = (_pad_rows(codes8, blk, 0) if codes8 is not None
+                        else None)
         fok_f = feat_ok_t.astype(jnp.float32)
 
         hist_parts, gain_parts, rank_parts, lcnt_parts = [], [], [], []
@@ -802,7 +811,9 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
             fok = (fok_f[jnp.asarray(t_clamp)]
                    * jnp.asarray((ch.scan_ok > 0)
                                  & (ch.pos >= 0), np.float32))[None, :]
-            outs = call(src[:, ch.f_lo:ch.f_hi], comps_p, node_p, fok)
+            with jax.named_scope("tree.codes"):
+                codes_c = src[:, ch.f_lo:ch.f_hi]
+            outs = call(codes_c, comps_p, node_p, fok)
             planes = jnp.stack(outs[:C])
             hist_parts.append(planes[:, :, jnp.asarray(ch.keep)])
             gain_parts.append(outs[C])

@@ -381,7 +381,8 @@ def _make_hist_fn(L: int, lay: FeatureLayout, allow_matmul: bool = True,
         blk = min(BLK, n)
         n_pad = -(-n // blk) * blk
         pad = n_pad - n
-        codes_p = jnp.pad(codes, ((0, pad), (0, 0)))
+        with jax.named_scope("tree.codes"):
+            codes_p = jnp.pad(codes, ((0, pad), (0, 0)))
         nl_p = jnp.pad(nl, (0, pad))
         comps_p = jnp.pad(comps, ((0, pad), (0, 0)))
 
@@ -1344,7 +1345,10 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         # and interleave, `scan` the XLA scan where it runs, `route` the
         # rows' move to their children; under a mesh `psum` is the
         # all-reduce of the level's histogram (`tree.leaf/psum`: of the
-        # leaf totals).
+        # leaf totals). The code operand's pad, cut and cast carry
+        # `tree.codes` (ops/hist_pallas.py): inside a level's `hist`
+        # here, a scope of its own in the programs that have no level
+        # (`tree.hist`, `tree.codes8`).
         def phase(L, what):
             return jax.named_scope("tree.L%d/%s" % (L, what))
 
