@@ -15,34 +15,49 @@ and the scan. This kernel keeps BOTH in VMEM:
     grid (row blocks)  — per-chunk VMEM-resident [L, W] accumulator per
                          component, revisited across the grid (init at
                          block 0, += afterwards)
-    per block          — the chunk's code one-hot M [blk, W] is built by
-                         ONE broadcast-compare over a DENSELY PACKED
-                         column layout (below); the component planes
-                         times the node one-hot, [C x L, blk] with the
-                         ROWS ALONG THE LANES (below), contract the row
-                         axis with M on the MXU
+    per block          — the chunk's code one-hot MT [W, blk] is built
+                         with the ROWS ALONG THE LANES and no matmul: a
+                         feature's code row is broadcast down the
+                         sublanes of that feature's columns and ONE
+                         compare with what each column stands for gives
+                         the one-hot, over a DENSELY PACKED column
+                         layout (below); the component planes times the
+                         node one-hot, [C x L, blk], contract the row
+                         axis with MT on the MXU, both along their lanes
     last block         — the split scan runs in-kernel on the resident
                          planes (pairwise-rank formulation, below) and
                          emits per-column gain/rank/left-count planes,
                          so the histogram never has to be re-read from
                          HBM by a second scan dispatch
 
-Operands, all zero-padded to whole blocks of rows. The codes come as
-they lie, `[n_pad, nf]` int8 or int32, a row to a sublane. The two
-per-row operands the wrapper makes every level come with the ROWS ALONG
-THE LANES: component planes `[C, n_pad]` (bf16 or f32) and node ids
-`[1, n_pad]` (int32), in `(C, blk)` / `(1, blk)` blocks. As `[n, C]`
-and `[n, 1]` they would put 3 or 1 values on the 128-lane axis: XLA
-writes, the DMA moves and the step's vregs hold 128 lanes a row for them
-(2.8 GB written a level for 22 MB of node ids at 5.5 M rows), and the
-`[blk, L]` LHS has to be turned for the MXU. This way the node one-hot
-`[L, blk]` is a sublane broadcast of the id row, and `A [.., blk] x
-M [blk, W]` is the MXU's own orientation. While C x L (L in whole
-sublane tiles) fits the MXU's 128 rows, the C components share
-ONE stacked LHS, so M is pushed as weights once a step and not once a
-component; wider levels stream enough rows a component to pay for their
-own push and keep a dot each (static shapes alone decide). PERF.md,
-sections 5 and 6, has what each costs on the chip.
+Operands, all zero-padded to whole blocks of rows and all three with the
+ROWS ALONG THE LANES: component planes `[C, n_pad]` (bf16 or f32) and
+node ids `[1, n_pad]` (int32), which the wrapper makes every level, and
+the codes `[F, n_pad]` (`make_codes8_fn`: clipped, int8 where every
+feature of the layout has at most 128 slots, int32 otherwise), which a
+grower makes ONCE A CALL, on one chip and on each chip's shard under a
+mesh, so that no cut, cast or layout copy of the code matrix is left
+inside a tree, only the operand's pad to whole blocks; an entry handed
+`codes [n, F]` alone turns it where it pads the planes. Blocks `(C, blk)`, `(1, blk)` and `(rows of F,
+blk)`, where a chunk's kernel reads the smallest aligned run of sublane
+tiles that holds its features (`_code_window`; all 28 rows for HIGGS,
+16 KB a step) and takes them by static sublane slices. As `[n, C]`,
+`[n, 1]` and `[n, nf]` they would put 3, 1 or 13 to 28 values on the
+128-lane axis: XLA writes, the DMA moves and the step's vregs hold 128
+lanes a row for them (2.8 GB written a level for 22 MB of node ids at
+5.5 M rows; 128 B a row of an int8 chunk and 512 B of the int32 matrix
+for its codes), the `[blk, L]` LHS has to be turned for the MXU, and a
+row's code of feature f has to be moved sideways into the 33 columns of
+that feature by a matmul of its own (`codes_f @ sel`, 256 of the step's
+304 `vmatmul` until PR 38). This way the node one-hot `[L, blk]` and
+the code one-hot `[W, blk]` are each sublane broadcasts of rows and one
+compare, and `A [.., blk] x MT [W, blk]^T` is the MXU's own transposed
+weight push. While C x L (L in whole sublane tiles) fits the MXU's 128
+rows, the C components share ONE stacked LHS, so MT is pushed as
+weights once a step and not once a component; wider levels stream
+enough rows a component to pay for their own push and keep a dot each
+(static shapes alone decide). PERF.md, sections 5 and 6, has what each
+costs on the chip.
 
 Three changes over the round-5 kernel (which was slower than the XLA
 lowering and shipped dark behind an env var):
@@ -50,14 +65,26 @@ lowering and shipped dark behind an env var):
 1. DENSELY PACKED COLUMN LAYOUT, NO PER-FEATURE STORES. The old kernel
    wrote each feature's one-hot segment at its raw flat-T offset with
    per-run slice stores; 33/65-wide segments land mid-lane and Mosaic
-   emits masked unaligned lane stores. The rebuilt kernel builds M with
-   zero per-feature stores: a static selection matmul broadcasts each
-   column's code (codes_f32 @ E, exact in f32), then one full-width
-   compare against the static slot-position row writes the whole
-   [blk, W] block at once. A chunk's feature pieces sit SIDE BY SIDE in
-   the lanes and only the chunk's total width is rounded up to 128
-   (the tail is dead columns, masked out of the gain scan and dropped
-   at the [C, L, T] compaction — the output contract is unchanged).
+   emits masked unaligned lane stores. The rebuilt kernel builds the
+   one-hot with no store at all: the code row of the feature that holds
+   a tile of 8 columns (of the two features, where a tile straddles a
+   boundary: one select more) is broadcast down the tile's sublanes,
+   the tiles lie one under the other as `[W, blk]`, and one compare
+   with the static slot-position column, handed over the same in all
+   128 lanes, gives the one-hot; Mosaic packs the compare's masks and
+   pushes them to the MXU as they are (PR 38; until then a static
+   selection matmul, codes_f32 @ E, moved each column's code into place
+   first, and was half of a grid step's MXU time). Every row's
+   broadcast and every straddle's mask is made once a body, in plain
+   `lax`: 2 operations a feature and 1 a straddle, so that a body costs
+   what the old one did to trace and lower (a first form, a compare a
+   tile of 8 columns and a `want` a slab of 128, was as fast on the chip
+   and three times as dear to trace: PERF.md, section 6, PR 38).
+   A chunk's feature pieces sit SIDE BY SIDE in the columns and only
+   the chunk's total width is rounded up to 128 (the tail is dead
+   columns, which stand for -1 and match no code, are masked out of the
+   gain scan and dropped at the [C, L, T] compaction — the output
+   contract is unchanged).
    Until PR 29 every piece started at a 128-lane boundary, a leftover
    of the per-feature stores: nothing in this kernel finds a column by
    its position (the scan goes by the seg / size / iscat metadata
@@ -65,10 +92,9 @@ lowering and shipped dark behind an env var):
    slots 3,584 columns for 924 and 7 calls a level for 2 (PERF.md,
    section 6).
 
-2. LOW-PRECISION PLANES. Bin codes travel int8 in HBM for chunks whose
-   features all fit 128 slots (4x less code-read bandwidth than i32 —
-   the kernel's roofline is expected to be code-read-bound; wide chunks
-   stay i32).
+2. LOW-PRECISION PLANES. Bin codes travel int8 in HBM where every
+   feature of the layout fits 128 slots (a layout with a wider feature
+   keeps int32 codes for all its chunks: one operand, one dtype).
    GBT gradient/hessian component planes travel bf16 with f32 MXU
    accumulation (`preferred_element_type`); RF planes stay f32 so
    integer-weight counts stay exact and PR-3's bit-parity gate holds
@@ -113,9 +139,10 @@ import numpy as np
 _LANE = 128  # TPU lane width: a chunk's total width is a multiple of it
 
 # VMEM budget shaping: rows per grid step x max padded chunk columns.
-# M [BLK, W] + the [W, W] scan indicator + C [L, W] planes must sit well
-# under ~16 MB. Overridable per PROCESS (-Dshifu.pallas.blk /
-# -Dshifu.pallas.wmax) so kernel-tuning rounds can sweep shapings
+# The row operands' blocks, the [W, W] scan indicator + C [L, W] planes
+# must sit well under ~16 MB (MT itself never leaves the vregs).
+# Overridable per PROCESS (-Dshifu.pallas.blk / -Dshifu.pallas.wmax) so
+# kernel-tuning rounds can sweep shapings
 # without code edits — per process because the built kernels are cached
 # (_build_call lru, tree_trainer's program cache): set the knobs at
 # launch, one process per shaping.
@@ -196,9 +223,8 @@ class _Chunk:
     the epilogue need. Only the tail past the last piece is dead
     (`pos` = `seg` = -1, `scan_ok` = 0)."""
 
-    __slots__ = ("pieces", "w", "f_lo", "f_hi", "pos", "feat_rel", "clip",
-                 "seg", "size", "iscat", "scan_ok", "seg0", "t_idx",
-                 "keep", "narrow", "start")
+    __slots__ = ("pieces", "w", "f_lo", "f_hi", "pos", "seg", "size",
+                 "iscat", "scan_ok", "seg0", "t_idx", "keep", "start")
 
     def __init__(self, pieces, lay, whole):
         self.pieces = pieces
@@ -207,8 +233,6 @@ class _Chunk:
         w = _pad_lane(pieces[-1][3] + pieces[-1][2] - pieces[-1][1])
         self.w = w
         pos = np.full(w, -1, np.int32)
-        feat_rel = np.zeros(w, np.int32)
-        clip = np.zeros(w, np.int32)
         seg = np.full(w, -1, np.int32)
         size = np.ones(w, np.int32)
         iscat = np.zeros(w, np.int32)
@@ -220,8 +244,6 @@ class _Chunk:
             cw = hi - lo
             sl = slice(col0, col0 + cw)
             pos[sl] = np.arange(lo, hi, dtype=np.int32)
-            feat_rel[sl] = f - self.f_lo
-            clip[sl] = int(lay.clip_max[f])
             seg[sl] = f
             size[sl] = int(lay.slots[f])
             iscat[sl] = int(bool(lay.is_cat_t[lay.off[f]]))
@@ -230,13 +252,10 @@ class _Chunk:
             t_idx[sl] = np.arange(int(lay.off[f]) + lo,
                                   int(lay.off[f]) + hi, dtype=np.int64)
             start[sl] = int(lay.off[f])
-        self.pos, self.feat_rel, self.clip = pos, feat_rel, clip
-        self.seg, self.size, self.iscat = seg, size, iscat
+        self.pos, self.seg, self.size, self.iscat = pos, seg, size, iscat
         self.scan_ok, self.seg0, self.t_idx = scan_ok, seg0, t_idx
         self.start = start
         self.keep = np.nonzero(pos >= 0)[0].astype(np.int64)
-        self.narrow = all(int(lay.slots[f]) <= _LANE
-                          for (f, _lo, _hi, _c0) in pieces)
 
 
 def _target(fused: bool = False, target: Optional[int] = None) -> int:
@@ -273,8 +292,7 @@ def _chunks(lay, target: Optional[int] = None) -> List[_Chunk]:
             # split would scan partial histograms — start a fresh chunk
             # instead (only over-wide features split, and those are the
             # epilogue's XLA-fallback set; a piece of one joins a chunk
-            # only for a lane or more, since it turns the chunk's codes
-            # from int8 to int32)
+            # only for a lane or more)
             fresh = s > avail if whole[f] else avail < _LANE
             if fresh:
                 chunks.append(_Chunk(cur, lay, whole))
@@ -309,15 +327,32 @@ def kernel_name(do_scan: bool) -> str:
     return "tree_fused_level" if do_scan else "tree_hist"
 
 
+def code_dtype(lay):
+    """The code operand's dtype, from the layout's slot counts alone: int8
+    where every feature has at most 128 slots, int32 otherwise."""
+    return np.int8 if int(max(lay.slots, default=0)) <= _LANE else np.int32
+
+
+def _code_window(ch: _Chunk, lay) -> tuple:
+    """(rows, block index) of the block of the `[F, n]` code operand a
+    chunk's kernel reads: the smallest power-of-two run of the dtype's
+    sublane tiles that holds the chunk's features in one aligned block, or
+    all F rows (the block is then the array's whole first axis)."""
+    rows = 32 if code_dtype(lay) == np.int8 else 8
+    while ch.f_lo // rows != (ch.f_hi - 1) // rows:
+        rows *= 2
+    n_feat = len(lay.slots)
+    return (n_feat, 0) if rows >= n_feat else (rows, ch.f_lo // rows)
+
+
 @functools.lru_cache(maxsize=None)
 def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
-                blk: int, code_i8: bool, lowp: bool, scan_key,
-                interpret: bool):
+                blk: int, lowp: bool, scan_key, interpret: bool):
     """One chunk's pallas_call builder, cached per static configuration.
 
-    Returns call(codes_chunk [n, nf], comps [C, n], node [1, n],
-    featok [1, W]) -> (C hist planes [L, W], + when scan_key:
-    gain [L, W], rank [L, W], lcnt [L, W], tot0 [L, C]).
+    Returns call(codes_t [F, n], comps [C, n], node [1, n],
+    featok [1, W] or None in hist mode) -> (C hist planes [L, W], + when
+    scan_key: gain [L, W], rank [L, W], lcnt [L, W], tot0 [L, C]).
 
     scan_key = None (hist-only) or (impurity, min_inst, min_gain,
     n_classes)."""
@@ -331,15 +366,14 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
     lay = make_layout(list(lay_key[0]), list(lay_key[1]))
     ch = _chunks(lay, target)[ci]
     W = ch.w
-    nf = ch.f_hi - ch.f_lo
+    f_rows, f_blk = _code_window(ch, lay)
     do_scan = scan_key is not None
     name = kernel_name(do_scan)
     comp_dt = jnp.bfloat16 if lowp else jnp.float32
-    m_dt = comp_dt
     # the accumulate step's LHS: all C components stacked, each on L
     # rounded up to whole sublane tiles of the planes' dtype, while that
     # fits the MXU's rows; past it a component streams enough rows of its
-    # own to pay for its push of M, and each keeps its dot
+    # own to pay for its push of MT, and each keeps its dot
     sub = 16 if lowp else 8
     tiled = -(-L // sub) * sub
     group, rows = (C, tiled) if C * tiled <= _MXU_ROWS else (1, L)
@@ -347,11 +381,22 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
         impurity, min_inst, min_gain, n_classes = scan_key
         use_entropy = impurity == "entropy"
 
+    # MT's recipe, from static shapes alone: a tile of 8 columns
+    # [r0, r0 + 8) names the pieces that hold them, each as (the feature's
+    # row in the code block, the tile's first sublane the piece holds)
+    f_base = f_blk * f_rows
+    tile_rows = [
+        [(f - f_base, max(col0 - r0, 0)) for (f, lo, hi, col0) in ch.pieces
+         if col0 < r0 + 8 and col0 + hi - lo > r0]
+        for r0 in range(0, W, 8)]
+
     # static column metadata rides in as [1, W] / [W, 1] inputs (vector
-    # constants are inputs, not closure captures, in Mosaic)
+    # constants are inputs, not closure captures, in Mosaic): what each
+    # column stands for as a column, for MT (the wrapper hands it over the
+    # same in all 128 lanes: a lane broadcast in the step would be 288 XLU
+    # operations and 11 % more bundles), and the scan's rows
+    posc_np = ch.pos[:, None]
     pos_np = ch.pos[None, :]
-    clip_np = ch.clip[None, :]
-    featrel_np = ch.feat_rel[None, :]
     seg_row_np = ch.seg[None, :]
     seg_col_np = ch.seg[:, None]
     iscat_np = ch.iscat[None, :]
@@ -359,59 +404,79 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
     seg0_np = ch.seg0[:, None]
 
     def kernel(*refs):
-        (codes_ref, comps_ref, node_ref, featok_ref, pos_ref, clip_ref,
-         featrel_ref) = refs[:7]
-        k = 7
+        codes_ref, comps_ref, node_ref, posc_ref = refs[:4]
+        k = 4
         if do_scan:
-            (segr_ref, segc_ref, iscat_ref, size_ref, seg0_ref) = \
-                refs[k:k + 5]
-            k += 5
+            (featok_ref, pos_ref, segr_ref, segc_ref, iscat_ref, size_ref,
+             seg0_ref) = refs[k:k + 7]
+            k += 7
         hist_refs = refs[k:k + C]
         k += C
         if do_scan:
             gain_ref, rank_ref, lcnt_ref, tot0_ref = refs[k:k + 4]
-            k += 4
-        m_ref = refs[k]
-        if do_scan:
-            wsq_ref, sec_ref, secT_ref = refs[k + 1:k + 4]
+            wsq_ref, sec_ref, secT_ref = refs[k + 4:k + 7]
 
         i = pl.program_id(0)
         grid_n = pl.num_programs(0)
-
-        # ---- M build: selection matmul + one full-width compare (no
-        # per-feature stores: those land mid-lane, unaligned) ----
-        codes_f = codes_ref[...].astype(jnp.float32)  # [blk, nf]
-        sel = (jax.lax.broadcasted_iota(jnp.int32, (nf, W), 0)
-               == featrel_ref[...]).astype(jnp.float32)  # [nf, W]
-        cb = jax.lax.dot_general(
-            codes_f, sel, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [blk, W]: code per col
-        cb = jnp.clip(cb, 0.0, clip_ref[...].astype(jnp.float32))
-        # tail columns carry pos -1: clipped codes are >= 0, so M is 0
-        m_ref[...] = (cb == pos_ref[...].astype(jnp.float32)).astype(m_dt)
-
-        # rows along the lanes: the node one-hot is a sublane broadcast
-        # of the [1, blk] ids, and A [.., blk] meets M [blk, W] in the
-        # MXU's own orientation, with nothing to transpose
-        comps = comps_ref[...]  # [C, blk]
-        M = m_ref[...]
 
         @pl.when(i == 0)
         def _init():
             for out_ref in hist_refs:
                 out_ref[...] = jnp.zeros_like(out_ref)
 
-        # the components of one group share a stacked LHS, so M is pushed
-        # to the MXU as weights once a group and not once a component;
-        # a component's rows start on a tile boundary, and those past L
-        # match no node id
-        oh_node = (node_ref[...] == jax.lax.broadcasted_iota(
+        # ---- MT [W, blk], the code one-hot with the rows along the
+        # lanes, built with no dot and no store: a feature's code row is
+        # broadcast down the sublanes of the tiles of 8 columns it holds
+        # (a tile that straddles two features selects between two rows),
+        # the tiles lie one under the other, and ONE compare with what
+        # each column stands for gives the one-hot; the dead tail stands
+        # for -1, which no clipped code is. Plain `lax` on numpy scalars,
+        # every row and every mask made once: a `jnp` operation is a
+        # traced call of its own, and what a body costs to trace and
+        # lower it costs in every process's set-up (PERF.md, section 6,
+        # PR 38) ----
+        lax = jax.lax
+        codes = codes_ref[...].astype(jnp.int32)  # [f_rows, blk]
+        sub8 = lax.broadcasted_iota(jnp.int32, (8, blk), 0)
+        code_rows, from_sub = {}, {}
+
+        def code_row(r):
+            if r not in code_rows:
+                code_rows[r] = lax.broadcast_in_dim(
+                    lax.slice_in_dim(codes, r, r + 1, axis=0), (8, blk),
+                    (0, 1))
+            return code_rows[r]
+
+        def tile_codes(pieces):
+            code = sub8  # a dead tile: any code
+            for n, (r, s0) in enumerate(pieces):
+                if n and s0 not in from_sub:
+                    from_sub[s0] = lax.ge(sub8, np.int32(s0))
+                code = (lax.select(from_sub[s0], code_row(r), code) if n
+                        else code_row(r))
+            return code
+
+        code = lax.concatenate([tile_codes(p) for p in tile_rows], 0)
+        # what each column stands for, the same in every lane: the
+        # [W, 128] operand side by side as often as the block is wide
+        want = lax.concatenate([posc_ref[...]] * -(-blk // _LANE), 1)
+        if want.shape[1] != blk:
+            want = lax.slice_in_dim(want, 0, blk, axis=1)
+        mt = lax.eq(code, want).astype(comp_dt)  # [W, blk]
+
+        # rows along the lanes: the node one-hot is a sublane broadcast
+        # of the [1, blk] ids. The components of one group share a
+        # stacked LHS, so MT is pushed to the MXU as weights once a group
+        # and not once a component; a component's rows start on a tile
+        # boundary, and those past L match no node id
+        comps = comps_ref[...]  # [C, blk]
+        oh_node = (node_ref[...] == lax.broadcasted_iota(
             jnp.int32, (rows, blk), 0)).astype(comp_dt)
         for c0 in range(0, C, group):
             A = jnp.concatenate([comps[c:c + 1, :] * oh_node
                                  for c in range(c0, c0 + group)], axis=0)
-            contrib = jax.lax.dot_general(
-                A, M, (((1,), (0,)), ((), ())),
+            contrib = lax.dot_general(
+                A, mt, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [group * rows, W]
             for k in range(group):
                 hist_refs[c0 + k][...] += contrib[k * rows:k * rows + L, :]
@@ -555,35 +620,30 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
                 preferred_element_type=jnp.float32) for c in range(C)]
             tot0_ref[...] = jnp.concatenate(tot_cols, axis=1)  # [L, C]
 
-    def call(codes_chunk, comps, node_row, featok):
+    def call(codes_t, comps, node_row, featok=None):
         import jax.numpy as jnp
 
-        grid = codes_chunk.shape[0] // blk
-        code_dt = jnp.int8 if code_i8 else jnp.int32
+        grid = codes_t.shape[1] // blk
         in_specs = [
-            pl.BlockSpec((blk, nf), lambda i: (i, 0)),
+            pl.BlockSpec((f_rows, blk), lambda i: (f_blk, i)),
             pl.BlockSpec((C, blk), lambda i: (0, i)),
             pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, W), lambda i: (0, 0)),
-            pl.BlockSpec((1, W), lambda i: (0, 0)),
-            pl.BlockSpec((1, W), lambda i: (0, 0)),
-            pl.BlockSpec((1, W), lambda i: (0, 0)),
+            pl.BlockSpec((W, _LANE), lambda i: (0, 0)),
         ]
-        with jax.named_scope("tree.codes"):
-            codes_chunk = codes_chunk.astype(code_dt)
-        args = [codes_chunk, comps, node_row,
-                featok.astype(jnp.float32),
-                jnp.asarray(pos_np), jnp.asarray(clip_np),
-                jnp.asarray(featrel_np)]
+        args = [codes_t, comps, node_row,
+                jnp.broadcast_to(jnp.asarray(posc_np), (W, _LANE))]
         if do_scan:
             in_specs += [
+                pl.BlockSpec((1, W), lambda i: (0, 0)),
+                pl.BlockSpec((1, W), lambda i: (0, 0)),
                 pl.BlockSpec((1, W), lambda i: (0, 0)),
                 pl.BlockSpec((W, 1), lambda i: (0, 0)),
                 pl.BlockSpec((1, W), lambda i: (0, 0)),
                 pl.BlockSpec((1, W), lambda i: (0, 0)),
                 pl.BlockSpec((W, 1), lambda i: (0, 0)),
             ]
-            args += [jnp.asarray(seg_row_np), jnp.asarray(seg_col_np),
+            args += [featok.astype(jnp.float32), jnp.asarray(pos_np),
+                     jnp.asarray(seg_row_np), jnp.asarray(seg_col_np),
                      jnp.asarray(iscat_np), jnp.asarray(size_np),
                      jnp.asarray(seg0_np)]
         out_specs = [pl.BlockSpec((L, W), lambda i: (0, 0))
@@ -595,11 +655,11 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
                 + [pl.BlockSpec((L, C), lambda i: (0, 0))]
             out_shape += [jax.ShapeDtypeStruct((L, W), jnp.float32)] * 3 \
                 + [jax.ShapeDtypeStruct((L, C), jnp.float32)]
-        scratch = [pltpu.VMEM((blk, W), m_dt)]
+        scratch = []
         if do_scan:
-            scratch += [pltpu.VMEM((W, W), jnp.float32),
-                        pltpu.VMEM((L, W), jnp.float32),
-                        pltpu.VMEM((W, L), jnp.float32)]
+            scratch = [pltpu.VMEM((W, W), jnp.float32),
+                       pltpu.VMEM((L, W), jnp.float32),
+                       pltpu.VMEM((W, L), jnp.float32)]
         outs = pl.pallas_call(
             kernel,
             grid=(grid,),
@@ -660,28 +720,63 @@ def _row_operands(labels, weights, active, node_slot, L: int,
     return _pad_rows(comps, blk, 1), _pad_rows(nl[None, :], blk, 1)
 
 
-def _annotate(lay, chunks, L, do_scan, lowp, i8_chunks, interpret):
+def _annotate(lay, chunks, L, do_scan, lowp, interpret):
     from shifu_tpu.obs import profile as _profile
 
+    i8_chunks = len(chunks) if code_dtype(lay) == np.int8 else 0
     _profile.annotate(
         "ops.hist_pallas", kernel=kernel_name(do_scan), blk=blk_setting(),
-        # how the two per-row operands lie: rows along the lanes
-        rowLayout="planes[C,n] node[1,n]",
+        # how the three per-row operands lie: rows along the lanes
+        rowLayout="planes[C,n] node[1,n] codes[F,n]",
         wMax=wmax_setting(), chunks=len(chunks), L=int(L), T=int(lay.T),
         paddedT=int(sum(c.w for c in chunks)), fusedScan=bool(do_scan),
         bf16Planes=bool(lowp), int8Chunks=int(i8_chunks),
         mode=pallas_mode(), interpret=bool(interpret))
 
 
+def make_codes8_fn(lay):
+    """jit-able (codes [n, F] i32) -> the kernel's code operand `[F, n]`,
+    rows along the lanes: clipped to [0, clip_max], int8 where every
+    feature of the layout has at most 128 slots and int32 otherwise
+    (`code_dtype`). Codes are node-, label- and tree-independent, so a
+    grower makes it once a call, on one chip and under `shard_map` on each
+    chip's own rows, and hands it to every tree and level."""
+    import jax
+    import jax.numpy as jnp
+
+    def build(codes):
+        with jax.named_scope("tree.codes"):
+            return jnp.clip(codes, 0, jnp.asarray(lay.clip_max)[None, :]
+                            ).astype(code_dtype(lay)).T
+
+    return build
+
+
+def _code_operand(lay, codes, codes_t, blk: int):
+    """The code operand in whole blocks of rows: the hoisted `[F, n]` one
+    where the caller made it, else `codes [n, F]` turned here. The pad is
+    all a tree keeps of the operand's making (one a tree: every level
+    writes it and the compiler keeps the first), under `tree.codes` inside
+    the level's `hist`, where `tests/benchmark/test_scope_readers.py` and
+    `tree_codes_ms_per_tree` look for it."""
+    import jax
+
+    if codes_t is None:
+        codes_t = make_codes8_fn(lay)(codes)
+    with jax.named_scope("tree.codes"):
+        return _pad_rows(codes_t, blk, 1)
+
+
 def make_pallas_hist_fn(L: int, lay, n_classes: int = 0,
                         interpret: bool = False,
                         low_precision: bool = False):
     """Histogram-only kernel entry: traced fn (codes, labels, weights,
-    node_slot, active) -> [C, L, T] matching tree_trainer's histogram
-    contract (the hist-subtraction built-child, budget-batched,
-    leaf-wise and streamed/shard_map call sites). `interpret=True` runs
-    the kernels in pallas interpret mode (CPU tests)."""
-    import jax
+    node_slot, active, codes_t=None) -> [C, L, T] matching tree_trainer's
+    histogram contract (the hist-subtraction built-child, budget-batched,
+    leaf-wise and streamed/shard_map call sites). `codes_t` is
+    `make_codes8_fn`'s operand where the caller hoisted it; without it
+    `codes [n, F]` is turned here. `interpret=True` runs the kernels in
+    pallas interpret mode (CPU tests)."""
     import jax.numpy as jnp
 
     C = n_classes if n_classes >= 3 else 3
@@ -689,45 +784,24 @@ def make_pallas_hist_fn(L: int, lay, n_classes: int = 0,
     target = _target()
     chunks = _chunks(lay, target)
     comp_dt = jnp.bfloat16 if low_precision else jnp.float32
-    _annotate(lay, chunks, L, False, low_precision, 0, interpret)
+    _annotate(lay, chunks, L, False, low_precision, interpret)
 
-    def hist_fn(codes, labels, weights, node_slot, active):
+    def hist_fn(codes, labels, weights, node_slot, active, codes_t=None):
         blk = _block_rows(codes.shape[0], blk_max)
         comps_p, node_p = _row_operands(labels, weights, active, node_slot,
                                         L, n_classes, comp_dt, blk)
-        with jax.named_scope("tree.codes"):
-            codes_p = _pad_rows(codes, blk, 0)
+        codes_p = _code_operand(lay, codes, codes_t, blk)
         parts = []
         for ci, ch in enumerate(chunks):
-            call = _build_call(lay.key, target, ci, L, C, blk, False,
+            call = _build_call(lay.key, target, ci, L, C, blk,
                                low_precision, None, interpret)
-            featok = jnp.ones((1, ch.w), jnp.float32)
-            with jax.named_scope("tree.codes"):
-                codes_c = codes_p[:, ch.f_lo:ch.f_hi]
-            outs = call(codes_c, comps_p, node_p, featok)
+            outs = call(codes_p, comps_p, node_p)
             planes = jnp.stack(outs[:C])  # [C, L, W]
             parts.append(planes[:, :, jnp.asarray(ch.keep)])
         return (parts[0] if len(parts) == 1
                 else jnp.concatenate(parts, axis=2))  # [C, L, T]
 
     return hist_fn
-
-
-def make_codes8_fn(lay):
-    """jit-able (codes [n, F] i32) -> [n, F] int8 low-bandwidth code
-    planes: exact for every feature with <= 128 slots (the int8-eligible
-    chunks); wide features keep reading the i32 matrix."""
-    import jax
-    import jax.numpy as jnp
-
-    cap = np.minimum(lay.clip_max, _LANE - 1).astype(np.int32)
-
-    def build(codes):
-        with jax.named_scope("tree.codes"):
-            return jnp.clip(codes, 0, jnp.asarray(cap)[None, :]).astype(
-                jnp.int8)
-
-    return build
 
 
 def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
@@ -740,10 +814,9 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
     feat_ok_t) -> (hist [C, L, T], scan) where `scan` is the reference
     split_scan 9-tuple (feature, cut_rank, rank_flat, leaf_value,
     is_split, best_gain, left_mask, node_cnt, left_cnt) — drop-in for
-    tree_trainer's per-level hist+scan pair. `codes8` may be None (i32
-    codes everywhere); when given, int8-eligible chunks read it instead
-    of the i32 matrix."""
-    import jax
+    tree_trainer's per-level hist+scan pair. `codes8` is
+    `make_codes8_fn`'s `[F, n]` operand where the caller hoisted it, or
+    None: `codes [n, F]` is then turned here."""
     import jax.numpy as jnp
 
     C = n_classes if n_classes >= 3 else 3
@@ -754,8 +827,7 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
     comp_dt = jnp.bfloat16 if low_precision else jnp.float32
     scan_key = (impurity, int(min_inst), float(min_gain), int(n_classes))
     T, s_max = lay.T, lay.s_max
-    i8_chunks = sum(1 for ch in chunks if ch.narrow)
-    _annotate(lay, chunks, L, True, low_precision, i8_chunks, interpret)
+    _annotate(lay, chunks, L, True, low_precision, interpret)
 
     # static epilogue maps over the padded column space
     start_all = np.concatenate([ch.start for ch in chunks])
@@ -792,18 +864,13 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
         blk = _block_rows(codes.shape[0], blk_max)
         comps_p, node_p = _row_operands(labels, weights, active, node_slot,
                                         L, n_classes, comp_dt, blk)
-        with jax.named_scope("tree.codes"):
-            codes_p = _pad_rows(codes, blk, 0)
-            codes8_p = (_pad_rows(codes8, blk, 0) if codes8 is not None
-                        else None)
+        codes_p = _code_operand(lay, codes, codes8, blk)
         fok_f = feat_ok_t.astype(jnp.float32)
 
         hist_parts, gain_parts, rank_parts, lcnt_parts = [], [], [], []
         tot0 = None
         for ci, ch in enumerate(chunks):
-            use_i8 = ch.narrow and codes8_p is not None
-            src = codes8_p if use_i8 else codes_p
-            call = _build_call(lay.key, target, ci, L, C, blk, use_i8,
+            call = _build_call(lay.key, target, ci, L, C, blk,
                                low_precision, scan_key, interpret)
             # dynamic per-tree feature mask folded with the static
             # scannable/tail mask into one [1, W] plane
@@ -811,9 +878,7 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
             fok = (fok_f[jnp.asarray(t_clamp)]
                    * jnp.asarray((ch.scan_ok > 0)
                                  & (ch.pos >= 0), np.float32))[None, :]
-            with jax.named_scope("tree.codes"):
-                codes_c = src[:, ch.f_lo:ch.f_hi]
-            outs = call(codes_c, comps_p, node_p, fok)
+            outs = call(codes_p, comps_p, node_p, fok)
             planes = jnp.stack(outs[:C])
             hist_parts.append(planes[:, :, jnp.asarray(ch.keep)])
             gain_parts.append(outs[C])

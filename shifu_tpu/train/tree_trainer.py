@@ -1178,18 +1178,33 @@ def _low_precision(cfg: "TreeTrainConfig") -> bool:
     return cfg.algorithm == "GBT" and cfg.n_classes < 3
 
 
-def _get_codes8_program(lay: FeatureLayout):
-    """Cached jit: [n, F] i32 codes -> int8 low-bandwidth planes for the
-    kernel's narrow chunks (hoisted once per forest: codes are
-    node/label/tree-independent)."""
-    key = ("codes8", lay.key)
+def _get_codes8_program(lay: FeatureLayout, mesh=None):
+    """Cached jit: [n, F] i32 codes -> the tree kernel's code operand
+    `[F, n]`, rows along the lanes, clipped, int8 where the layout's slot
+    counts allow (hoisted once per forest: codes are
+    node/label/tree-independent). Under a `mesh` every chip turns its own
+    rows: row-sharded `[n, F]` in, `[F, n]` sharded along its second axis
+    out, no collective. One program makes the operand for every call and
+    tree, so its shape, dtype and sharding never change under the
+    whole-tree program's cache key."""
+    key = ("codes8", lay.key, _mesh_key(mesh))
     prog = _PROGRAMS.get(key)
     if prog is None:
         import jax
 
         from shifu_tpu.ops.hist_pallas import make_codes8_fn
 
-        prog = profile.wrap("tree.codes8", jax.jit(make_codes8_fn(lay)))
+        fn = make_codes8_fn(lay)
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as P
+
+            from shifu_tpu.parallel.mesh import row_axes, shard_map_compat
+
+            r_axes = row_axes(mesh)
+            rows = r_axes if len(r_axes) > 1 else r_axes[0]
+            fn = shard_map_compat(fn, mesh=mesh, in_specs=(P(rows),),
+                                  out_specs=P(None, rows))
+        prog = profile.wrap("tree.codes8", jax.jit(fn))
         _PROGRAMS[key] = prog
     return prog
 
@@ -1226,7 +1241,9 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
     DTMaster NodeStats merge, DTMaster.java:297-310), and the split scan
     runs replicated — the BSP master/worker exchange as one SPMD program.
 
-    Signature: prog(codes, labels, weights, feat_ok_t) ->
+    Signature: prog(codes, labels, weights, feat_ok_t), or with the Pallas
+    kernel on prog(codes, codes8, labels, weights, feat_ok_t) with
+    `_get_codes8_program`'s operand second, on one chip and under a mesh ->
     (feat_flat, mask_flat, leaf_flat, resting, row_pred) — the flat arrays
     ARE the DenseTree layout (level-order concatenation, final level
     -1/zeros), so host assembly is three contiguous transfers instead of
@@ -1273,16 +1290,10 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         # hist-mode kernel for the un-fused levels, and for meshed
         # growers (per device inside shard_map; the scan stays XLA,
         # after the psum merges the partials)
-        pallas_fns = [make_pallas_hist_fn(2**d, lay, n_classes=n_classes,
-                                          interpret=p_interp,
-                                          low_precision=lowp)
-                      if not fuse_at[d] else None for d in range(D)]
-        hist_fns = [
-            (lambda c, lab, wt, nd, act, *_la, _f=f: _f(c, lab, wt, nd,
-                                                        act))
-            if f is not None else None
-            for f in pallas_fns
-        ]
+        hist_fns = [make_pallas_hist_fn(2**d, lay, n_classes=n_classes,
+                                        interpret=p_interp,
+                                        low_precision=lowp)
+                    if not fuse_at[d] else None for d in range(D)]
     else:
         hist_fns = [_make_hist_fn(2**d, lay, n_classes=n_classes)
                     for d in range(D)]
@@ -1311,7 +1322,13 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
     acc_dt = jnp.float64 if acc64 else jnp.float32
     derive = _get_derive_program()
 
-    def tree_body(codes, labels, weights, feat_ok_t, codes8=None):
+    def tree_body(codes, *rest):
+        # with the kernel on, the program's second argument is the hoisted
+        # code operand (`_get_codes8_program`), on one chip and under a
+        # mesh alike: it enters here and not through a wrapper of its own
+        # (a frame more under every traced operation: PERF.md, section 7)
+        codes8 = rest[0] if p_on else None
+        labels, weights, feat_ok_t = rest[-3:]
         n = codes.shape[0]
         node = jnp.zeros(n, jnp.int32)
         active = jnp.ones(n, bool)
@@ -1322,9 +1339,12 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         prev = None
 
         def call_hist(L, idx, node_arg, act_arg):
+            # the kernel's entry reads the hoisted code operand, the XLA
+            # lowering the layout's constants
+            rest = (codes8,) if p_on else (off_c, clip_c, seg_c, pos_c)
             with phase(L, "hist"):
                 h = hist_fns[idx](codes, labels, weights, node_arg,
-                                  act_arg, off_c, clip_c, seg_c, pos_c)
+                                  act_arg, *rest)
             if on_mesh:
                 # the level's all-reduce under a scope of its own: it waits
                 # for the slowest chip, which the histogram does not
@@ -1345,10 +1365,11 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
         # and interleave, `scan` the XLA scan where it runs, `route` the
         # rows' move to their children; under a mesh `psum` is the
         # all-reduce of the level's histogram (`tree.leaf/psum`: of the
-        # leaf totals). The code operand's pad, cut and cast carry
-        # `tree.codes` (ops/hist_pallas.py): inside a level's `hist`
-        # here, a scope of its own in the programs that have no level
-        # (`tree.hist`, `tree.codes8`).
+        # leaf totals). What is done to the code operand carries
+        # `tree.codes` (ops/hist_pallas.py): its pad to whole blocks
+        # inside a level's `hist` here, its clip, cast and turn a scope
+        # of their own in the programs that have no level (`tree.hist`,
+        # `tree.codes8`).
         def phase(L, what):
             return jax.named_scope("tree.L%d/%s" % (L, what))
 
@@ -1436,25 +1457,19 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
             row_pred = _lookup(leaf_flat, resting)
         return feat_flat, mask_flat, leaf_flat, resting, row_pred
 
+    body = tree_body
     if on_mesh:
         from jax.sharding import PartitionSpec as P
 
-        rspec = P(r_axes if len(r_axes) > 1 else r_axes[0])
         from shifu_tpu.parallel.mesh import shard_map_compat
 
+        rows = r_axes if len(r_axes) > 1 else r_axes[0]
+        rspec = P(rows)
+        turned = (P(None, rows),) if p_on else ()
         body = shard_map_compat(
-            tree_body, mesh=mesh,
-            in_specs=(rspec, rspec, rspec, P()),
+            body, mesh=mesh, in_specs=(rspec, *turned, rspec, rspec, P()),
             out_specs=(P(), P(), P(), rspec, rspec))
-        prog = jax.jit(body)
-    elif p_fused:
-        def fused_entry(codes, codes8, labels, weights, feat_ok_t):
-            return tree_body(codes, labels, weights, feat_ok_t,
-                             codes8=codes8)
-
-        prog = jax.jit(fused_entry)
-    else:
-        prog = jax.jit(tree_body)
+    prog = jax.jit(body)
     # the fused-kernel grower is its own profiler seam so `shifu profile
     # --diff` can compare it against the XLA path's tree.whole_tree
     prog = profile.wrap("tree.pallas_fused" if p_fused
@@ -1523,14 +1538,10 @@ def build_tree(
         fot = jnp.asarray(np.asarray(feat_ok, bool)[lay.seg_of_t])
         if replicate_fn is not None:
             fot = replicate_fn(fot)
-        _p_on, _p_int, p_fused = _pallas_state(mesh)
-        if p_fused:
-            codes8 = _get_codes8_program(lay)(codes)
-            feats_d, masks_d, leaves_d, resting, _row_pred = prog(
-                codes, codes8, labels, weights, fot)
-        else:
-            feats_d, masks_d, leaves_d, resting, _row_pred = prog(
-                codes, labels, weights, fot)
+        hoisted = ((_get_codes8_program(lay, mesh)(codes),)
+                   if _pallas_state(mesh)[0] else ())
+        feats_d, masks_d, leaves_d, resting, _row_pred = prog(
+            codes, *hoisted, labels, weights, fot)
         import jax
 
         _record_hist_counters(
@@ -2179,15 +2190,13 @@ def train_trees(
             batch_cap = _node_batch_size(lay.T, cfg.max_stats_memory_mb,
                                          cfg.n_classes)
             fused = (not leaf_wise) and 2**cfg.max_depth <= batch_cap
-            codes8_forest = None
-            pallas_fused = False
+            codes8_forest = ()
             if fused:
                 replicate_fn = None
                 if mesh is not None:
                     from shifu_tpu.parallel.mesh import replicate
 
                     replicate_fn = lambda a: replicate(a, mesh)  # noqa: E731
-                _p_on, _p_int, pallas_fused = _pallas_state(mesh)
                 sub_levels, acc64 = _sub_plan(cfg, batch_cap)
                 sub_counts = _plan_counts(sub_levels[:cfg.max_depth],
                                           cfg.hist_subtraction)
@@ -2200,10 +2209,10 @@ def train_trees(
                     sub_levels=sub_levels, acc64=acc64,
                     lowp=_low_precision(cfg),
                 )
-                if pallas_fused:
-                    # int8 code planes hoisted once per forest (codes are
-                    # tree/level-independent): 4x less kernel code-read bandwidth
-                    codes8_forest = _get_codes8_program(lay)(codes_j)
+                if _pallas_state(mesh)[0]:
+                    # the kernel's code operand, hoisted once per forest
+                    # (codes are tree/level-independent)
+                    codes8_forest = (_get_codes8_program(lay, mesh)(codes_j),)
             deferred: List[tuple] = []  # (k, weight, feats_d, masks_d, leaves_d)
             err_pairs: List[tuple] = []  # device (train, valid) when deferred
 
@@ -2283,12 +2292,8 @@ def train_trees(
                         fot = jnp.asarray(np.asarray(feat_ok, bool)[lay.seg_of_t])
                         if replicate_fn is not None:
                             fot = replicate_fn(fot)
-                    if pallas_fused:
-                        feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
-                            codes_j, codes8_forest, labels_k, w_k, fot)
-                    else:
-                        feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
-                            codes_j, labels_k, w_k, fot)
+                    feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
+                        codes_j, *codes8_forest, labels_k, w_k, fot)
                     _record_hist_counters(*sub_counts)
                     _record_kernel_calls(kernel_calls)
                     _record_route_counters(cfg.max_depth, lay.s_max)

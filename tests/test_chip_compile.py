@@ -11,6 +11,8 @@ second file can land on another worker, whose fixture then skips).
 Nothing here runs on a device; a compile that passes is not a chip run.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -73,6 +75,36 @@ def _compiled_kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _code_operand(one_chip, lay, rows=N_ROWS):
+    """The kernels' hoisted code operand, `[F, n]` (hp.make_codes8_fn)."""
+    return _shape(one_chip, (len(lay.slots), rows), hp.code_dtype(lay))
+
+
+def _lane_padded_code_ops(text):
+    """Instructions that write a code operand with the rows on the
+    sublanes, `s8[n, 13 | 15]` chunk cuts or the `s32[n, 28]` matrix: the
+    `pad`, `copy` and `pad_slice_fusion` the kernels' `[n, nf]` operand cost
+    once a tree until PR 38 (128 and 512 B a row in HBM for 13 to 28
+    codes). Nothing makes one now: the operand is `[F, n]`, made once a
+    call outside the tree program."""
+    return re.findall(
+        r"= s(?:8\[\d+,(?:13|15)\]|32\[\d+,28\])\S* "
+        r"(?:pad|copy|fusion)\([^\n]*", text)
+
+
+def _code_operand_writes(text):
+    """The instructions of a tree program that write an `[F, n]` code
+    operand in HBM, by opcode: one `pad` to whole blocks where the rows
+    are not whole blocks (the first level's; every level reads it), and
+    nothing else: no clip, cast, transpose or `copy`, which
+    `tt._get_codes8_program` did once a call. A write into memory space
+    `S(1)` is left out: at N_ROWS the operand is 1.8 MB and XLA prefetches
+    it into VMEM whole (`copy-done` of `s8[28,65536]{...S(1)}`)."""
+    return [op for shape, op in re.findall(
+        r"= (s8\[28,\d+\]\S*) (\w[\w-]*)\(", text)
+        if op != "parameter" and "S(1)" not in shape]
+
+
 # higgs at wmax 1,024 is ONE chunk of 28 features side by side (924 of
 # 1,024 columns), L_MAX the widest level the mesh4 cell builds
 @pytest.mark.parametrize("layout,L,lowp", [
@@ -101,14 +133,15 @@ def test_hist_kernel_compiles_at_rf_deepest_level(one_chip):
 
 
 def _distinct_kernels(lay):
-    """{kernel signature: representative chunk index}. The per-column
-    metadata rides in as kernel INPUTS, so two chunks with the same
-    (padded width, feature count, code dtype) are the same Mosaic
-    program — gbt_wide's 54 chunks are 4 distinct kernels, and compiling
-    one of each asks the compiler everything the whole layout would."""
+    """{kernel signature: representative chunk index}. Two chunks with
+    the same (padded width, rows of the code operand's block, feature
+    count) differ only in the static offsets at which MT's tiles find
+    their features' rows, so compiling one of each asks the compiler
+    what the whole layout would (gbt_wide's 19 chunks are 10 kinds)."""
     seen = {}
     for ci, ch in enumerate(hp._chunks(lay, hp._SCAN_W_CAP)):
-        seen.setdefault((ch.w, ch.f_hi - ch.f_lo, ch.narrow), ci)
+        rows, _blk = hp._code_window(ch, lay)
+        seen.setdefault((ch.w, rows, ch.f_hi - ch.f_lo), ci)
     return seen
 
 
@@ -121,17 +154,16 @@ def test_fused_kernels_compile_where_the_rule_admits(one_chip, layout,
     slots, is_cat = LAYOUTS[layout]
     lay = tt.make_layout(slots, is_cat)
     kinds = _distinct_kernels(lay)
-    assert max(w for (w, _nf, _i8) in kinds) <= hp._SCAN_W_CAP
+    assert max(w for (w, _rows, _nf) in kinds) <= hp._SCAN_W_CAP
     blk, C = hp.blk_setting(), 3
     comp_dt = jnp.bfloat16 if lowp else jnp.float32
     scan_key = ("variance" if lowp else "entropy", 1, 0.0, 0)
     calls, args = [], []
-    for (w, nf, narrow), ci in kinds.items():
+    for (w, _rows, _nf), ci in kinds.items():
         calls.append(hp._build_call(lay.key, hp._SCAN_W_CAP, ci, L, C, blk,
-                                    narrow, lowp, scan_key, False))
+                                    lowp, scan_key, False))
         args.append((
-            _shape(one_chip, (N_ROWS, nf),
-                   jnp.int8 if narrow else jnp.int32),
+            _code_operand(one_chip, lay),
             _shape(one_chip, (C, N_ROWS), comp_dt),
             _shape(one_chip, (1, N_ROWS), jnp.int32),
             _shape(one_chip, (1, w), jnp.float32)))
@@ -154,7 +186,7 @@ def test_fused_level_entry_compiles(one_chip, layout):
                                 low_precision=True)
     codes, labels, weights, node, active = _row_args(one_chip, len(slots))
     compiled = jax.jit(fn).lower(
-        codes, _shape(one_chip, codes.shape, jnp.int8), labels, weights,
+        codes, _code_operand(one_chip, lay), labels, weights,
         node, active, _shape(one_chip, (lay.T,), jnp.bool_)).compile()
     assert _compiled_kernels(compiled) == len(
         hp._chunks(lay, hp._SCAN_W_CAP))
@@ -162,30 +194,29 @@ def test_fused_level_entry_compiles(one_chip, layout):
         [199] if layout == "gbt_wide" else [])
 
 
-# The kernel's two per-row operands ride with the rows along the lanes,
-# [C, n] planes and [1, n] node ids: a level's temporaries are then the
-# code operand's (128 B a row for each int8 chunk, 512 B for the int32
-# matrix) and little else. With the rows on the sublanes ([n, 3], [n, 1])
-# every row took a 128-lane tile row in each: 1,024 and 1,280 B a row
-# here (5.63 and 7.04 GB; described-chip compile, PR 31). At N_ROWS the
-# operands sit in VMEM and the reading is 0, so this one asks at the
-# cell's rows (shapes only: nothing is allocated). The hist-mode entry is
-# asked at the cell's rows in whole blocks, as each chip of the mesh
-# holds them: at 5,500,000 the wrapper's pad of the int32 codes stands
-# beside their row-major copy for a moment and that alone reads 1,024.
-@pytest.mark.parametrize("entry,L,rows,cap", [
-    ("fused", 1, CELL_ROWS, 320),
-    ("hist", L_MAX, -(-CELL_ROWS // 512) * 512, 560)])
-def test_level_temporaries_a_row_at_the_cells_rows(one_chip, entry, L, rows,
-                                                   cap):
+# The kernel's three per-row operands ride with the rows along the lanes,
+# [C, n] planes, [1, n] node ids and, since PR 38, [F, n] codes: a level's
+# temporaries are the row operands in whole blocks and little else, 44 B a
+# row behind the hoisted code operand (the fused entry, as the whole-tree
+# program calls it) and 64 B where the entry turns `codes [n, F]` itself
+# (the hist-mode entry, as the `tree.hist` programs call it). With the
+# codes a row to a sublane, `[n, nf]`, they read 268 and 524 B (128 B a
+# row for each int8 chunk, 512 B for the int32 matrix; PR 31), and 1,024
+# and 1,280 B with all three operands so (described-chip compile). At
+# N_ROWS the operands sit in VMEM and the reading is 0, so this one asks
+# at the cell's rows (shapes only: nothing is allocated).
+@pytest.mark.parametrize("entry,L,cap", [
+    ("fused", 1, 60), ("hist", L_MAX, 80)])
+def test_level_temporaries_a_row_at_the_cells_rows(one_chip, entry, L, cap):
     slots, is_cat = LAYOUTS["higgs"]
     lay = tt.make_layout(slots, is_cat)
+    rows = CELL_ROWS
     codes, labels, weights, node, active = _row_args(
         one_chip, len(slots), rows)
     if entry == "fused":
         fn = hp.make_fused_level_fn(L, lay, "variance", 5, 0.0,
                                     low_precision=True)
-        args = (codes, _shape(one_chip, codes.shape, jnp.int8), labels,
+        args = (codes, _code_operand(one_chip, lay, rows), labels,
                 weights, node, active, _shape(one_chip, (lay.T,), jnp.bool_))
     else:
         fn = hp.make_pallas_hist_fn(L, lay, low_precision=True)
@@ -193,6 +224,7 @@ def test_level_temporaries_a_row_at_the_cells_rows(one_chip, entry, L, rows,
     compiled = jax.jit(fn).lower(*args).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert 0 < temp < cap * rows, temp / rows
+    assert _lane_padded_code_ops(compiled.as_text()) == []
 
 
 @pytest.mark.parametrize("meshed,want", [(False, 12), (True, 6)],
@@ -204,7 +236,11 @@ def test_whole_tree_program_kernel_count_at_higgs(one_chip, monkeypatch,
     the fused scan, 1 x 6 where every level runs the hist-mode kernel, as
     the meshed grower's do (here on one described chip, without the mesh:
     `_pallas_state` is steered in the test, as it is off the chip anyway).
-    The counter's arithmetic and the compiler's count agree."""
+    The counter's arithmetic and the compiler's count agree. Both take
+    the hoisted `[F, n]` code operand as their second argument, as the
+    one-chip and the meshed program do, and in neither is a lane-padded
+    `[n, nf]` form of it left in the executable, nor any write of the
+    operand (65,536 rows are whole blocks: not even its pad)."""
     slots, is_cat = LAYOUTS["higgs"]
     lay = tt.make_layout(slots, is_cat)
     D, sub_levels = 6, (False,) + (True,) * 6
@@ -219,11 +255,12 @@ def test_whole_tree_program_kernel_count_at_higgs(one_chip, monkeypatch,
             del tt._PROGRAMS[k]  # built under a steered state: never reuse
     codes, labels, weights, _node, _active = _row_args(one_chip, len(slots))
     args = (codes, labels, weights, _shape(one_chip, (lay.T,), jnp.bool_))
-    if not meshed:
-        args = (codes, _shape(one_chip, codes.shape, jnp.int8)) + args[1:]
+    args = (codes, _code_operand(one_chip, lay)) + args[1:]
     compiled = prog.fn.lower(*args).compile()
     assert _compiled_kernels(compiled) == want
     assert tt._tree_kernel_calls(D, lay, sub_levels) == want
+    assert _lane_padded_code_ops(compiled.as_text()) == []
+    assert _code_operand_writes(compiled.as_text()) == []
 
 
 def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
@@ -234,14 +271,14 @@ def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
     halves of 1 to 32 nodes) and 3 `tree_hist` (levels 7 to 9, built halves
     of 64 to 256 nodes, one chunk); no `gather` that hands out a value a row
     (PR 34's program held two, `built_lsb[node >> 1]` at 128 and 256 parents:
-    the build mask comes out of `route_rows` since PR 35); temporaries under
-    1,130 B a row (1,050; 1,061 in PR 34: 5.84 GB, of which the int32 code
-    matrix's row-major `copy` and its `pad` for the hist-mode entry are
-    2.8 GB each) and, with the arguments, under 45 % of the 15.75 GiB a v5e
-    hands out (40 %). About 45 s on the CPU."""
+    the build mask comes out of `route_rows` since PR 35); no `pad`, `copy`
+    or `pad_slice_fusion` of a lane-padded code operand; temporaries under
+    110 B a row (88 since PR 38: 0.48 GB; 1,050 before, 5.78 GB, of which
+    the int32 code matrix's row-major `copy` and its `pad` for the
+    hist-mode entry were 2.8 GB each) and, with the arguments, under 10 %
+    of the 15.75 GiB a v5e hands out (8.3 %). About 40 s on the CPU."""
     import json
     import os
-    import re
 
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                            "configs", "higgs_rf.json")) as f:
@@ -273,9 +310,12 @@ def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
     codes, labels, weights, _node, _active = _row_args(
         one_chip, c["features"], rows)
     compiled = prog.fn.lower(
-        codes, _shape(one_chip, codes.shape, jnp.int8), labels, weights,
+        codes, _code_operand(one_chip, lay, rows), labels, weights,
         _shape(one_chip, (lay.T,), jnp.bool_)).compile()
     text = compiled.as_text()
+    assert _lane_padded_code_ops(text) == []
+    # 5,500,000 rows are 416 short of whole blocks: the one pad, no more
+    assert _code_operand_writes(text) == ["pad"]
     # each call is an instruction named after its kernel
     names = re.findall(r"%(tree_fused_level|tree_hist)[\w.]* = ", text)
     assert (names.count("tree_fused_level"), names.count("tree_hist")) == (
@@ -285,10 +325,10 @@ def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
     assert re.findall(r"= \w+\[%d\][^\n=]* gather\(" % rows, text) == []
     assert len(re.findall(r" gather\(", text)) > 100  # the pattern's opcode
     mem = compiled.memory_analysis()
-    assert 0 < mem.temp_size_in_bytes < 1130 * rows, \
+    assert 0 < mem.temp_size_in_bytes < 110 * rows, \
         mem.temp_size_in_bytes / rows
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
-            < 0.45 * 15.75 * 2**30)
+            < 0.10 * 15.75 * 2**30)
 
 
 def test_nn_train_step_compiles_at_small_width(one_chip):

@@ -20,6 +20,7 @@ import jax.numpy as jnp  # noqa: E402
 from shifu_tpu.ops.hist_pallas import (  # noqa: E402
     _block_rows,
     _chunks,
+    code_dtype,
     make_codes8_fn,
     make_fused_level_fn,
     make_pallas_hist_fn,
@@ -61,13 +62,17 @@ def _ref_hist(L, lay, codes, y, w, node, active, n_classes=0):
 
 
 def _pallas_hist(L, lay, codes, y, w, node, active, n_classes=0,
-                 low_precision=False):
+                 low_precision=False, hoisted=False):
+    """`hoisted`: the code operand made ahead by `make_codes8_fn`, as the
+    whole-tree program is handed it; else the [n, F] entry turns it."""
     fn = jax.jit(make_pallas_hist_fn(L, lay, n_classes=n_classes,
                                      interpret=True,
                                      low_precision=low_precision))
+    codes_t = (jax.jit(make_codes8_fn(lay))(jnp.asarray(codes)),) \
+        if hoisted else ()
     return np.asarray(fn(jnp.asarray(codes), jnp.asarray(y),
                          jnp.asarray(w), jnp.asarray(node),
-                         jnp.asarray(active)))
+                         jnp.asarray(active), *codes_t))
 
 
 def _mixed_case(n=1500, seed=0, full_range=False):
@@ -141,16 +146,45 @@ def test_bf16_plane_parity_bounds():
 
 _LANES_SLOTS = [9] * 6 + [33, 65]
 _LANES_CAT = [False] * 6 + [True] * 2
+# The code operand's two forms (PR 37), `[F, n]` with the rows along the
+# lanes. "narrow": every feature within 128 slots, so int8 codes; the
+# 9-slot features end inside an 8-column tile of MT, which then holds two
+# features' columns. "wide": a feature of 1,500 slots turns the operand
+# int32 and lies in several chunks, all pieces but the first with
+# `lo` > 0; its codes leave their range on both sides, for the [n, F]
+# entry to clip as the reference does.
+_LANES_LAYOUTS = {
+    "narrow": (_LANES_SLOTS, _LANES_CAT, np.int8),
+    "wide": ([9, 1500, 33], [False, True, True], np.int32),
+}
 
 
-def _lanes_case(L, n_classes, n=1100, seed=17):
+def _lanes_cases(levels, more):
+    """(L, lowp, n_classes, layout, hoisted): every level, precision and
+    plane kind on the narrow layout through the [n, F] entry, and `more`
+    for each other pair of layout and entry (`hoisted`: the operand made
+    ahead by `make_codes8_fn`, as the whole-tree program is handed it)."""
+    cases = [(L, lowp, nc, "narrow", False) for nc in (0, 3)
+             for lowp in (False, True) for L in levels]
+    for layout, hoisted in [("narrow", True), ("wide", False),
+                            ("wide", True)]:
+        cases += [c + (layout, hoisted) for c in more]
+    return [pytest.param(*c, id="-".join(
+        [str(c[0]), "bf16" if c[1] else "f32",
+         "classes3" if c[2] else "moments", c[3]] + ["hoisted"] * c[4]))
+        for c in cases]
+
+
+def _lanes_case(L, n_classes, n=1100, seed=17, layout="narrow"):
     """Integer weights and integer labels: every plane value is a small
     integer, exact in bf16 as in f32, so kernel and reference must agree
     BIT for bit at either precision. n = 1,100 is 2 blocks of 512 and a
     tail of 76 rows in a third: the 436 padded rows must add nothing.
     Inactive rows carry node ids the level does not have."""
     rng = np.random.default_rng(seed + L)
-    codes = np.stack([rng.integers(0, s, size=n) for s in _LANES_SLOTS],
+    slots = _LANES_LAYOUTS[layout][0]
+    over = 0 if layout == "narrow" else 3  # codes past both ends
+    codes = np.stack([rng.integers(-over, s + over, size=n) for s in slots],
                      1).astype(np.int32)
     w = rng.integers(1, 4, size=n).astype(np.float32)
     y = (rng.integers(0, n_classes, size=n) if n_classes
@@ -161,33 +195,42 @@ def _lanes_case(L, n_classes, n=1100, seed=17):
     return codes, y, w, node, active
 
 
-@pytest.mark.parametrize("n_classes", [0, 3], ids=["moments", "classes3"])
-@pytest.mark.parametrize("lowp", [False, True], ids=["f32", "bf16"])
-@pytest.mark.parametrize("L", [1, 4, 32, 64, 512])
-def test_hist_kernel_parity_rows_along_lanes(L, lowp, n_classes):
-    lay = make_layout(_LANES_SLOTS, _LANES_CAT)
-    codes, y, w, node, active = _lanes_case(L, n_classes)
+@pytest.mark.parametrize(
+    "L,lowp,n_classes,layout,hoisted",
+    _lanes_cases([1, 4, 32, 64, 512],
+                 [(1, False, 0), (4, True, 3), (32, True, 0),
+                  (64, False, 3), (512, False, 0)]))
+def test_hist_kernel_parity_rows_along_lanes(L, lowp, n_classes, layout,
+                                             hoisted):
+    """Against `hist_scatter`, bit for bit: the f32 cases are RF's
+    planes."""
+    slots, is_cat, _dt = _LANES_LAYOUTS[layout]
+    lay = make_layout(slots, is_cat)
+    codes, y, w, node, active = _lanes_case(L, n_classes, layout=layout)
     assert len(y) % 512 and _block_rows(len(y), 512) == 512
     h_ref = _ref_hist(L, lay, codes, y, w, node, active,
                       n_classes=n_classes)
     h_pl = _pallas_hist(L, lay, codes, y, w, node, active,
-                        n_classes=n_classes, low_precision=lowp)
+                        n_classes=n_classes, low_precision=lowp,
+                        hoisted=hoisted)
     assert h_pl.shape == (3, L, lay.T)
     np.testing.assert_array_equal(h_ref, h_pl)
     # every active row lands once in feature 0's columns, no padded row does
     weight = h_pl.sum(0) if n_classes else h_pl[0]
-    assert weight[:, :_LANES_SLOTS[0]].sum() == w[active].sum()
+    assert weight[:, :slots[0]].sum() == w[active].sum()
 
 
-@pytest.mark.parametrize("n_classes", [0, 3], ids=["moments", "classes3"])
-@pytest.mark.parametrize("lowp", [False, True], ids=["f32", "bf16"])
-@pytest.mark.parametrize("L", [1, 4, 32])
-def test_fused_level_parity_rows_along_lanes(L, lowp, n_classes):
-    codes, y, w, _node, _active = _lanes_case(L, n_classes)
+@pytest.mark.parametrize(
+    "L,lowp,n_classes,layout,hoisted",
+    _lanes_cases([1, 4, 32], [(1, False, 0), (4, True, 3), (8, False, 0)]))
+def test_fused_level_parity_rows_along_lanes(L, lowp, n_classes, layout,
+                                             hoisted):
+    slots, is_cat, _dt = _LANES_LAYOUTS[layout]
+    codes, y, w, _node, _active = _lanes_case(L, n_classes, layout=layout)
     h_ref, hist, ref, out = _run_scan_pair(
-        _LANES_SLOTS, _LANES_CAT, codes, y, w, L=L,
+        slots, is_cat, codes, y, w, L=L,
         impurity="gini" if n_classes else "variance", n_classes=n_classes,
-        low_precision=lowp)
+        low_precision=lowp, hoisted=hoisted)
     np.testing.assert_array_equal(np.asarray(h_ref), np.asarray(hist))
     # the class impurities divide and square in another order in the
     # kernel: their gains agree to rounding, every choice exactly
@@ -255,20 +298,124 @@ def test_chunks_cover_layout_densely_packed(slots, target, want_nf,
         assert (len(home[f]) == 1) == (s <= target)
     if want_nf is not None:
         assert [ch.f_hi - ch.f_lo for ch in chunks] == want_nf
-    # chunks whose features all fit 128 slots are int8-code eligible
-    for ch in chunks:
-        assert ch.narrow == all(slots[f] <= 128
-                                for (f, _lo, _hi, _c0) in ch.pieces)
+    # int8 codes where every feature of the layout fits 128 slots: one
+    # code operand a layout, whatever its chunks hold
+    assert code_dtype(lay) == (np.int8 if max(slots) <= 128 else np.int32)
 
 
-def test_codes8_planes():
-    slots, is_cat, codes, *_ = _mixed_case(n=300)
+@pytest.mark.parametrize("n", [300, 512, 1100],
+                         ids=["one-block", "whole-blocks", "ragged"])
+@pytest.mark.parametrize("layout", list(_LANES_LAYOUTS))
+def test_codes8_planes(layout, n):
+    """The hoisted code operand: `[F, n]`, the rows along the lanes, every
+    code clipped into its feature's slots, int8 where the layout's slot
+    counts allow and int32 where one feature passes 128. It is handed over
+    as long as the table (the entry pads it to whole blocks beside the
+    planes): 1,100 rows stay 1,100."""
+    slots, is_cat, want_dt = _LANES_LAYOUTS[layout]
     lay = make_layout(slots, is_cat)
+    codes, *_ = _lanes_case(4, 0, n=n, layout=layout)
     codes8 = np.asarray(jax.jit(make_codes8_fn(lay))(jnp.asarray(codes)))
-    assert codes8.dtype == np.int8
-    # exact for <=128-slot features; wide columns are clamped (unused)
-    np.testing.assert_array_equal(codes8[:, :8], codes[:, :8])
-    assert codes8[:, 8].max() <= 127
+    assert codes8.dtype == want_dt and codes8.shape == (len(slots), n)
+    np.testing.assert_array_equal(
+        codes8, np.clip(codes, 0, np.asarray(slots) - 1).T)
+
+
+# Tiles of MT's 8 columns at their worst: features of 3 and 5 slots lie
+# three to a tile (two selects), one of 8 fills a tile alone and starts
+# mid-tile, one of 1 slot, and a 40-slot feature runs over five tiles and
+# ends mid-tile. 77 live columns of 128: the dead tail is 6 tiles.
+_TILE_SLOTS = [3, 3, 5, 8, 1, 40, 3, 3, 3, 8]
+_TILE_CAT = [False, True] * 5
+
+
+@pytest.mark.parametrize("hoisted", [False, True], ids=["turned", "hoisted"])
+@pytest.mark.parametrize("n_classes", [0, 3, 4, 7],
+                         ids=["moments", "C3", "C4", "C7"])
+@pytest.mark.parametrize("L", [1, 16, 256])
+def test_hist_kernel_parity_several_features_a_tile(L, n_classes, hoisted):
+    """Bit for bit against `hist_scatter` on f32 planes (integer weights
+    and labels): pieces that end anywhere in a sublane tile, C = 3 and
+    C > 3 (native multi-class counts, past the stacked LHS from C x L >
+    128), L = 256 (the forest's deepest built level), a ragged last block,
+    out-of-range codes on both sides against the clip."""
+    lay = make_layout(_TILE_SLOTS, _TILE_CAT)
+    rng = np.random.default_rng(5 + L + n_classes)
+    n = 1100
+    codes = np.stack([rng.integers(-2, s + 2, size=n) for s in _TILE_SLOTS],
+                     1).astype(np.int32)
+    w = rng.integers(1, 4, size=n).astype(np.float32)
+    y = (rng.integers(0, n_classes, size=n) if n_classes
+         else rng.integers(0, 3, size=n)).astype(np.float32)
+    node = rng.integers(0, L, size=n).astype(np.int32)
+    active = rng.random(n) < 0.8
+    node[~active] = L + 5
+    h_ref = _ref_hist(L, lay, codes, y, w, node, active, n_classes=n_classes)
+    h_pl = _pallas_hist(L, lay, codes, y, w, node, active,
+                        n_classes=n_classes, hoisted=hoisted)
+    assert h_pl.shape == (max(n_classes, 3), L, lay.T)
+    np.testing.assert_array_equal(h_ref, h_pl)
+    # a code past either end lands in its feature's first or last slot
+    f, first = 5, int(lay.off[5])
+    weight = h_pl.sum(0) if n_classes else h_pl[0]
+    want_first = w[active & (codes[:, f] <= 0)].sum()
+    want_last = w[active & (codes[:, f] >= _TILE_SLOTS[f] - 1)].sum()
+    assert weight[:, first].sum() == want_first
+    assert weight[:, first + _TILE_SLOTS[f] - 1].sum() == want_last
+
+
+@pytest.mark.parametrize("slots,L,lowp,do_scan", [
+    ([33] * 28, 16, True, False), ([33] * 28, 16, True, True),
+    ([33] * 28, 256, False, False), ([33] * 28, 32, False, True),
+    (_TILE_SLOTS, 4, False, False), (_TILE_SLOTS, 4, False, True),
+    ([9, 1500, 33], 4, False, False), ([9, 1500, 33], 4, False, True)],
+    ids=["higgs-L16-hist", "higgs-L16-fused", "higgs-L256-hist",
+         "higgs-L32-fused", "tiles-hist", "tiles-fused", "wide-hist",
+         "wide-fused"])
+def test_the_kernels_body_holds_the_accumulate_dots_alone(slots, L, lowp,
+                                                          do_scan):
+    """No selection matmul is left in a body: of the equations Pallas is
+    handed, the only `dot_general` outside the last step's scan are the
+    accumulate's, one a group of components, each `[group x rows, blk]`
+    against MT `[W, blk]` over the row axis, and none has an operand with a
+    feature a row (`[nf, W]` or `[blk, nf]`, the old `codes_f @ sel`). And
+    the body stays small, since a body is traced and lowered in every
+    process's set-up: 2 equations a feature (its row's slice and its
+    broadcast), 1 a piece (a straddled tile's select) and a fixed 50.
+    HIGGS at L = 16 reads 123 in hist mode (28 features, W = 1,024) and 88
+    fused (15, W = 512); the parent's bodies 44 and 46; PR 37's form, a
+    compare a tile of 8 columns and a `want` a slab, 343 and 190."""
+    from shifu_tpu.ops import hist_pallas as hp
+
+    lay = make_layout(slots, [False] * len(slots))
+    target = hp._target(fused=do_scan)
+    scan_key = ("variance", 1, 0.0, 0) if do_scan else None
+    n, blk, C = 1024, 512, 3
+    comp_dt = jnp.bfloat16 if lowp else jnp.float32
+    for ci, ch in enumerate(hp._chunks(lay, target)[:3]):
+        call = hp._build_call(lay.key, target, ci, L, C, blk, lowp, scan_key,
+                              False)
+        args = [jnp.zeros((len(slots), n), hp.code_dtype(lay)),
+                jnp.zeros((C, n), comp_dt), jnp.zeros((1, n), jnp.int32)]
+        if do_scan:
+            args.append(jnp.ones((1, ch.w), jnp.float32))
+        jaxpr = jax.make_jaxpr(call)(*args)
+        (pc,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name ==
+                 "pallas_call"]
+        body = pc.params["jaxpr"]
+        top = [e for e in body.eqns if e.primitive.name != "cond"]
+        dots = [e for e in top if e.primitive.name == "dot_general"]
+        rows_f, _ = hp._code_window(ch, lay)
+        sub = 16 if lowp else 8
+        tiled = -(-L // sub) * sub
+        group, rows = (C, tiled) if C * tiled <= 128 else (1, L)
+        assert len(dots) == C // group
+        for e in dots:
+            lhs, rhs = (v.aval.shape for v in e.invars)
+            assert lhs == (group * rows, blk) and rhs == (ch.w, blk)
+            assert e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
+        nf = ch.f_hi - ch.f_lo
+        assert len(top) <= 50 + 2 * nf + len(ch.pieces), len(top)
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +424,8 @@ def test_codes8_planes():
 
 
 def _run_scan_pair(slots, is_cat, codes, y, w, L, impurity, n_classes=0,
-                   min_inst=2, seed=7, wmax=None, low_precision=False):
+                   min_inst=2, seed=7, wmax=None, low_precision=False,
+                   hoisted=False):
     rng = np.random.default_rng(seed)
     n = len(y)
     lay = make_layout(slots, is_cat)
@@ -302,7 +450,9 @@ def _run_scan_pair(slots, is_cat, codes, y, w, L, impurity, n_classes=0,
         fused = jax.jit(make_fused_level_fn(
             L, lay, impurity, min_inst, 0.0, n_classes=n_classes,
             interpret=True, low_precision=low_precision))
-        hist, out = fused(jnp.asarray(codes), None, jnp.asarray(y),
+        codes_t = (jax.jit(make_codes8_fn(lay))(jnp.asarray(codes))
+                   if hoisted else None)
+        hist, out = fused(jnp.asarray(codes), codes_t, jnp.asarray(y),
                           jnp.asarray(w), jnp.asarray(node),
                           jnp.asarray(active), fot)
     finally:
@@ -541,15 +691,20 @@ def test_mode_knob_resolution():
         _set_mode("")
 
 
-def test_shaping_knobs_and_profiler_annotation():
+@pytest.mark.parametrize("narrow", [False, True], ids=["int32", "int8"])
+def test_shaping_knobs_and_profiler_annotation(narrow):
     """-Dshifu.pallas.blk/.wmax override the VMEM shaping (the kernel-
     tuning sweep seam), the overridden kernel still matches the scatter
     reference exactly, and the chosen shaping lands in the profiler
-    snapshot so every manifest records what produced its numbers."""
+    snapshot so every manifest records what produced its numbers: how the
+    three row operands lie, and how many chunks read int8 codes (all, or
+    none where a feature passes 128 slots)."""
     from shifu_tpu import obs
     from shifu_tpu.ops.hist_pallas import blk_setting, wmax_setting
 
     slots, is_cat, codes, y, w, rng = _mixed_case(n=700)
+    if narrow:  # the 1,500-slot column folded into 120 slots
+        slots, codes = slots[:-1] + [120], codes % 120
     lay = make_layout(slots, is_cat)
     L = 4
     node = rng.integers(0, L, size=len(y)).astype(np.int32)
@@ -569,8 +724,9 @@ def test_shaping_knobs_and_profiler_annotation():
         np.testing.assert_allclose(h_ref, h_pl, rtol=2e-5, atol=1e-4)
         ann = obs.profiler().snapshot()["annotations"]["ops.hist_pallas"]
         assert ann["blk"] == 128 and ann["wMax"] == 256
-        assert ann["rowLayout"] == "planes[C,n] node[1,n]"
+        assert ann["rowLayout"] == "planes[C,n] node[1,n] codes[F,n]"
         assert ann["chunks"] == len(_chunks(lay))
+        assert ann["int8Chunks"] == (len(_chunks(lay)) if narrow else 0)
         assert ann["mode"] in ("auto", "on", "off")
     finally:
         environment.set_property("shifu.pallas.blk", "")

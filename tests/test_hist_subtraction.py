@@ -300,3 +300,118 @@ def test_three_growers_agree_with_and_without_subtraction(monkeypatch,
         np.testing.assert_array_equal(got_tree.leaf_value,
                                       want_tree.leaf_value, name)
         np.testing.assert_array_equal(got_resting, want_resting, name)
+
+
+# The growers over the Pallas kernel (interpret mode here). Since PR 38 the
+# kernel reads its codes as `[F, n_pad]`, the rows along the lanes: the
+# whole-tree program is handed the operand made once a call
+# (`tree.codes8`), every other caller hands `codes [n, F]` and the entry
+# turns it where it pads the planes. "narrow": int8 codes; "wide": a
+# 300-slot feature turns the operand int32 and lies in several chunks.
+_KERNEL_LAYOUTS = {"narrow": [16] * 5 + [9], "wide": [16] * 4 + [300, 9]}
+
+
+def _kernel_table(slots, n=1300, seed=3):
+    rng = np.random.default_rng(seed)
+    codes = np.stack([rng.integers(0, s, size=n) for s in slots],
+                     1).astype(np.int32)
+    y = ((codes[:, 0] + codes[:, 1] + rng.integers(0, 8, n))
+         > 18).astype(np.float32)
+    w = rng.poisson(1.0, size=n).astype(np.float32)
+    return codes, y, w
+
+
+@pytest.fixture
+def kernel_mode():
+    """Sets `shifu.pallas.mode`; afterwards the knob and the program cache
+    are as they were (a program built under a knob is never reused)."""
+    from shifu_tpu.train import tree_trainer as tt
+    from shifu_tpu.utils import environment
+
+    before = set(tt._PROGRAMS)
+
+    def set_mode(mode):
+        environment.set_property("shifu.pallas.mode", mode)
+
+    try:
+        yield set_mode
+    finally:
+        environment.set_property("shifu.pallas.mode", "")
+        for k in set(tt._PROGRAMS) - before:
+            del tt._PROGRAMS[k]
+
+
+@pytest.mark.parametrize("grower", ["whole_tree", "node_batched",
+                                    "leaf_wise", "streamed"])
+@pytest.mark.parametrize("layout", list(_KERNEL_LAYOUTS))
+def test_growers_over_the_kernel_grow_the_xla_forest(monkeypatch, tmp_path,
+                                                     kernel_mode, layout,
+                                                     grower):
+    """RF planes are whole numbers, so every grower grows over the kernel,
+    bit for bit, the forest it grows over the XLA lowering: the whole-tree
+    program (hoisted operand), the node-batched `build_tree` and the
+    leaf-wise grower (the hist-mode entry turns `codes` every call), the
+    streamed grower (a `tree.hist` program a shard)."""
+    from shifu_tpu.train import tree_trainer as tt
+
+    slots = _KERNEL_LAYOUTS[layout]
+    codes, y, w = _kernel_table(slots)
+    cols = [f"c{i}" for i in range(len(slots))]
+    is_cat = [False] * (len(slots) - 1) + [True]
+    kw = {"max_leaves": 9} if grower == "leaf_wise" else {}
+    cfg = TreeTrainConfig(algorithm="RF", tree_num=2, max_depth=4,
+                          min_instances_per_node=1, seed=2, **kw)
+    if grower == "node_batched":  # one node short of the whole-tree program
+        monkeypatch.setattr(tt, "_node_batch_size", lambda *a, **k: 15)
+
+    def grow():
+        if grower == "streamed":
+            from shifu_tpu.norm.dataset import write_codes
+            from shifu_tpu.train.streaming_tree import train_trees_streamed
+
+            out = str(tmp_path / "codes")
+            write_codes(out, codes, y, w, cols, slots, n_shards=3)
+            return train_trees_streamed(out, slots, is_cat, cols, cfg)
+        return train_trees(codes, y, w, slots, is_cat, cols, cfg)
+
+    forests = {}
+    for mode in ("off", "on"):
+        kernel_mode(mode)
+        obs.reset()
+        forests[mode] = grow()
+        calls = obs.registry().snapshot()["counters"].get(
+            "tree.kernel.calls", 0)
+        assert (calls > 0) == (mode == "on")
+    _assert_forests_bit_equal(forests["on"], forests["off"])
+
+
+@pytest.mark.parametrize("layout", list(_KERNEL_LAYOUTS))
+@pytest.mark.parametrize("L", [1, 8])
+def test_meshed_hist_program_over_the_kernel(kernel_mode, layout, L):
+    """`_get_hist_program(mesh=)`, the streamed trainer's worker merge:
+    every chip's hist-mode entry turns its own rows' codes, the partials
+    are all-reduced, and the sum is the one-chip kernel's and the XLA
+    builder's histogram bit for bit (whole-number planes)."""
+    import jax.numpy as jnp
+
+    from shifu_tpu.parallel.mesh import data_mesh, shard_rows
+    from shifu_tpu.train import tree_trainer as tt
+
+    slots = _KERNEL_LAYOUTS[layout]
+    lay = make_layout(slots, [False] * len(slots))
+    codes, y, w = _kernel_table(slots, n=1200)
+    rng = np.random.default_rng(L)
+    node = rng.integers(0, L, size=len(y)).astype(np.int32)
+    active = rng.random(len(y)) < 0.8
+    la = tt._device_layout(lay, np.ones(len(slots), bool))
+    rest = (la.off, la.clip, la.seg_t, la.pos_t)
+    rows = tuple(jnp.asarray(a) for a in (codes, y, w, node, active))
+    mesh = data_mesh(4)
+    kernel_mode("off")
+    want = np.asarray(tt._get_hist_program(L, lay)(*rows, *rest))
+    kernel_mode("on")
+    one = np.asarray(tt._get_hist_program(L, lay)(*rows, *rest))
+    four = np.asarray(tt._get_hist_program(L, lay, mesh=mesh)(
+        *(shard_rows(a, mesh) for a in rows), *rest))
+    np.testing.assert_array_equal(one, want)
+    np.testing.assert_array_equal(four, want)

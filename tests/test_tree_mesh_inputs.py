@@ -81,6 +81,37 @@ def test_device_inputs_grow_the_host_arrays_forest(alg, n):
     obs.reset()
 
 
+@pytest.mark.parametrize("alg", ["GBT", "RF"])
+def test_the_meshed_kernel_grows_the_one_chip_forest(alg):
+    """With the kernel on (interpret mode here) every chip's hist-mode
+    entry reads its own rows' codes as `[F, n / chips]`, and the meshed
+    forest is the one-chip kernel's (RF: bit for bit; GBT's bf16 planes
+    round each chip's partial sums, so its splits are held and its leaves
+    to a rounding), in 4 kernel calls a tree and with nothing brought back
+    to the host."""
+    from shifu_tpu.utils import environment
+
+    rows, mesh = _table(1000), data_mesh(4)
+    environment.set_property("shifu.pallas.mode", "on")
+    try:
+        one = _grow(rows, alg, None)
+        obs.reset()
+        four = _grow(tuple(shard_rows(a, mesh) for a in rows), alg, mesh)
+        counters = _counters()
+    finally:
+        environment.set_property("shifu.pallas.mode", "")
+    assert counters["tree.kernel.calls"] == 3 * 4  # 1 chunk x 4 levels
+    assert not counters.get("mesh.d2h_bytes")
+    for ta, tb in zip(one.spec.trees, four.spec.trees):
+        assert np.array_equal(ta.feature, tb.feature)
+        assert np.array_equal(ta.left_mask, tb.left_mask)
+        if alg == "RF":
+            assert np.array_equal(ta.leaf_value, tb.leaf_value)
+        else:
+            np.testing.assert_allclose(ta.leaf_value, tb.leaf_value,
+                                       atol=1e-5)
+
+
 def test_host_inputs_are_put_once_and_say_so():
     mesh = data_mesh(4)
     host = _table(1003)
