@@ -156,7 +156,11 @@ class Tracer:
         """The events that END inside [lo, hi], both `perf_counter` stamps
         (the clock a caller times its own window on), whose name starts with
         `prefix`; in the order they ended."""
-        lo_us, hi_us = (lo - self.t0) * 1e6, (hi - self.t0) * 1e6
+        # an event that ends on a bound is inside, whatever rounding does to
+        # `ts + dur` against a bound taken from the same stamps: a nanosecond
+        # of slack, where a double holds these microseconds to 1e-4
+        lo_us = (lo - self.t0) * 1e6 - 1e-3
+        hi_us = (hi - self.t0) * 1e6 + 1e-3
         with self._lock:
             return [dict(e) for e in self._events
                     if e["name"].startswith(prefix)

@@ -132,6 +132,7 @@ catalog row.)
 from __future__ import annotations
 
 import functools
+import time
 from typing import List, Optional
 
 import numpy as np
@@ -660,6 +661,7 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
             scratch = [pltpu.VMEM((W, W), jnp.float32),
                        pltpu.VMEM((L, W), jnp.float32),
                        pltpu.VMEM((W, L), jnp.float32)]
+        t_trace = time.perf_counter()
         outs = pl.pallas_call(
             kernel,
             grid=(grid,),
@@ -675,9 +677,31 @@ def _build_call(lay_key: tuple, target: int, ci: int, L: int, C: int,
             # tells this kernel and its level from any other in a trace
             metadata={"kernel": name, "L": str(L)},
         )(*args)
+        if isinstance(codes_t, jax.core.Tracer):
+            _record_kernel_trace(t_trace, name, L, ci, W)
         return outs
 
     return call
+
+
+def _record_kernel_trace(start: float, kernel: str, L: int, chunk: int,
+                         W: int) -> None:
+    """Span `tree.kernel.trace` and counter `tree.kernel.traces`: one
+    `pallas_call` made while a program is traced, from `start` to now,
+    which is where the kernel's body is traced into a jaxpr of its own:
+    summed, what a process pays for kernel bodies before its first tree
+    (PERF.md, sections 6 and 7, PR 39). A ring event like `jax.trace`
+    (obs/jaxprobe.py), not a `with` span: it carries the span path open on
+    the tracing thread as its `parent` and opens no frame under the call
+    (a frame more under a traced body is dearer on a chip's host than the
+    body: PERF.md, section 6, PR 39)."""
+    from shifu_tpu.obs import registry, tracer
+
+    registry().counter("tree.kernel.traces").inc()
+    tr = tracer()
+    tr.record("tree.kernel.trace", start, time.perf_counter(),
+              tr.current_path(),
+              {"kernel": kernel, "L": L, "chunk": chunk, "W": W})
 
 
 def _block_rows(n: int, blk: int) -> int:

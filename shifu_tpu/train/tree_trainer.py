@@ -517,8 +517,8 @@ def _get_hist_program(L: int, lay: FeatureLayout,
         from shifu_tpu.ops.hist_pallas import kernel_calls
 
         def counted(*args, _prog=prog,
-                    _calls=kernel_calls(lay, fused=False)):
-            _record_kernel_calls(_calls)
+                    _plan=(("hist", 1, kernel_calls(lay, fused=False)),)):
+            _record_kernel_calls(_plan)
             return _prog(*args)
 
         prog = counted
@@ -1011,37 +1011,51 @@ def _record_hist_counters(built: int, derived: int, fallback: int) -> None:
         reg.counter("tree.hist.fallback_rebuilds").inc(fallback)
 
 
-def _tree_kernel_calls(D: int, lay: FeatureLayout, sub_levels: tuple,
-                       mesh=None) -> int:
-    """Mosaic kernel calls of one whole-tree program (`_get_tree_program`),
-    from static shapes alone: chunks x built levels. A level built by
-    subtraction runs the kernel of the level above it (half the nodes);
-    a kernel is the fused one where `_pallas_state` and
-    `_FUSED_SCAN_L_CAP` say so, else the hist-mode one; 0 with the
-    kernel off."""
+def _tree_kernel_plan(D: int, lay: FeatureLayout, sub_levels: tuple,
+                      mesh=None) -> tuple:
+    """((mode, levels, chunks), ...) of one whole-tree program
+    (`_get_tree_program`), from static shapes alone: for each kernel mode
+    the program uses, "fused" or "hist", the levels built with it and the
+    layout's chunks under that mode's column cap. A level built by
+    subtraction runs the kernel of the level above it (half the nodes); a
+    kernel is the fused one where `_pallas_state` and `_FUSED_SCAN_L_CAP`
+    say so, else the hist-mode one; empty with the kernel off."""
     p_on, _interp, p_fused = _pallas_state(mesh)
     if not p_on:
-        return 0
+        return ()
     from shifu_tpu.ops.hist_pallas import kernel_calls
 
     built_at = [d - 1 if d and sub_levels[d] else d for d in range(D)]
     n_fused = sum(p_fused and 2**i <= _FUSED_SCAN_L_CAP for i in built_at)
-    calls = 0
+    plan = []
     if n_fused:
-        calls += n_fused * kernel_calls(lay, fused=True)
+        plan.append(("fused", n_fused, kernel_calls(lay, fused=True)))
     if D > n_fused:
-        calls += (D - n_fused) * kernel_calls(lay, fused=False)
-    return calls
+        plan.append(("hist", D - n_fused, kernel_calls(lay, fused=False)))
+    return tuple(plan)
 
 
-def _record_kernel_calls(calls: int) -> None:
+def _tree_kernel_calls(D: int, lay: FeatureLayout, sub_levels: tuple,
+                       mesh=None) -> int:
+    """Mosaic kernel calls of one whole-tree program: chunks x built
+    levels over `_tree_kernel_plan`'s modes; 0 with the kernel off."""
+    return sum(levels * chunks for _mode, levels, chunks
+               in _tree_kernel_plan(D, lay, sub_levels, mesh))
+
+
+def _record_kernel_calls(plan: tuple) -> None:
     """`tree.kernel.calls`: Mosaic kernel calls handed to the device, counted
     beside `tree.hist.built` (the whole-tree program's once a tree, a
-    hist program's at each dispatch)."""
-    if calls:
-        from shifu_tpu.obs import registry
+    hist program's at each dispatch), and `tree.kernel.chunks{mode=}`: the
+    layout's chunks under each mode's cap, once for every mode the
+    dispatched program builds a level with. `plan` is
+    `_tree_kernel_plan`'s."""
+    from shifu_tpu.obs import registry
 
-        registry().counter("tree.kernel.calls").inc(calls)
+    reg = registry()
+    for mode, levels, chunks in plan:
+        reg.counter("tree.kernel.calls").inc(levels * chunks)
+        reg.counter("tree.kernel.chunks", mode=mode).inc(chunks)
 
 
 def _psum_counts(D: int, T: int, sub_levels: tuple,
@@ -1546,7 +1560,7 @@ def build_tree(
 
         _record_hist_counters(
             *_plan_counts(sub_levels[:D], cfg.hist_subtraction))
-        _record_kernel_calls(_tree_kernel_calls(D, lay, sub_levels, mesh))
+        _record_kernel_calls(_tree_kernel_plan(D, lay, sub_levels, mesh))
         _record_route_counters(D, lay.s_max)
         if mesh is not None:
             _record_psum_counters(D, lay.T, sub_levels, cfg.n_classes)
@@ -2200,8 +2214,8 @@ def train_trees(
                 sub_levels, acc64 = _sub_plan(cfg, batch_cap)
                 sub_counts = _plan_counts(sub_levels[:cfg.max_depth],
                                           cfg.hist_subtraction)
-                kernel_calls = _tree_kernel_calls(cfg.max_depth, lay,
-                                                  sub_levels, mesh)
+                kernel_plan = _tree_kernel_plan(cfg.max_depth, lay,
+                                                sub_levels, mesh)
                 tree_prog = _get_tree_program(
                     cfg.max_depth, lay, cfg.impurity,
                     cfg.min_instances_per_node, cfg.min_info_gain,
@@ -2295,7 +2309,7 @@ def train_trees(
                     feats_d, masks_d, leaves_d, _resting, tree_pred = tree_prog(
                         codes_j, *codes8_forest, labels_k, w_k, fot)
                     _record_hist_counters(*sub_counts)
-                    _record_kernel_calls(kernel_calls)
+                    _record_kernel_calls(kernel_plan)
                     _record_route_counters(cfg.max_depth, lay.s_max)
                     if mesh is not None:
                         _record_psum_counters(cfg.max_depth, lay.T,

@@ -429,3 +429,70 @@ def test_levels_beyond_the_rule_go_to_the_hist_kernel(monkeypatch):
     assert fused_L == [1, 2, 4, 8, 16, 32] and L_MAX == 32
     assert hist_L == [64, 128]
     assert hp._SCAN_W_CAP == 512
+
+
+# 255 bins (higgs_gbt_255): more than 128 slots a column makes the code
+# operand int32, a block of it one sublane tile of 8 features, and 256 slots
+# fill two lanes' worth of columns each: 2 features a chunk under the fused
+# scan's cap, 4 in hist mode, with whole 256-column segments in the [W, W]
+# scan. One chunk of each kind at the cell's 11,000,000 rows and its widest
+# level of that mode (a built half of 32 nodes fused, of 64 in hist mode),
+# on bf16 planes. Not the whole-tree program (105 such calls): it takes
+# 160 s to compile here, PERF.md section 4 has its reading. The temporaries
+# are the row operands in whole blocks: 10 B a row of planes and node ids,
+# and the code operand's pad (8 B and 16 B a row for 2 and 4 int32 codes;
+# 11,000,000 rows are not whole blocks of 512).
+@pytest.mark.parametrize("entry,features,L,cap", [
+    ("fused", 2, L_MAX, 40), ("hist", 4, 2 * L_MAX, 60)])
+def test_one_chunk_of_256_slot_columns_at_whole_higgs(one_chip, entry,
+                                                      features, L, cap):
+    rows = 11_000_000
+    lay = tt.make_layout([256] * features, [False] * features)
+    assert hp.code_dtype(lay) == np.int32
+    codes, labels, weights, node, active = _row_args(one_chip, features,
+                                                     rows)
+    operand = _code_operand(one_chip, lay, rows)
+    assert operand.dtype == jnp.int32 and operand.shape == (features, rows)
+    if entry == "fused":
+        chunks = hp._chunks(lay, hp._SCAN_W_CAP)
+        fn = hp.make_fused_level_fn(L, lay, "variance", 100, 0.0,
+                                    low_precision=True)
+        args = (codes, operand, labels, weights, node, active,
+                _shape(one_chip, (lay.T,), jnp.bool_))
+    else:
+        chunks = hp._chunks(lay)
+        fn = hp.make_pallas_hist_fn(L, lay, low_precision=True)
+        args = (codes, labels, weights, node, active, operand)
+    assert [(ch.w, ch.f_hi - ch.f_lo) for ch in chunks] == [
+        (256 * features, features)]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _compiled_kernels(compiled) == 1
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < cap * rows, temp / rows
+
+
+@pytest.mark.parametrize("fused,ci,L", [(True, 13, 1), (False, 6, 2 * L_MAX)])
+def test_the_last_chunk_of_28_columns_reads_a_ragged_code_block(one_chip,
+                                                                fused, ci, L):
+    """At all 28 columns of 256 slots a chunk's code block is one tile of 8
+    int32 rows of the `[28, n]` operand, and 28 rows are three tiles and a
+    half: the last fused chunk (features 26 and 27) and the last hist-mode
+    chunk (24 to 27) read block 3, rows 24 to 31, of which four are past the
+    operand's end. Mosaic takes the ragged block (and the chip reads the
+    reference's forest through it: PERF.md section 2)."""
+    lay = tt.make_layout([256] * 28, [False] * 28)
+    target = hp._target(fused=fused)
+    ch = hp._chunks(lay, target)[ci]
+    assert (ch.f_lo, ch.f_hi) == ((26, 28) if fused else (24, 28))
+    assert hp._code_window(ch, lay) == (8, 3)
+    blk, C = hp.blk_setting(), 3
+    rows = -(-11_000_000 // blk) * blk
+    call = hp._build_call(lay.key, target, ci, L, C, blk, True,
+                          ("variance", 100, 0.0, 0) if fused else None, False)
+    args = [_code_operand(one_chip, lay, rows),
+            _shape(one_chip, (C, rows), jnp.bfloat16),
+            _shape(one_chip, (1, rows), jnp.int32)]
+    if fused:
+        args.append(_shape(one_chip, (1, ch.w), jnp.float32))
+    compiled = jax.jit(call).lower(*args).compile()
+    assert _compiled_kernels(compiled) == 1

@@ -495,6 +495,10 @@ def test_tree_kernel_calls_counts_a_trees_mosaic_calls(pallas_on):
     # subtraction on: level 64 is built by the fused kernel of level 32
     (HIGGS[0], 8, True, False, 2 * 7 + 1 * 1),
     ([33] * 20 + [65] * 10, 3, True, False, 3 * 3),
+    # 255 bins (higgs_gbt_255): two 256-slot features a fused chunk, four
+    # in hist mode: 7 fused levels x 14 + the level built at 64 nodes x 7
+    ([256] * 28, 8, True, False, 14 * 7 + 7 * 1),
+    ([256] * 28, 8, True, True, 7 * 8),
 ])
 def test_tree_kernel_calls_from_static_shapes(pallas_on, slots, D, sub,
                                               meshed, want):
@@ -571,6 +575,81 @@ def test_hist_program_counts_its_kernel_calls_at_each_dispatch(pallas_on):
     assert h.shape == (3, 2, lay.T)
     # 45 + 1,500 columns at wmax 1,024: two chunks
     assert obs.registry().counter("tree.kernel.calls").value == 2 * 2
+    assert obs.registry().counter("tree.kernel.chunks",
+                                  mode="hist").value == 2 * 2
+    # the program is traced once: a `tree.kernel.trace` a chunk, under no
+    # trainer's span here
+    assert obs.registry().counter("tree.kernel.traces").value == 2
+    spans = [e for e in obs.tracer().events
+             if e["name"] == "tree.kernel.trace"]
+    assert [(e["args"]["kernel"], e["args"]["L"], e["args"]["chunk"])
+            for e in spans] == [("tree_hist", 2, 0), ("tree_hist", 2, 1)]
+    assert all("parent" not in e["args"] for e in spans)
+
+
+def test_kernel_chunks_at_255_bins_are_14_fused_and_7_in_hist_mode(
+        pallas_on):
+    """`tree.kernel.chunks{mode=}` is the layout's chunks under each cap:
+    256 slots fill two lanes' worth of columns each, so the fused scan's
+    512-column cap puts 2 features in a chunk and wmax 1,024 puts 4."""
+    from shifu_tpu.ops import hist_pallas as hp
+
+    lay = tt.make_layout([256] * 28, [False] * 28)
+    sub_levels = (False,) + (True,) * 8
+    assert tt._tree_kernel_plan(8, lay, sub_levels) == (
+        ("fused", 7, 14), ("hist", 1, 7))
+    assert tt._tree_kernel_calls(8, lay, sub_levels) == 105
+    assert tt._tree_kernel_plan(8, lay, sub_levels, object()) == (
+        ("hist", 8, 7),)
+    fused = hp._chunks(lay, hp._SCAN_W_CAP)
+    assert [(c.f_lo, c.f_hi, c.w) for c in fused] == [
+        (2 * i, 2 * i + 2, 512) for i in range(14)]
+    assert [(c.f_lo, c.f_hi, c.w) for c in hp._chunks(lay)] == [
+        (4 * i, 4 * i + 4, 1024) for i in range(7)]
+    assert hp.code_dtype(lay) == np.int32
+    # an int32 code block is one sublane tile of 8 features
+    assert [hp._code_window(c, lay) for c in fused[:5]] == [
+        (8, 0)] * 4 + [(8, 1)]
+    obs.reset()
+    tt._record_kernel_calls(tt._tree_kernel_plan(8, lay, sub_levels))
+    tt._record_kernel_calls(tt._tree_kernel_plan(8, lay, sub_levels))
+    counters = obs.registry().snapshot()["counters"]
+    assert counters["tree.kernel.calls"] == 2 * 105
+    assert counters['tree.kernel.chunks{mode="fused"}'] == 2 * 14
+    assert counters['tree.kernel.chunks{mode="hist"}'] == 2 * 7
+    tt._record_kernel_calls(())  # the kernel off: nothing is counted
+    assert obs.registry().snapshot()["counters"] == counters
+
+
+def test_every_kernel_call_of_a_traced_tree_leaves_a_trace_span(rows,
+                                                                pallas_on):
+    """`tree.kernel.trace`: one a `pallas_call` made while the whole-tree
+    program is traced, with the kernel's name, level and chunk, under the
+    trainer's span, and `tree.kernel.traces` counts them; a second
+    `train_trees` call traces nothing and adds none."""
+    obs.reset()
+    lay = tt.make_layout([SLOTS] * F, [False] * F)
+    before = set(tt._PROGRAMS)
+    try:
+        _grow(rows, trees=2)
+        _grow(rows, trees=2)
+    finally:
+        for k in set(tt._PROGRAMS) - before:
+            del tt._PROGRAMS[k]  # built under a mode this test set
+    spans = [e for e in obs.tracer().events
+             if e["name"] == "tree.kernel.trace"]
+    # depth 3, one chunk: the root and the fused kernels of levels 1 and 2
+    assert [(e["args"]["kernel"], e["args"]["L"], e["args"]["chunk"],
+             e["args"]["W"]) for e in spans] == [
+        ("tree_fused_level", 1, 0, 128), ("tree_fused_level", 1, 0, 128),
+        ("tree_fused_level", 2, 0, 128)]
+    assert {e["args"]["parent"] for e in spans} == {TREE}
+    assert all(e["dur"] > 0 for e in spans)
+    reg = obs.registry()
+    assert reg.counter("tree.kernel.traces").value == 3
+    assert reg.counter("tree.kernel.calls").value == 4 * 3
+    assert reg.counter("tree.kernel.chunks", mode="fused").value == 4
+    assert tt._tree_kernel_calls(3, lay, (False, True, True, True)) == 3
 
 
 def test_nn_program_carries_its_scopes():
