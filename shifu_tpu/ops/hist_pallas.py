@@ -1022,22 +1022,13 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
         wide_cols = np.concatenate(
             [np.arange(int(lay.off[f]), int(lay.off[f]) + int(lay.slots[f]),
                        dtype=np.int64) for f in wide])
-        w_slots = np.asarray([int(lay.slots[f]) for f in wide], np.int32)
-        w_off = np.zeros(len(wide), np.int32)
-        w_off[1:] = np.cumsum(w_slots[:-1])
-        w_seg = np.repeat(np.arange(len(wide), dtype=np.int32), w_slots)
-        w_pos = np.arange(int(w_slots.sum()), dtype=np.int32) - w_off[w_seg]
-        w_start = w_off[w_seg]
-        w_size = w_slots[w_seg]
-        w_iscat = np.asarray(
-            [bool(lay.is_cat_t[lay.off[f]]) for f in wide])[w_seg]
-        w_clip = np.maximum(w_slots - 1, 0)
-        w_smax = int(w_slots.max())
         wide_arr = np.asarray(wide, np.int32)
-        from shifu_tpu.train.tree_trainer import _make_scan_fn
+        from shifu_tpu.train.tree_trainer import _make_scan_fn, make_layout
 
-        wide_scan = _make_scan_fn(L, int(w_slots.sum()), w_smax, impurity,
-                                  min_inst, min_gain, n_classes)
+        w_lay = make_layout([int(lay.slots[f]) for f in wide],
+                            [bool(lay.is_cat_t[lay.off[f]]) for f in wide])
+        wide_scan = _make_scan_fn(w_lay, impurity, min_inst, min_gain,
+                                  n_classes)
     off_c = np.asarray(lay.off)
     clip_c = np.asarray(lay.clip_max)
 
@@ -1102,13 +1093,8 @@ def make_fused_level_fn(L: int, lay, impurity: str, min_inst: int,
         rank_flat = rank_all[:, jnp.asarray(keep_all)].astype(jnp.int32)
 
         if wide:
-            sub = wide_scan(
-                hist[:, :, jnp.asarray(wide_cols)],
-                fok_f[jnp.asarray(wide_cols)] > 0,
-                jnp.asarray(w_iscat), jnp.asarray(w_seg),
-                jnp.asarray(w_pos), jnp.asarray(w_start),
-                jnp.asarray(w_size), jnp.asarray(w_off),
-                jnp.asarray(w_clip), int(w_slots[0]))
+            sub = wide_scan(hist[:, :, jnp.asarray(wide_cols)],
+                            fok_f[jnp.asarray(wide_cols)] > 0)
             (f_w, cut_w, rank_w, _lv, _sp, g_w, _lm, _nc, lc_w) = sub
             f_wg = jnp.asarray(wide_arr)[f_w]
             o_w = jnp.asarray(off_c)[f_wg].astype(jnp.float32) \

@@ -52,6 +52,7 @@ from shifu_tpu.train.tree_trainer import (
     _node_batch_size,
     _record_hist_counters,
     _record_route_counters,
+    _record_scan_levels,
     _scan_batched,
     _sub_acc64,
     _sub_plan,
@@ -242,7 +243,7 @@ def _grow_leafwise_streamed(feed, work, la, lay, cfg, row_put, pad_to_mesh,
 
     hist1 = _get_hist_program(1, lay, n_classes=cfg.n_classes, mesh=mesh,
                               low_precision=_low_precision(cfg))
-    scan1 = _get_scan_program(1, lay.T, lay.s_max, cfg.impurity,
+    scan1 = _get_scan_program(lay, cfg.impurity,
                               cfg.min_instances_per_node, cfg.min_info_gain,
                               cfg.n_classes)
     max_leaves = cfg.max_leaves
@@ -298,9 +299,8 @@ def _grow_leafwise_streamed(feed, work, la, lay, cfg, row_put, pad_to_mesh,
             (f, c, r, lv, sp, g, m, nc, lc) = scan1(
                 (hist.astype(jnp.float32)
                  if hist.dtype != jnp.float32 else hist),
-                la.feat_ok_t, la.is_cat_t, la.seg_t, la.pos_t,
-                la.start_t, la.size_t, la.off, la.clip, la.seg0_size,
-            )
+                la.feat_ok_t)
+            _record_scan_levels(lay, 1)
             leaf_val[lid] = float(lv[0])
             if bool(sp[0]) and depth_of[lid] < cfg.max_depth:
                 candidates[lid] = (float(g[0]), int(f[0]), int(c[0]),

@@ -163,12 +163,17 @@ class FeatureLayout:
     T: int
     seg_of_t: np.ndarray  # [T] feature id per flat slot
     pos_in_seg: np.ndarray  # [T] slot rank within its segment
-    seg_start_t: np.ndarray  # [T]
     seg_size_t: np.ndarray  # [T]
     is_cat_t: np.ndarray  # [T] bool
     clip_max: np.ndarray  # [F] slots-1
     s_max: int
     key: tuple = ()  # static cache key (the make_layout interning key)
+
+    @property
+    def has_cat(self) -> bool:
+        """Whether any column is categorical: only then does the split
+        scan order a node's slots at run time (`_slot_order`)."""
+        return bool(self.is_cat_t.any())
 
 
 _LAYOUTS: Dict[tuple, FeatureLayout] = {}
@@ -191,7 +196,6 @@ def make_layout(slots: List[int], is_cat: List[bool]) -> FeatureLayout:
         T=T,
         seg_of_t=seg,
         pos_in_seg=pos,
-        seg_start_t=off[seg],
         seg_size_t=slots_np[seg],
         is_cat_t=np.asarray(is_cat, bool)[seg],
         clip_max=np.maximum(slots_np - 1, 0),
@@ -527,22 +531,30 @@ def _get_hist_program(L: int, lay: FeatureLayout,
     return prog
 
 
-def _make_scan_fn(L: int, T: int, s_max: int, impurity: str,
-                  min_inst: int, min_gain: float, n_classes: int = 0):
+def _make_scan_fn(lay: FeatureLayout, impurity: str, min_inst: int,
+                  min_gain: float, n_classes: int = 0):
     """Raw (unjitted) reference split scan — shared by the jitted scan
     program and the Pallas fused path, which reuses it for the derived
     sibling halves of histogram subtraction and as the fallback for
-    features too wide for one in-kernel chunk."""
+    features too wide for one in-kernel chunk.
+
+    Traced fn (hist [C, L, T], feat_ok_t [T]) -> (feature, cut_rank,
+    rank_flat, leaf_value, is_split, best_gain, left_mask, node_cnt,
+    left_cnt), at any node count L (`_best_split`). The layout is read at trace time: every segment's
+    bounds are static slices, and where no column is categorical
+    (`lay.has_cat` False) the slots are scanned in the layout's own order,
+    with no sort, reorder or rank scatter; a categorical layout orders each
+    node's slots by mean label (`_slot_order`)."""
     if n_classes >= 3:
-        return _make_cls_scan(L, T, s_max, impurity, min_inst, min_gain,
-                              n_classes)
-    return _make_split_scan(L, T, s_max, impurity, min_inst, min_gain)
+        return _make_cls_scan(lay, impurity, min_inst, min_gain, n_classes)
+    return _make_split_scan(lay, impurity, min_inst, min_gain)
 
 
-def _get_scan_program(L: int, T: int, s_max: int, impurity: str,
-                      min_inst: int, min_gain: float, n_classes: int = 0):
-    key = ("scan", L, T, s_max, impurity, min_inst, float(min_gain),
-           n_classes)
+def _get_scan_program(lay: FeatureLayout, impurity: str, min_inst: int,
+                      min_gain: float, n_classes: int = 0):
+    # the layout's key holds its slot counts and categorical flags, which
+    # the scan reads at trace time
+    key = ("scan", lay.key, impurity, min_inst, float(min_gain), n_classes)
     prog = _PROGRAMS.get(key)
     if prog is not None:
         return prog
@@ -550,61 +562,153 @@ def _get_scan_program(L: int, T: int, s_max: int, impurity: str,
 
     prog = profile.wrap(
         "tree.split_scan",
-        jax.jit(_make_scan_fn(L, T, s_max, impurity, min_inst, min_gain,
+        jax.jit(_make_scan_fn(lay, impurity, min_inst, min_gain,
                               n_classes)))
     _PROGRAMS[key] = prog
     return prog
 
 
-def _make_split_scan(L: int, T: int, s_max: int, impurity: str,
-                     min_inst: int, min_gain: float):
+def _segment_runs(lay: FeatureLayout) -> List[Tuple[int, int, int]]:
+    """(first column, segments, width) of each run of consecutive
+    non-empty segments of equal width: one run for a layout whose columns
+    all have the same slot count."""
+    runs: List[list] = []
+    for o, w in zip(lay.off.tolist(), lay.slots.tolist()):
+        if w == 0:
+            continue
+        if runs and runs[-1][2] == w and runs[-1][0] + runs[-1][1] * w == o:
+            runs[-1][1] += 1
+        else:
+            runs.append([o, 1, w])
+    return [tuple(r) for r in runs]
+
+
+def _segment_sums(c, runs):
+    """(left, total) over the last axis of a cumulative plane `c`: each
+    slot's sum from its segment's start, and its segment's whole sum. A
+    segment's base is the column before its start (0 for the first): read
+    by static slices a run at a time, the same elements as a per-slot
+    gather, so the same values to the bit."""
+    import jax.numpy as jnp
+
+    lead = c.shape[:-1]
+    lefts, tots = [], []
+    for o, k, w in runs:
+        blk = c[..., o:o + k * w].reshape(lead + (k, w))
+        end = blk[..., w - 1:]  # [..., k, 1]
+        first = (c[..., o - 1:o] if o else jnp.zeros_like(c[..., :1]))
+        base = first[..., None]
+        if k > 1:
+            base = jnp.concatenate([base, end[..., :-1, :]], axis=-2)
+        lefts.append((blk - base).reshape(lead + (k * w,)))
+        tots.append(jnp.broadcast_to(end - base, blk.shape)
+                    .reshape(lead + (k * w,)))
+    join = (lambda xs: xs[0] if len(xs) == 1  # noqa: E731
+            else jnp.concatenate(xs, axis=-1))
+    return join(lefts), join(tots)
+
+
+def _slot_order(mean, lay: FeatureLayout):
+    """[L, T] original flat slot at each ordered position, for a layout
+    with a categorical column: lexsort on (segment, key) where key is the
+    mean label `mean` [L, T] for categorical segments (the reference's
+    mean-sort category split) and slot position for numeric ones. Segment
+    bounds are static, so feature f stays at [off[f], off[f]+slots[f])."""
     import jax
     import jax.numpy as jnp
 
-    def split_scan(hist, feat_ok_t, is_cat_t, seg_t, pos_t, start_t, size_t,
-                   off_f, clip_f, seg0_size):
-        """Best split per node from the flat histogram.
+    seg_t = jnp.asarray(lay.seg_of_t)
+    sec = jnp.where(jnp.asarray(lay.is_cat_t)[None, :], mean,
+                    jnp.broadcast_to(jnp.asarray(lay.pos_in_seg, jnp.float32),
+                                     mean.shape))
+    return jax.vmap(lambda row: jnp.lexsort((row, seg_t)))(sec)
 
-        Ordered prefix sums inside static segment boundaries: lexsort on
-        (segment, key) where key = mean label for categorical segments
-        (the reference's mean-sort category split) and slot position for
-        numeric ones. Segment boundaries are static, so the ordered layout
-        keeps feature f at [off[f], off[f]+slots[f]).
+
+def _best_split(gain, lcnt, rcnt, feat_ok_t, order, lay: FeatureLayout,
+                min_inst: int, min_gain: float):
+    """The scans' common tail over ordered positions [L, T]: validity,
+    argmax (first best ordered position), and the model-facing mask.
+
+    Returns (feature [L], cut_rank [L], rank_flat [L, T], is_split [L],
+    best_gain [L], left_mask [L, s_max], left_cnt [L]): rank_flat is each
+    ORIGINAL flat slot's rank within its segment's ordering, the layout's
+    own position where `order` is None; left_cnt is the best split's left
+    weighted count, the histogram-subtraction paths' smaller-child
+    selector (garbage where is_split is False)."""
+    import jax.numpy as jnp
+
+    L, T = gain.shape
+    seg_t = jnp.asarray(lay.seg_of_t)
+    pos_t = jnp.asarray(lay.pos_in_seg)
+    valid = (
+        (lcnt >= min_inst)
+        & (rcnt >= min_inst)
+        & (gain > min_gain)
+        & feat_ok_t[None, :]
+        # a cut at a segment's end is no split
+        & jnp.asarray(lay.pos_in_seg < lay.seg_size_t - 1)[None, :]
+    )
+    gain = jnp.where(valid, gain, -jnp.inf)
+
+    best = jnp.argmax(gain, axis=-1)  # ordered position
+    best_gain = jnp.take_along_axis(gain, best[:, None], axis=-1)[:, 0]
+    left_cnt = jnp.take_along_axis(lcnt, best[:, None], axis=-1)[:, 0]
+    feature = seg_t[best].astype(jnp.int32)
+    cut_rank = pos_t[best].astype(jnp.int32)
+    is_split = jnp.isfinite(best_gain)
+
+    # model-facing mask over ORIGINAL codes [L, s_max]
+    s_range = jnp.arange(lay.s_max, dtype=jnp.int32)
+    f_clip = jnp.asarray(lay.clip_max)[feature]  # [L]
+    s_idx = jnp.minimum(s_range[None, :], f_clip[:, None])
+    if order is None:
+        rank_flat = jnp.broadcast_to(pos_t, (L, T))
+        ranks = s_idx
+    else:
+        rank_flat = (
+            jnp.zeros((L, T), jnp.int32)
+            .at[jnp.arange(L)[:, None], order]
+            .set(jnp.broadcast_to(pos_t, (L, T)))
+        )
+        flat_idx = jnp.asarray(lay.off)[feature][:, None] + s_idx
+        ranks = jnp.take_along_axis(rank_flat, flat_idx, axis=-1)
+    left_mask = (
+        (ranks <= cut_rank[:, None])
+        & (s_range[None, :] <= f_clip[:, None])
+        & is_split[:, None]
+    )
+    return (feature, cut_rank, rank_flat, is_split, best_gain, left_mask,
+            left_cnt)
+
+
+def _make_split_scan(lay: FeatureLayout, impurity: str, min_inst: int,
+                     min_gain: float):
+    import jax.numpy as jnp
+
+    runs = _segment_runs(lay)
+    seg0 = int(lay.slots[0]) if len(lay.slots) else 1
+
+    def split_scan(hist, feat_ok_t):
+        """Best split per node from the flat histogram: ordered prefix sums
+        inside static segment boundaries.
 
         Returns (feature [L], cut_rank [L], rank_flat [L, T], leaf_value
         [L], is_split [L], best_gain [L], left_mask_model [L, s_max],
-        node_cnt [L], left_cnt [L]) — left_cnt is the best split's left
-        weighted count, the histogram-subtraction paths' smaller-child
-        selector (garbage where is_split is False)."""
+        node_cnt [L], left_cnt [L]) — see `_best_split`."""
         cnt, s1, s2 = hist[0], hist[1], hist[2]
-        mean = jnp.where(cnt > 0, s1 / jnp.maximum(cnt, 1e-12), jnp.inf)
-        sec = jnp.where(is_cat_t[None, :], mean,
-                        jnp.broadcast_to(pos_t.astype(jnp.float32), cnt.shape))
+        order = None
+        if lay.has_cat:
+            mean = jnp.where(cnt > 0, s1 / jnp.maximum(cnt, 1e-12), jnp.inf)
+            order = _slot_order(mean, lay)
+            cnt, s1, s2 = (jnp.take_along_axis(a, order, axis=-1)
+                           for a in (cnt, s1, s2))
 
-        def order_row(sec_row):
-            return jnp.lexsort((sec_row, seg_t))
-
-        order = jax.vmap(order_row)(sec)  # [L, T] original index per pos
-
-        def reorder(a):
-            return jnp.take_along_axis(a, order, axis=-1)
-
-        c0 = jnp.cumsum(reorder(cnt), axis=-1)
-        c1 = jnp.cumsum(reorder(s1), axis=-1)
-        c2 = jnp.cumsum(reorder(s2), axis=-1)
-
-        start_prev = jnp.maximum(start_t - 1, 0)
-        end_idx = start_t + size_t - 1
-
-        def seg_sums(c):
-            base = jnp.where(start_t > 0, c[:, start_prev], 0.0)
-            left = c - base
-            tot = c[:, end_idx] - base
-            return left, tot
-
-        lcnt, tcnt = seg_sums(c0)
-        ls1, ts1 = seg_sums(c1)
-        ls2, ts2 = seg_sums(c2)
+        c0 = jnp.cumsum(cnt, axis=-1)
+        c1 = jnp.cumsum(s1, axis=-1)
+        c2 = jnp.cumsum(s2, axis=-1)
+        lcnt, tcnt = _segment_sums(c0, runs)
+        ls1, ts1 = _segment_sums(c1, runs)
+        ls2, ts2 = _segment_sums(c2, runs)
         rcnt, rs1, rs2 = tcnt - lcnt, ts1 - ls1, ts2 - ls2
 
         def sse(c, s, q):
@@ -634,51 +738,19 @@ def _make_split_scan(L: int, T: int, s_max: int, impurity: str,
         else:  # variance
             gain = sse(tcnt, ts1, ts2) - sse(lcnt, ls1, ls2) - sse(rcnt, rs1, rs2)
 
-        valid = (
-            (lcnt >= min_inst)
-            & (rcnt >= min_inst)
-            & (gain > min_gain)
-            & feat_ok_t[None, :]
-            & (pos_t < size_t - 1)[None, :]  # cut at segment end = no split
-        )
-        gain = jnp.where(valid, gain, -jnp.inf)
-
-        best = jnp.argmax(gain, axis=-1)  # ordered position
-        best_gain = jnp.take_along_axis(gain, best[:, None], axis=-1)[:, 0]
-        left_cnt = jnp.take_along_axis(lcnt, best[:, None], axis=-1)[:, 0]
-        feature = seg_t[best].astype(jnp.int32)
-        cut_rank = pos_t[best].astype(jnp.int32)
-        is_split = jnp.isfinite(best_gain)
-
-        # rank of each ORIGINAL flat slot within its segment's ordering
-        rank_flat = (
-            jnp.zeros((L, T), jnp.int32)
-            .at[jnp.arange(L)[:, None], order]
-            .set(jnp.broadcast_to(pos_t, (L, T)))
-        )
-
-        node_cnt = c0[:, seg0_size - 1]
-        node_sum = c1[:, seg0_size - 1]
+        (feature, cut_rank, rank_flat, is_split, best_gain, left_mask,
+         left_cnt) = _best_split(gain, lcnt, rcnt, feat_ok_t, order, lay,
+                                 min_inst, min_gain)
+        node_cnt = c0[:, seg0 - 1]
+        node_sum = c1[:, seg0 - 1]
         leaf_value = node_sum / jnp.maximum(node_cnt, 1e-12)
-
-        # model-facing mask over ORIGINAL codes [L, s_max]
-        s_range = jnp.arange(s_max, dtype=jnp.int32)
-        f_clip = clip_f[feature]  # [L]
-        s_idx = jnp.minimum(s_range[None, :], f_clip[:, None])
-        flat_idx = off_f[feature][:, None] + s_idx
-        ranks = jnp.take_along_axis(rank_flat, flat_idx, axis=-1)
-        left_mask = (
-            (ranks <= cut_rank[:, None])
-            & (s_range[None, :] <= f_clip[:, None])
-            & is_split[:, None]
-        )
         return (feature, cut_rank, rank_flat, leaf_value, is_split,
                 best_gain, left_mask, node_cnt, left_cnt)
 
     return split_scan
 
 
-def _make_cls_scan(L: int, T: int, s_max: int, impurity: str, min_inst: int,
+def _make_cls_scan(lay: FeatureLayout, impurity: str, min_inst: int,
                    min_gain: float, K: int):
     """Multi-class split scan over per-class count planes [K, L, T] —
     NATIVE RF classification (reference Entropy/Gini multi-class counts,
@@ -688,37 +760,27 @@ def _make_cls_scan(L: int, T: int, s_max: int, impurity: str, min_inst: int,
 
     Returns the same tuple shape as the regression scan so the tree
     builders are oblivious to the mode."""
-    import jax
     import jax.numpy as jnp
 
     use_entropy = impurity == "entropy"
+    runs = _segment_runs(lay)
+    seg0 = int(lay.slots[0]) if len(lay.slots) else 1
 
-    def cls_scan(hist, feat_ok_t, is_cat_t, seg_t, pos_t, start_t, size_t,
-                 off_f, clip_f, seg0_size):
-        cnt = hist.sum(0)  # [L, T] total weighted count per slot
-        # categorical ordering key: expected class index (the multi-class
-        # generalization of the reference's mean-response category sort)
-        exp = (hist * jnp.arange(K, dtype=jnp.float32)[:, None, None]).sum(0)
-        mean = jnp.where(cnt > 0, exp / jnp.maximum(cnt, 1e-12), jnp.inf)
-        sec = jnp.where(is_cat_t[None, :], mean,
-                        jnp.broadcast_to(pos_t.astype(jnp.float32), cnt.shape))
+    def cls_scan(hist, feat_ok_t):
+        order = None
+        if lay.has_cat:
+            cnt = hist.sum(0)  # [L, T] total weighted count per slot
+            # categorical ordering key: expected class index (the
+            # multi-class generalization of the reference's mean-response
+            # category sort)
+            exp = (hist * jnp.arange(K, dtype=jnp.float32)[:, None, None]
+                   ).sum(0)
+            mean = jnp.where(cnt > 0, exp / jnp.maximum(cnt, 1e-12), jnp.inf)
+            order = _slot_order(mean, lay)
+            hist = jnp.take_along_axis(hist, order[None], axis=-1)
 
-        def order_row(sec_row):
-            return jnp.lexsort((sec_row, seg_t))
-
-        order = jax.vmap(order_row)(sec)  # [L, T]
-
-        def reorder(a):
-            return jnp.take_along_axis(a, order, axis=-1)
-
-        ccum = jnp.cumsum(jax.vmap(reorder)(hist), axis=-1)  # [K, L, T]
-
-        start_prev = jnp.maximum(start_t - 1, 0)
-        end_idx = start_t + size_t - 1
-        base = jnp.where(start_t[None, None, :] > 0,
-                         ccum[:, :, start_prev], 0.0)
-        left = ccum - base  # per-class left counts
-        tot = ccum[:, :, end_idx] - base
+        ccum = jnp.cumsum(hist, axis=-1)  # [K, L, T]
+        left, tot = _segment_sums(ccum, runs)  # per-class counts
         right = tot - left
         lcnt = left.sum(0)
         rcnt = right.sum(0)
@@ -734,42 +796,12 @@ def _make_cls_scan(L: int, T: int, s_max: int, impurity: str, min_inst: int,
 
         gain = (mass(tot, tcnt) - mass(left, lcnt) - mass(right, rcnt))
 
-        valid = (
-            (lcnt >= min_inst)
-            & (rcnt >= min_inst)
-            & (gain > min_gain)
-            & feat_ok_t[None, :]
-            & (pos_t < size_t - 1)[None, :]
-        )
-        gain = jnp.where(valid, gain, -jnp.inf)
-
-        best = jnp.argmax(gain, axis=-1)
-        best_gain = jnp.take_along_axis(gain, best[:, None], axis=-1)[:, 0]
-        left_cnt = jnp.take_along_axis(lcnt, best[:, None], axis=-1)[:, 0]
-        feature = seg_t[best].astype(jnp.int32)
-        cut_rank = pos_t[best].astype(jnp.int32)
-        is_split = jnp.isfinite(best_gain)
-
-        rank_flat = (
-            jnp.zeros((L, T), jnp.int32)
-            .at[jnp.arange(L)[:, None], order]
-            .set(jnp.broadcast_to(pos_t, (L, T)))
-        )
-
-        node_class_cnt = ccum[:, :, seg0_size - 1]  # [K, L]
+        (feature, cut_rank, rank_flat, is_split, best_gain, left_mask,
+         left_cnt) = _best_split(gain, lcnt, rcnt, feat_ok_t, order, lay,
+                                 min_inst, min_gain)
+        node_class_cnt = ccum[:, :, seg0 - 1]  # [K, L]
         node_cnt = node_class_cnt.sum(0)
         leaf_value = jnp.argmax(node_class_cnt, axis=0).astype(jnp.float32)
-
-        s_range = jnp.arange(s_max, dtype=jnp.int32)
-        f_clip = clip_f[feature]
-        s_idx = jnp.minimum(s_range[None, :], f_clip[:, None])
-        flat_idx = off_f[feature][:, None] + s_idx
-        ranks = jnp.take_along_axis(rank_flat, flat_idx, axis=-1)
-        left_mask = (
-            (ranks <= cut_rank[:, None])
-            & (s_range[None, :] <= f_clip[:, None])
-            & is_split[:, None]
-        )
         return (feature, cut_rank, rank_flat, leaf_value, is_split,
                 best_gain, left_mask, node_cnt, left_cnt)
 
@@ -1109,6 +1141,31 @@ def _record_route_counters(D: int, s_max: int) -> None:
         reg.counter("tree.route.gather").inc(gather)
 
 
+def _tree_scan_levels(D: int, sub_levels: tuple, mesh=None) -> int:
+    """Levels of one whole-tree program (`_get_tree_program`) whose split
+    scan is XLA's, from static shapes alone: every level but one built and
+    scanned in the fused kernel alone (no parent level kept, and few
+    enough nodes for `_FUSED_SCAN_L_CAP`). A level derived from a fused
+    parent scans its derived half in XLA."""
+    p_fused = _pallas_state(mesh)[2]
+    subs = tuple(sub_levels) + (False,) * D
+    return sum(not ((d == 0 or not subs[d]) and p_fused
+                    and 2**d <= _FUSED_SCAN_L_CAP) for d in range(D))
+
+
+def _record_scan_levels(lay: FeatureLayout, levels: int) -> None:
+    """`tree.scan.levels{order=}`: XLA split scans handed to the device,
+    `layout` where the slots are scanned in the layout's own order,
+    `sorted` where a categorical column has them sorted at run time; the
+    whole-tree program's once a tree (`_tree_scan_levels`), a
+    `tree.split_scan` program's at each dispatch."""
+    from shifu_tpu.obs import registry
+
+    if levels:
+        order = "sorted" if lay.has_cat else "layout"
+        registry().counter("tree.scan.levels", order=order).inc(levels)
+
+
 @dataclass
 class _LayoutArrays:
     """Device copies of the static layout arrays."""
@@ -1116,12 +1173,8 @@ class _LayoutArrays:
     off: object
     clip: object
     feat_ok_t: object
-    is_cat_t: object
     seg_t: object
     pos_t: object
-    start_t: object
-    size_t: object
-    seg0_size: int
 
 
 def _device_layout(lay: FeatureLayout, feat_ok: np.ndarray, replicate_fn=None):
@@ -1131,16 +1184,11 @@ def _device_layout(lay: FeatureLayout, feat_ok: np.ndarray, replicate_fn=None):
         off=jnp.asarray(lay.off),
         clip=jnp.asarray(lay.clip_max),
         feat_ok_t=jnp.asarray(np.asarray(feat_ok, bool)[lay.seg_of_t]),
-        is_cat_t=jnp.asarray(lay.is_cat_t),
         seg_t=jnp.asarray(lay.seg_of_t),
         pos_t=jnp.asarray(lay.pos_in_seg),
-        start_t=jnp.asarray(lay.seg_start_t),
-        size_t=jnp.asarray(lay.seg_size_t),
-        seg0_size=int(lay.slots[0]) if len(lay.slots) else 1,
     )
     if replicate_fn is not None:
-        for name in ("off", "clip", "feat_ok_t", "is_cat_t", "seg_t",
-                     "pos_t", "start_t", "size_t"):
+        for name in ("off", "clip", "feat_ok_t", "seg_t", "pos_t"):
             setattr(arrs, name, replicate_fn(getattr(arrs, name)))
     return arrs
 
@@ -1151,14 +1199,11 @@ def _scan_batched(hists, la, lay, cfg, L_level):
     feats, cuts, ranks, leaves, splits, gains, masks, cnts, lcnts = (
         [], [], [], [], [], [], [], [], []
     )
-    for hist, Lb, _b0 in hists:
-        scan = _get_scan_program(Lb, lay.T, lay.s_max, cfg.impurity,
-                                 cfg.min_instances_per_node,
-                                 cfg.min_info_gain, cfg.n_classes)
-        (f, c, r, lv, sp, g, m, nc, lc) = scan(
-            hist, la.feat_ok_t, la.is_cat_t, la.seg_t, la.pos_t, la.start_t,
-            la.size_t, la.off, la.clip, la.seg0_size,
-        )
+    scan = _get_scan_program(lay, cfg.impurity, cfg.min_instances_per_node,
+                             cfg.min_info_gain, cfg.n_classes)
+    for hist, _Lb, _b0 in hists:
+        (f, c, r, lv, sp, g, m, nc, lc) = scan(hist, la.feat_ok_t)
+        _record_scan_levels(lay, 1)
         feats.append(f); cuts.append(c); ranks.append(r); leaves.append(lv)
         splits.append(sp); gains.append(g); masks.append(m); cnts.append(nc)
         lcnts.append(lc)
@@ -1292,7 +1337,7 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
     import jax
     import jax.numpy as jnp
 
-    T, s_max = lay.T, lay.s_max
+    s_max = lay.s_max
     min_inst_eff = max(min_inst, 1)
     # the in-kernel scan unrolls an L-iteration node loop over [W, W]
     # indicators; past this width the program size outweighs the fusion
@@ -1317,22 +1362,17 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
     else:
         hist_fns = [_make_hist_fn(2**d, lay, n_classes=n_classes)
                     for d in range(D)]
-    scan_fns = [_get_scan_program(2**d, T, s_max, impurity, min_inst_eff,
-                                  min_gain, n_classes) for d in range(D)]
-    raw_scan_fns = ([_make_scan_fn(2**d, T, s_max, impurity, min_inst_eff,
-                                   min_gain, n_classes) for d in range(D)]
-                    if p_fused else None)
+    scan_fn = _get_scan_program(lay, impurity, min_inst_eff, min_gain,
+                                n_classes)
+    raw_scan_fn = (_make_scan_fn(lay, impurity, min_inst_eff, min_gain,
+                                 n_classes) if p_fused else None)
     leaf_acc, leaf_finalize = _make_leaf_fn(2**D, n_classes)
 
     # static layout constants (closed over; jit hoists them once)
     off_c = jnp.asarray(lay.off)
     clip_c = jnp.asarray(lay.clip_max)
-    is_cat_c = jnp.asarray(lay.is_cat_t)
     seg_c = jnp.asarray(lay.seg_of_t)
     pos_c = jnp.asarray(lay.pos_in_seg)
-    start_c = jnp.asarray(lay.seg_start_t)
-    size_c = jnp.asarray(lay.seg_size_t)
-    seg0 = int(lay.slots[0]) if len(lay.slots) else 1
     on_mesh = mesh is not None
     if on_mesh:
         from shifu_tpu.parallel.mesh import row_axes
@@ -1372,10 +1412,8 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                     h = jax.lax.psum(h, r_axes)
             return h
 
-        def xla_scan(idx, hist, raw=False):
-            fn = raw_scan_fns[idx] if raw else scan_fns[idx]
-            return fn(hist, feat_ok_t, is_cat_c, seg_c, pos_c, start_c,
-                      size_c, off_c, clip_c, seg0)
+        def xla_scan(hist, raw=False):
+            return (raw_scan_fn if raw else scan_fn)(hist, feat_ok_t)
 
         # One named scope a level and one a phase inside it: op metadata
         # only (the `tf_op` a profiler trace shows for each device
@@ -1411,8 +1449,7 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                                         p_hist - b_acc,
                                         jnp.zeros_like(p_hist))
                 with phase(L, "scan"):
-                    scan_d = xla_scan(d - 1, derived.astype(jnp.float32),
-                                      raw=True)
+                    scan_d = xla_scan(derived.astype(jnp.float32), raw=True)
                 with phase(L, "derive"):
                     (bf, _br, _rank, lv, is_split, _g, lm, nc,
                      lc) = tuple(
@@ -1440,13 +1477,13 @@ def _get_tree_program(D: int, lay: FeatureLayout, impurity: str,
                                             left_small)
                 with phase(L, "scan"):
                     (bf, _br, _rank, lv, is_split, _g, lm, nc,
-                     lc) = xla_scan(d, hist)
+                     lc) = xla_scan(hist)
             else:
                 hist = call_hist(L, d, node, active)
                 hist_acc = hist.astype(acc_dt) if acc64 else hist
                 with phase(L, "scan"):
                     (bf, _br, _rank, lv, is_split, _g, lm, nc,
-                     lc) = xla_scan(d, hist)
+                     lc) = xla_scan(hist)
             retain = d + 1 < D and sub_levels[d + 1]
             with phase(L, "route"):
                 left_small = lc <= nc - lc if retain else None
@@ -1569,6 +1606,7 @@ def build_tree(
         _record_kernel_calls(_tree_kernel_plan(D, lay, sub_levels, mesh,
                                                cfg.n_classes))
         _record_route_counters(D, lay.s_max)
+        _record_scan_levels(lay, _tree_scan_levels(D, sub_levels, mesh))
         if mesh is not None:
             _record_psum_counters(D, lay.T, sub_levels, cfg.n_classes)
         feats_h, masks_h, leaves_h = jax.device_get(
@@ -1723,7 +1761,7 @@ def build_tree_leafwise(
 
     hist1 = _get_hist_program(1, lay, n_classes=cfg.n_classes,
                               low_precision=_low_precision(cfg))
-    scan1 = _get_scan_program(1, lay.T, lay.s_max, cfg.impurity,
+    scan1 = _get_scan_program(lay, cfg.impurity,
                               cfg.min_instances_per_node, cfg.min_info_gain,
                               cfg.n_classes)
     # parent-reuse: each candidate's histogram is retained (budget-gated by
@@ -1750,9 +1788,8 @@ def build_tree_leafwise(
         downcast only for the scan."""
         (f, c, r, lv, sp, g, m, nc, lc) = scan1(
             hist.astype(jnp.float32) if hist.dtype != jnp.float32 else hist,
-            la.feat_ok_t, la.is_cat_t, la.seg_t, la.pos_t,
-            la.start_t, la.size_t, la.off, la.clip, la.seg0_size,
-        )
+            la.feat_ok_t)
+        _record_scan_levels(lay, 1)
         leaf_val[lid] = float(lv[0])
         if bool(sp[0]) and depth_of[lid] < cfg.max_depth:
             candidates[lid] = (float(g[0]), int(f[0]), int(c[0]),
@@ -2224,6 +2261,8 @@ def train_trees(
                 kernel_plan = _tree_kernel_plan(cfg.max_depth, lay,
                                                 sub_levels, mesh,
                                                 cfg.n_classes)
+                scan_levels = _tree_scan_levels(cfg.max_depth, sub_levels,
+                                                mesh)
                 tree_prog = _get_tree_program(
                     cfg.max_depth, lay, cfg.impurity,
                     cfg.min_instances_per_node, cfg.min_info_gain,
@@ -2319,6 +2358,7 @@ def train_trees(
                     _record_hist_counters(*sub_counts)
                     _record_kernel_calls(kernel_plan)
                     _record_route_counters(cfg.max_depth, lay.s_max)
+                    _record_scan_levels(lay, scan_levels)
                     if mesh is not None:
                         _record_psum_counters(cfg.max_depth, lay.T,
                                               sub_levels, cfg.n_classes)

@@ -323,7 +323,9 @@ def test_forest_tree_program_at_the_rf_cells_size(one_chip, monkeypatch):
     assert tt._tree_kernel_calls(D, lay, sub_levels, None, cfg.n_classes) \
         == 10
     assert re.findall(r"= \w+\[%d\][^\n=]* gather\(" % rows, text) == []
-    assert len(re.findall(r" gather\(", text)) > 100  # the pattern's opcode
+    # the pattern's opcode: 93 gathers, none per row (189 while the XLA
+    # scan reordered its planes and gathered its segment bounds by column)
+    assert len(re.findall(r" gather\(", text)) > 80
     mem = compiled.memory_analysis()
     assert 0 < mem.temp_size_in_bytes < 110 * rows, \
         mem.temp_size_in_bytes / rows

@@ -563,11 +563,9 @@ def _run_scan_pair(slots, is_cat, codes, y, w, L, impurity, n_classes=0,
             jnp.asarray(codes), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(node), jnp.asarray(active), la.off, la.clip,
             la.seg_t, la.pos_t)
-        scan = jax.jit(_make_scan_fn(L, lay.T, lay.s_max, impurity,
-                                     min_inst, 0.0, n_classes))
-        ref = scan(h_ref, fot, la.is_cat_t, la.seg_t, la.pos_t,
-                   la.start_t, la.size_t, la.off, la.clip,
-                   int(lay.slots[0]))
+        scan = jax.jit(_make_scan_fn(lay, impurity, min_inst, 0.0,
+                                     n_classes))
+        ref = scan(h_ref, fot)
         fused = jax.jit(make_fused_level_fn(
             L, lay, impurity, min_inst, 0.0, n_classes=n_classes,
             interpret=True, low_precision=low_precision))

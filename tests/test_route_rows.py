@@ -57,11 +57,8 @@ def _level(cols, L, seed, n_classes=0, dead_nodes=True):
     if dead_nodes:
         hist[:, 1::3, :] = 0.0
     la = tt._device_layout(lay, np.ones(len(slots), bool))
-    scan = tt._get_scan_program(L, lay.T, lay.s_max, "variance", 1, 0.0,
-                                n_classes)
-    out = scan(jnp.asarray(hist), la.feat_ok_t, la.is_cat_t, la.seg_t,
-               la.pos_t, la.start_t, la.size_t, la.off, la.clip,
-               la.seg0_size)
+    scan = tt._get_scan_program(lay, "variance", 1, 0.0, n_classes)
+    out = scan(jnp.asarray(hist), la.feat_ok_t)
     return lay, la, out
 
 
