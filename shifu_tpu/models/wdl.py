@@ -110,16 +110,31 @@ def unflatten_wdl(flat, template: WDLParams) -> WDLParams:
     )
 
 
-def wdl_plane(embed, wide, first_w, dense, codes):
+def wdl_plane(embed, wide, first_w, dense, codes, onehot=None):
     """One lookup a categorical field: the field's embedding row and its wide
     weight sit side by side in a `[vocab_f, E + 1]` table put together here
     from `embed[f]` and `wide[f]` (a pass over the table's rows, not the
-    data's), and one gather reads both; under `jax.grad` its transpose is one
-    scatter-add a field into that table, which the assembly's transpose
-    splits into the gradients of `embed[f]` and `wide[f]`. The gathered
-    planes are never cut apart (a column cut from a `[n, E + 1]` plane is a
-    pass over the rows of its own, as dear as the lookup it saves): they are
-    concatenated whole.
+    data's), and one gather reads both. The gathered planes are never cut
+    apart (a column cut from a `[n, E + 1]` plane is a pass over the rows of
+    its own, as dear as the lookup it saves): they are concatenated whole.
+
+    Under `jax.grad` a lookup has one of two transposes, and the assembly's
+    transpose splits what it gives into the gradients of `embed[f]` and
+    `wide[f]`:
+    - the gather's own, one scatter-add a field into its table, which costs
+      a pass over the rows whatever the table's size (PERF.md, section 5).
+      Every table takes it without `onehot`, and with it every table that
+      is not among its `fields` (those of more than 4,096 rows always);
+    - with `onehot` = (fields, codes_t, interpret), the tables of `fields`
+      take ONE call of the one-hot kernel for all of them
+      (`ops/onehot_grad_pallas.py`: a contraction on the MXU whose cost
+      grows with the table's rows, where the scatter's does not). The
+      trainer gives it the tables of at most `ROWS_CAP` = 4,096 rows that
+      one call holds in VMEM (`small_fields`): a cap that keeps a table's
+      part of the call to 32 tiles and lies under the kernel's measured
+      break-even with the scatter, near 23,000 rows (PERF.md, section 7).
+      `codes_t` is `codes_operand` of those fields, made once a program;
+      their forward is the same gather.
 
     Returns the plane `h` [n, Dn + Dc x (E + 1)]; the tower's first weight
     `first_w` [Dn + Dc x E, H] given a zero row at every wide column, so
@@ -130,16 +145,27 @@ def wdl_plane(embed, wide, first_w, dense, codes):
     import jax.numpy as jnp
 
     first_w = jnp.asarray(first_w)
-    pieces, at = [dense], dense.shape[1]
-    w_rows = [first_w[:at]]
-    no_row = jnp.zeros((1, first_w.shape[1]), first_w.dtype)
-    is_wide = [0.0] * at
+    tables, idxs = [], []
     for f, (emb, wd) in enumerate(zip(embed, wide, strict=True)):
         # params may be host numpy (loaded spec)
         tb = jnp.concatenate([jnp.asarray(emb), jnp.asarray(wd)[:, None]],
                              axis=1)
-        idx = jnp.clip(codes[:, f], 0, tb.shape[0] - 1)
-        pieces.append(tb[idx])
+        tables.append(tb)
+        idxs.append(jnp.clip(codes[:, f], 0, tb.shape[0] - 1))
+    looked = {}
+    if onehot is not None:
+        from shifu_tpu.ops import onehot_grad_pallas
+
+        fields, codes_t, interpret = onehot
+        looked = dict(zip(fields, onehot_grad_pallas.lookups(
+            [tables[f] for f in fields], [idxs[f] for f in fields], codes_t,
+            interpret)))
+    pieces, at = [dense], dense.shape[1]
+    w_rows = [first_w[:at]]
+    no_row = jnp.zeros((1, first_w.shape[1]), first_w.dtype)
+    is_wide = [0.0] * at
+    for f, (emb, tb, idx) in enumerate(zip(embed, tables, idxs)):
+        pieces.append(looked[f] if f in looked else tb[idx])
         e = emb.shape[1]
         w_rows += [first_w[at:at + e], no_row]
         is_wide += [0.0] * e + [1.0]
@@ -149,7 +175,7 @@ def wdl_plane(embed, wide, first_w, dense, codes):
 
 
 def wdl_forward(p: WDLParams, dense, codes, activations: List[str],
-                logits_only: bool = False):
+                logits_only: bool = False, onehot=None):
     """dense [n, Dn], codes [n, Dc] -> [n] probability (or raw logit).
 
     One lookup a categorical field for its embedding row and its wide weight
@@ -162,7 +188,8 @@ def wdl_forward(p: WDLParams, dense, codes, activations: List[str],
     the tower, `wdl.wide` the selector dot and the dense dot. Under
     `jax.grad` their halves read `jvp(wdl.embed)`, the gathers, and
     `transpose(jvp(wdl.embed))`, one scatter-add a field into its
-    `[vocab_f, E + 1]` table."""
+    `[vocab_f, E + 1]` table, or for `onehot`'s small tables the one-hot
+    kernel (`wdl_plane`)."""
     import jax
     import jax.numpy as jnp
 
@@ -170,7 +197,7 @@ def wdl_forward(p: WDLParams, dense, codes, activations: List[str],
 
     with jax.named_scope("wdl.embed"):
         h, first_w, is_wide = wdl_plane(
-            p.embed, p.wide, p.dense_layers[0]["W"], dense, codes)
+            p.embed, p.wide, p.dense_layers[0]["W"], dense, codes, onehot)
     with jax.named_scope("wdl.deep"):
         weights = [first_w] + [layer["W"] for layer in p.dense_layers[1:]]
         x = h

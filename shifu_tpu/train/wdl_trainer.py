@@ -86,6 +86,27 @@ class WDLTrainResult:
 _PROGRAMS: Dict[tuple, object] = {}
 
 
+def _onehot_plan(vocab_sizes, width: int, mesh) -> tuple:
+    """(fields, interpret): the fields whose lookups' transpose is the
+    one-hot kernel (`models/wdl.wdl_plane`), which are the tables of at
+    most 4,096 rows that one call at `width` (an embedding row and the wide
+    weight) holds in VMEM (`onehot_grad_pallas.small_fields`), where the
+    kernel runs (`hist_pallas.pallas_active`: a TPU, or interpret mode
+    where -Dshifu.pallas.mode=on) and with no mesh (a sharded table keeps
+    XLA's partitioned scatter); else no field. The program may be vmapped
+    (`train_wdl_bagged`): the kernel is too."""
+    if mesh is not None:
+        return (), False
+    from shifu_tpu.ops.hist_pallas import pallas_active
+
+    enabled, interpret = pallas_active()
+    if not enabled:
+        return (), False
+    from shifu_tpu.ops.onehot_grad_pallas import small_fields
+
+    return small_fields(vocab_sizes, width), interpret
+
+
 def _get_program(cfg: WDLTrainConfig, template: WDLParams, mesh=None):
     import jax
     import jax.numpy as jnp
@@ -103,8 +124,12 @@ def _get_program(cfg: WDLTrainConfig, template: WDLParams, mesh=None):
     # closure would pin every initial 10k-vocab embedding table forever
     shapes = wdl_shapes(template)
     n_cat = len(template.embed)
+    vocab = [int(e.shape[0]) for e in template.embed]
+    fields, interpret = _onehot_plan(
+        vocab, int(template.embed[0].shape[1]) + 1 if n_cat else 1, mesh)
     key = (tuple(shapes), n_cat, tuple(cfg.activations), cfg.optimizer,
-           cfg.l2_reg, cfg.early_stop_window, embed_sharding)
+           cfg.l2_reg, cfg.early_stop_window, embed_sharding, fields,
+           interpret)
     if key in _PROGRAMS:
         return _PROGRAMS[key]
 
@@ -116,14 +141,16 @@ def _get_program(cfg: WDLTrainConfig, template: WDLParams, mesh=None):
     )
     window = cfg.early_stop_window
 
-    def loss_fn(flat, dense, codes, t, sig):
+    def loss_fn(flat, dense, codes, codes_t, t, sig):
         p = unflatten_wdl_from_shapes(flat, shapes, n_cat)
         if embed_sharding is not None:
             p.embed = [
                 jax.lax.with_sharding_constraint(e, embed_sharding)
                 for e in p.embed
             ]
-        prob = wdl_forward(p, dense, codes, cfg.activations)
+        prob = wdl_forward(p, dense, codes, cfg.activations,
+                           onehot=(fields, codes_t, interpret) if fields
+                           else None)
         with jax.named_scope("wdl.loss"):
             eps = 1e-7
             pc = jnp.clip(prob, eps, 1 - eps)
@@ -135,9 +162,9 @@ def _get_program(cfg: WDLTrainConfig, template: WDLParams, mesh=None):
     # Named scopes (`wdl.embed`, `wdl.wide`, `wdl.deep` in wdl_forward,
     # `wdl.loss`, `wdl.update` here) are op metadata only and not in the
     # compile cache's key.
-    def one_iter(carry, dense, codes, t, sig_tr, sig_va, nts, lr):
+    def one_iter(carry, dense, codes, codes_t, t, sig_tr, sig_va, nts, lr):
         (flat, opt, it, best_val, best_flat, bad, halt, tr_e, va_e) = carry
-        g_neg, prob = grad_fn(flat, dense, codes, t, sig_tr)
+        g_neg, prob = grad_fn(flat, dense, codes, codes_t, t, sig_tr)
         g = -g_neg
         with jax.named_scope("wdl.loss"):
             sq = (t - prob) ** 2
@@ -155,11 +182,20 @@ def _get_program(cfg: WDLTrainConfig, template: WDLParams, mesh=None):
 
     @jax.jit
     def program(carry, limit, dense, codes, t, sig_tr, sig_va, nts, lr):
+        # the one-hot kernel's codes operand does not change from one epoch
+        # to the next: made once, before the loop
+        codes_t = None
+        if fields:
+            from shifu_tpu.ops.onehot_grad_pallas import codes_operand
+
+            codes_t = codes_operand(codes, fields, vocab)
+
         def cond(c):
             return (c[2] < limit) & (~c[6])
 
         def body(c):
-            return one_iter(c, dense, codes, t, sig_tr, sig_va, nts, lr)
+            return one_iter(c, dense, codes, codes_t, t, sig_tr, sig_va, nts,
+                            lr)
 
         return jax.lax.while_loop(cond, body, carry)
 

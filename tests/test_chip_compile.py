@@ -408,6 +408,93 @@ def test_wdl_program_compiles_at_the_criteo_cells_size(one_chip):
     assert len(re.findall(r" scatter\(", text)) == 26
 
 
+def test_wdl_program_with_the_onehot_kernel_at_the_criteo_cells_size(
+        one_chip, monkeypatch):
+    """The same program as the chip builds it (`_onehot_plan` finds the
+    kernel on): the 14 tables of at most 4,096 rows take ONE
+    `wdl_onehot_grad` call (ops/onehot_grad_pallas.py) in place of their
+    scatter-adds, so the program holds 26 `gather` and 12 `scatter`; the
+    kernel's codes operand is made before the epoch loop, and the
+    temporaries stay under 3.0 KB a row (2,783 B: the `[14, n]` codes)."""
+    import json
+    import os
+
+    from shifu_tpu.models.wdl import flatten_wdl, init_wdl_params
+    from shifu_tpu.train import wdl_trainer as wt
+
+    monkeypatch.setattr(hp, "pallas_active", lambda: (True, False))
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "criteo_wdl.json")) as f:
+        c = json.load(f)
+    rows, vocab = c["rows"], c["vocab_sizes"]
+    tpl = init_wdl_params(c["dense_columns"], vocab, c["embed_outputs"],
+                          c["hidden_nodes"])
+    n_flat = flatten_wdl(tpl).size
+    key_before = set(wt._PROGRAMS)
+    program, _init = wt._get_program(wt.WDLTrainConfig(), tpl)
+    for k in set(wt._PROGRAMS) - key_before:
+        del wt._PROGRAMS[k]
+    s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    flat, row = s((n_flat,), jnp.float32), s((rows,), jnp.float32)
+    f32, i32 = s((), jnp.float32), s((), jnp.int32)
+    carry = (flat, {"m": flat, "v": flat}, i32, f32, flat, i32,
+             s((), jnp.bool_), f32, f32)
+    compiled = program.lower(
+        carry, i32, s((rows, c["dense_columns"]), jnp.float32),
+        s((rows, len(vocab)), jnp.int32), row, row, row, f32, f32).compile()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < 3000 * rows, \
+        mem.temp_size_in_bytes / rows
+    text = compiled.as_text()
+    assert len(re.findall(r" gather\(", text)) == 26
+    assert len(re.findall(r" scatter\(", text)) == 12
+    assert text.count('"kernel":"wdl_onehot_grad"') == 1
+    # the codes operand `s32[14, n]` is made outside the loop's body
+    entry = text[text.index("\nENTRY "):]
+    assert re.search(r"s32\[14,%d\]" % rows, entry)
+
+
+@pytest.mark.parametrize("vocab, width, taken", [
+    ([4000] * 60, 9, 50),
+    ([100] * 160, 9, 128),
+    ([4, 5, 11, 16, 19, 25, 28, 106, 306, 584, 634, 1461, 2174, 3195], 17,
+     14),
+], ids=["60_near_the_cap", "160_one_tile", "criteo_small_embed16"])
+def test_onehot_kernel_compiles_where_the_plan_takes_it(one_chip, vocab,
+                                                         width, taken):
+    """Whatever the plan takes (`onehot_grad_pallas.small_fields`: the
+    smallest tables while `call_vmem` fits `VMEM_BUDGET`, `FIELDS_CAP` at
+    most) compiles: many tables near the cap (a 26 MB accumulator window),
+    many one-tile tables (the unrolled body at its most fields), and the
+    Criteo cell's 14 at `EmbedOutputs` 16. The compiler refuses a call
+    whose window passes the v5e's 128 MiB of VMEM."""
+    from shifu_tpu.ops import onehot_grad_pallas as og
+
+    fields = og.small_fields(vocab, width)
+    assert len(fields) == taken
+    v = tuple(vocab[f] for f in fields)
+    s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    compiled = jax.jit(lambda c, *p: og.table_grads(c, list(p), v)).lower(
+        s((len(v), N_ROWS), jnp.int32),
+        *[s((width, N_ROWS), jnp.float32)] * len(v)).compile()
+    assert compiled.as_text().count('"kernel":"wdl_onehot_grad"') == 1
+
+
+def test_onehot_kernel_compiles_under_vmap(one_chip):
+    """`train_wdl_bagged`'s form: the members' cotangents on a leading axis,
+    the codes shared; `pallas_call`'s batching rule makes it one call."""
+    from shifu_tpu.ops import onehot_grad_pallas as og
+
+    v, M = (5, 130, 4096), 3
+    s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    fn = jax.vmap(lambda c, *p: og.table_grads(c, list(p), v),
+                  in_axes=(None,) + (0,) * len(v))
+    compiled = jax.jit(fn).lower(
+        s((len(v), N_ROWS), jnp.int32),
+        *[s((M, 9, N_ROWS), jnp.float32)] * len(v)).compile()
+    assert compiled.as_text().count('"kernel":"wdl_onehot_grad"') == 1
+
+
 def test_levels_beyond_the_rule_go_to_the_hist_kernel(monkeypatch):
     """The static rule, as the grower applies it: levels with
     2**d <= _FUSED_SCAN_L_CAP build the fused kernel (chunked to
